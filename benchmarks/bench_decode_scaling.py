@@ -9,6 +9,7 @@ growth in the per-element cost.
 
 from __future__ import annotations
 
+import pathlib
 import time
 
 import jax
@@ -76,4 +77,7 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
     print("\n".join(run()))

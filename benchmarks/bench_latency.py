@@ -9,6 +9,8 @@ Carlo confirmation.
 
 from __future__ import annotations
 
+import pathlib
+
 import numpy as np
 
 from repro.core import coded_fft_threshold, repetition_threshold, short_dot_threshold
@@ -44,4 +46,7 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
     print("\n".join(run()))

@@ -6,6 +6,7 @@ and times encode/worker/decode stages.
 
 from __future__ import annotations
 
+import pathlib
 import time
 
 import jax
@@ -59,4 +60,7 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
     print("\n".join(run()))

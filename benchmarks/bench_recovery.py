@@ -9,6 +9,7 @@ fails on its worst-case subsets of the same size.
 
 from __future__ import annotations
 
+import pathlib
 import itertools
 
 import jax
@@ -65,4 +66,7 @@ def run() -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
     print("\n".join(run()))

@@ -7,11 +7,15 @@ Individual modules run standalone too.
 
 from __future__ import annotations
 
+import pathlib
 import time
 import traceback
 
 
 def main() -> int:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
     from benchmarks import (
         bench_comm_load,
         bench_decode_scaling,

@@ -2,13 +2,14 @@
 
 Submits a stream of transform requests; each request's workers draw
 shifted-exponential latencies, the service answers after the fastest m,
-and every answer is verified against jnp.fft.  With 8 local devices
-(XLA_FLAGS=--xla_force_host_platform_device_count=8) the worker compute
-runs under shard_map across a real device mesh; with 1 device it runs the
-same math locally.
+and every answer is verified against jnp.fft.  With ``--mesh`` the worker
+compute runs under shard_map across a device mesh sized from the devices
+present: the most devices (up to 8) that divide the N=8 coded workers --
+4 on a v5e host, two workers per chip.  Without it the same math runs
+locally.
 
 Run:  PYTHONPATH=src python examples/fft_service_demo.py
-      XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+      XLA_FLAGS=--xla_force_host_platform_device_count=4 \\
           PYTHONPATH=src python examples/fft_service_demo.py --mesh
 """
 
@@ -24,19 +25,24 @@ from repro.serving import FFTService, FFTServiceConfig
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", action="store_true",
-                    help="run workers under shard_map (needs >= 8 devices)")
+                    help="run workers under shard_map over the devices "
+                         "present")
     ap.add_argument("--requests", type=int, default=12)
     args = ap.parse_args()
 
+    n_workers = 8
     mesh = None
     if args.mesh:
         from repro.distributed import test_mesh
 
-        mesh = test_mesh((8,), ("workers",))
-        print(f"[demo] shard_map over {jax.device_count()} devices")
+        p = max(d for d in (1, 2, 4, 8)
+                if d <= jax.device_count() and n_workers % d == 0)
+        mesh = test_mesh((p,), ("workers",))
+        print(f"[demo] shard_map over {p} of {jax.device_count()} devices "
+              f"({n_workers // p} coded workers per device)")
 
     svc = FFTService(
-        FFTServiceConfig(s=4096, m=4, n_workers=8,
+        FFTServiceConfig(s=4096, m=4, n_workers=n_workers,
                          straggler=StragglerModel(t0=1.0, mu=1.0)),
         mesh=mesh)
 

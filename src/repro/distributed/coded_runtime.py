@@ -43,12 +43,12 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
+from functools import partial, wraps
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import mds
@@ -57,6 +57,20 @@ from repro.core.plan import batch_shape
 from repro.distributed.faults import FaultInjector, FaultPlan
 
 __all__ = ["DistributedCodedPlan", "DistributedCodedFFT"]
+
+
+def _full_f32_matmuls(method):
+    """Trace ``method`` with full-f32 matmuls.
+
+    TPU dots default to one bf16 pass, which puts the encode, decode and
+    recombine contractions of a coded plan above its f32 error budget;
+    CPU dots are unaffected."""
+    @wraps(method)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return method(*args, **kwargs)
+
+    return traced
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +99,7 @@ class DistributedCodedPlan:
         return self.plan.n_workers // self.mesh.shape[self.axis]
 
     # ------------------------------------------------------------------
+    @_full_f32_matmuls
     def run(self, x: jax.Array, mask: Optional[jax.Array] = None,
             *, fragment_mask: Optional[jax.Array] = None,
             method: str = "auto",
@@ -174,7 +189,7 @@ class DistributedCodedPlan:
             shard_map, mesh=self.mesh,
             in_specs=(P(), P(), P()),
             out_specs=P(self.axis, None, None, None),
-            check_rep=False,
+            check_vma=False,
         )
         def workers(c_rep, fmask_rep, corrupt_rep):
             # per-device fused encode+compute: each device forms only its
@@ -209,7 +224,7 @@ class DistributedCodedPlan:
             shard_map, mesh=self.mesh,
             in_specs=(P(self.axis, None, None, None), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         def master(b_local, fmask_rep):
             # the paper's fan-in: gather the coded results to the master,
@@ -260,6 +275,7 @@ class DistributedCodedPlan:
         return out.reshape(batch + tuple(plan.output_shape))
 
     # ------------------------------------------------------------------
+    @_full_f32_matmuls
     def run_sharded(self, x: jax.Array, mask: Optional[jax.Array] = None,
                     *, method: str = "auto") -> jax.Array:
         """Optimized 1-D pipeline (§Perf cell C): sharded-output decode.
@@ -297,7 +313,7 @@ class DistributedCodedPlan:
             shard_map, mesh=self.mesh,
             in_specs=(P(), P()),
             out_specs=P(None, self.axis),
-            check_rep=False,
+            check_vma=False,
         )
         def pipeline(x_rep, mask_rep):
             # fused interleave+encode: c[i, l] = x[i + l*m] is just the

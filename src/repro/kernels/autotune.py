@@ -35,6 +35,7 @@ import os
 import pathlib
 import tempfile
 import time
+import warnings
 from typing import Callable, Optional
 
 import jax
@@ -54,6 +55,7 @@ __all__ = [
     "ensure_fourstep",
     "tune_bucket",
     "ensure_bucket",
+    "AutotuneError",
 ]
 
 SCHEMA_VERSION = 1
@@ -153,6 +155,38 @@ def record(kind: str, entry: dict, persist: bool = True, **params) -> dict:
 
 
 # ------------------------------------------------------------ measurement
+class AutotuneError(RuntimeError):
+    """Every candidate of a search failed to lower, compile or run."""
+
+
+def _search(kind: str, cands: list, time_one: Callable) -> dict:
+    """Time each ``(label, entry)`` candidate with ``time_one(entry)`` and
+    return the fastest entry with its ``ms``.
+
+    A candidate that fails is reported with its error (a warning) and
+    skipped -- it never turns into a recorded default.  If every candidate
+    fails the search raises, so a device that cannot run the kernel is
+    seen at warmup instead of being hidden behind a guessed tiling.
+    """
+    best: Optional[dict] = None
+    errors = []
+    for label, entry in cands:
+        try:
+            ms = time_one(entry)
+        except Exception as e:  # lowering/compile/runtime errors alike
+            errors.append(f"{label}: {type(e).__name__}: {e}")
+            warnings.warn(f"autotune {kind}: candidate {label} failed: "
+                          f"{type(e).__name__}: {e}", RuntimeWarning,
+                          stacklevel=3)
+            continue
+        if best is None or ms < best["ms"]:
+            best = {**entry, "ms": ms}
+    if best is None:
+        raise AutotuneError(f"autotune {kind}: every candidate failed:\n"
+                            + "\n".join(errors))
+    return best
+
+
 def _time_ms(fn: Callable, args: tuple, reps: int) -> float:
     out = jax.block_until_ready(fn(*args))  # compile + warm
     del out
@@ -236,19 +270,16 @@ def tune_fourstep(ell: int, batch: int = 4, mode: str = "direct", *,
     if include_xla:
         cands.append(("xla", None))
 
-    best: Optional[dict] = None
-    for variant, factors in cands:
-        fn = jax.jit(_fourstep_candidate_fn(variant, factors, interpret))
-        try:
-            ms = _time_ms(fn, (xr, xi), reps)
-        except Exception:
-            continue  # a candidate that fails to lower is just skipped
-        if best is None or ms < best["ms"]:
-            best = {"variant": variant, "ms": ms}
-            if factors is not None:
-                best["factors"] = factors
-    if best is None:  # every candidate failed: record the safe default
-        best = {"variant": "two_pass", "ms": float("nan")}
+    def time_one(entry):
+        fn = jax.jit(_fourstep_candidate_fn(
+            entry["variant"], entry.get("factors"), interpret))
+        return _time_ms(fn, (xr, xi), reps)
+
+    best = _search(f"fourstep L={ell} mode={mode}", [
+        (f"{variant}{'' if factors is None else factors}",
+         {"variant": variant,
+          **({} if factors is None else {"factors": factors})})
+        for variant, factors in cands], time_one)
     return record("fourstep", best, persist=persist, L=ell, mode=mode)
 
 
@@ -318,17 +349,13 @@ def tune_bucket(kind: str, s: int, m: int, n: int, q: int = 4, *,
             a, b, mk, gr, gi, s, interpret=interpret, block_q=bq))
         return fn, (xr, xi, masks)
 
-    best: Optional[dict] = None
-    for bq in block_qs:
-        fn, args = make(int(bq))
-        try:
-            ms = _time_ms(fn, args, reps)
-        except Exception:
-            continue
-        if best is None or ms < best["ms"]:
-            best = {"block_q": int(bq), "ms": ms}
-    if best is None:
-        best = {"block_q": 1, "ms": float("nan")}
+    def time_one(entry):
+        fn, args = make(entry["block_q"])
+        return _time_ms(fn, args, reps)
+
+    best = _search(f"{kind} s={s} m={m} n={n} mode={mode}",
+                   [(f"block_q={int(bq)}", {"block_q": int(bq)})
+                    for bq in block_qs], time_one)
     return record(kind, best, persist=persist, s=s, m=m, n=n, mode=mode)
 
 

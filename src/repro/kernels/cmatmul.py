@@ -25,7 +25,8 @@ __all__ = ["cmatmul", "cmatmul_body", "bcmatmul", "bcmatmul_body"]
 
 def cmatmul_body(ar, ai, br, bi):
     """One complex matmul block: 4 real MXU matmuls, f32 accumulation."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     return dot(ar, br) - dot(ai, bi), dot(ar, bi) + dot(ai, br)
 
 
@@ -68,6 +69,7 @@ def bcmatmul_body(ar, ai, br, bi):
     dot = functools.partial(
         jax.lax.dot_general,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     return dot(ar, br) - dot(ai, bi), dot(ar, bi) + dot(ai, br)

@@ -7,9 +7,8 @@ The batched service hot path (DESIGN.md §5/§6) is, per request,
 and every stage is either a (shared-matrix) matmul, a batched matmul
 against per-request decode matrices, or an elementwise twiddle.  For
 bucket shapes that fit VMEM there is no reason for ANY intermediate to
-touch HBM: this kernel runs the full pipeline per batch block --
+touch HBM: this kernel runs the coded core per batch block --
 
-    c   = interleave(x)                       (pure relabeling, free)
     t   = ((F_A @ c) * W) @ F_B               (four-step worker DFT of the
                                                m MESSAGE shards)
     b   = G @ t                               (MDS encode; commutes with
@@ -17,13 +16,14 @@ touch HBM: this kernel runs the full pipeline per batch block --
     c^  = D_q @ b                             (per-request scatter decode
                                                matrices, stragglers = zero
                                                columns)
-    X   = F_m @ (c^ * W_s)                    (recombine butterfly)
+    X   = F_m @ (c^ * W_s)                    (c2c recombine butterfly)
 
--- six MXU contractions and two VPU twiddles per block, one HBM read of
-the requests and one HBM write of the spectra.  Off-TPU the ops layer
-collapses the batch into a single grid step, so the interpret-mode
-lowering is one straight-line XLA program (this is what makes the fused
-kernel the fastest CPU path as well, see BENCH_kernels.json).
+-- every contraction keeps each shard's (A, B) tile layout, so the body
+lowers inside a Mosaic kernel.  The relabelings around the core (the c2c
+interleave ``c_i[j] = x[i + j*m]``, the r2c pair packing, the c2r
+adjoint message butterfly, and the final unscramble of the four-step
+order) run as XLA ops in the same jitted executor: they move the shard
+index out of the lane axis, which a TPU kernel cannot do cheaply.
 
 Stage-level kernels (fourstep_fft.py, cmatmul.py, recombine.py) remain the
 fallback for bucket shapes whose working set exceeds VMEM.
@@ -40,11 +40,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.cmatmul import bcmatmul_body, cmatmul_body
-from repro.kernels.fourstep_fft import _cmul_mm, encode_fourstep_body
+from repro.kernels.fourstep_fft import (
+    COMPILER_PARAMS,
+    _HIGHEST,
+    _even_divisor,
+    encode_fourstep_body,
+    shard_contract,
+    stage1_body,
+    stage2_body,
+)
 
 __all__ = [
     "lagrange_planes_body",
     "subsets_from_masks_body",
+    "interleave_planes",
+    "unscramble_planes",
+    "coded_core_body",
     "bucket_body",
     "bucket_body_masked",
     "bucket_body_fftworker",
@@ -68,16 +79,15 @@ __all__ = [
     "coded_irfft_bucket_masked",
 ]
 
-
 # ================================== device-resident decode matrices (§8)
 #
 # The closed-form Lagrange inversion of core/mds.py restated on f32 planes
-# with ONLY Mosaic-expressible ops -- broadcasted_iota, elementwise trig,
-# static-shape matmuls, one static-unrolled m-step product -- so the bucket
-# kernels can build every request's decode matrix IN VMEM from its
-# responder subset.  No gathers: node powers come from the root-of-unity
-# closed form, coefficient shifts from a static one-hot contraction, and
-# the scatter from a subset-vs-iota one-hot matmul.
+# with ONLY Mosaic-expressible ops -- iota, elementwise trig, lane slices
+# and lane broadcasts of (bq, m) rows, one static-unrolled m-step product
+# -- so the bucket kernels can build every request's decode matrix IN VMEM
+# from its responder subset.  No gathers: node powers come from the
+# root-of-unity closed form, the deflation is synthetic division row by
+# row, and the scatter a subset-vs-iota one-hot sum.
 
 
 @functools.lru_cache(maxsize=None)
@@ -94,17 +104,14 @@ def lagrange_planes_body(subsets, n):
     workers.  Returns ``(ivr, ivi, dr, di)``: the compact ``(bq, m, m)``
     inverse planes (the gathered-decode form the direct executor wants) and
     the scatter ``(bq, m, n)`` planes with zero straggler columns (the MXU
-    form the fused kernels contract against).  O(m^2) work per request;
-    every op lowers inside a Mosaic kernel body.
+    form the fused kernels contract against).  O(m^2 n) work per request;
+    every op works on ``(bq, m)`` / ``(bq, n)`` rows and lowers inside a
+    Mosaic kernel body.
     """
     bq, m = subsets.shape
     f32 = jnp.float32
     subsets = subsets.astype(jnp.int32)
     tau = 2.0 * np.pi / n
-    # exact node powers P[b, j, d] = x_j^d = omega^(subset_j * d mod n)
-    d_iota = jax.lax.broadcasted_iota(jnp.int32, (bq, m, m), 2)
-    angp = (-tau) * ((subsets[:, :, None] * d_iota) % n).astype(f32)
-    pr, pi_ = jnp.cos(angp), jnp.sin(angp)
     angn = (-tau) * (subsets % n).astype(f32)
     nr, ni = jnp.cos(angn), jnp.sin(angn)                   # nodes (bq, m)
     # locator A(z) = prod (z - x_j): m static-unrolled shift-multiply steps
@@ -116,34 +123,39 @@ def lagrange_planes_body(subsets, n):
         si = jnp.concatenate([zero, ai[:, :m]], axis=1)
         xr_, xi_ = nr[:, i:i + 1], ni[:, i:i + 1]
         ar, ai = sr - (xr_ * ar - xi_ * ai), si - (xr_ * ai + xi_ * ar)
-    # deflation in suffix form: T[i, d] = a[i+d+1] (0 past m); the selector
-    # S[t, (i, d)] = [t == i+d+1] is built from iota IN the body -- a
-    # pallas_call kernel may not capture host constants
-    ii = jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
-    dd = jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)
-    tsel = jax.lax.broadcasted_iota(jnp.int32, (m + 1, m, m), 0)
-    sel = (tsel == (ii + dd + 1)[None]).astype(f32).reshape(m + 1, m * m)
-    # q = T @ P^T: the coefficients of A(z)/(z - x_j) for every j at once
-    tr = (ar @ sel).reshape(bq, m, m)
-    ti = (ai @ sel).reshape(bq, m, m)
-    prT = jnp.swapaxes(pr, 1, 2)
-    piT = jnp.swapaxes(pi_, 1, 2)
-    qr = tr @ prT - ti @ piT
-    qi = tr @ piT + ti @ prT                                # (bq, i, j)
-    # A'(x_j) = Q_j(x_j) = sum_i q[i, j] x_j^i  (diagonal contraction)
-    qrT = jnp.swapaxes(qr, 1, 2)
-    qiT = jnp.swapaxes(qi, 1, 2)                            # (bq, j, i)
-    apr = jnp.sum(qrT * pr - qiT * pi_, axis=2)
-    api = jnp.sum(qrT * pi_ + qiT * pr, axis=2)             # (bq, j)
+    # synthetic division: row i of q holds coefficient i of A(z)/(z - x_j)
+    # for every node j at once -- q_{m-1} = a_m, q_{i-1} = a_i + x_j q_i
+    qr = [None] * m
+    qi = [None] * m
+    qr[m - 1] = jnp.broadcast_to(ar[:, m:], (bq, m))
+    qi[m - 1] = jnp.broadcast_to(ai[:, m:], (bq, m))
+    for i in range(m - 1, 0, -1):
+        qr[i - 1] = ar[:, i:i + 1] + (nr * qr[i] - ni * qi[i])
+        qi[i - 1] = ai[:, i:i + 1] + (nr * qi[i] + ni * qr[i])
+    # A'(x_j) = Q_j(x_j) = sum_i q[i, j] x_j^i with exact node powers
+    # x_j^i = omega^(subset_j * i mod n)
+    apr = jnp.zeros((bq, m), f32)
+    api = jnp.zeros((bq, m), f32)
+    for i in range(m):
+        ang = (-tau) * ((subsets * i) % n).astype(f32)
+        pr, pi_ = jnp.cos(ang), jnp.sin(ang)
+        apr = apr + (qr[i] * pr - qi[i] * pi_)
+        api = api + (qr[i] * pi_ + qi[i] * pr)
     den = apr * apr + api * api
-    cr = (apr / den)[:, None, :]
-    ci = (-api / den)[:, None, :]                           # 1 / A'(x_j)
-    ivr = qr * cr - qi * ci
-    ivi = qr * ci + qi * cr                                 # inv (bq, m, m)
-    # scatter inv columns to worker slots: D[:, subset] = inv, one-hot matmul
-    k_iota = jax.lax.broadcasted_iota(jnp.int32, (bq, m, n), 2)
-    onehot = (subsets[:, :, None] == k_iota).astype(f32)    # (bq, m, n)
-    return ivr, ivi, ivr @ onehot, ivi @ onehot
+    cr, ci = apr / den, -api / den                          # 1 / A'(x_j)
+    # scatter inverse columns to worker slots: D[:, subset_j] = inv[:, j]
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (bq, n), 1)
+    onehot = [(subsets[:, j:j + 1] == k_iota).astype(f32) for j in range(m)]
+    ivr, ivi, dr, di = [], [], [], []
+    for i in range(m):
+        vr = qr[i] * cr - qi[i] * ci
+        vi = qr[i] * ci + qi[i] * cr                        # inv row i
+        ivr.append(vr)
+        ivi.append(vi)
+        dr.append(sum(vr[:, j:j + 1] * onehot[j] for j in range(m)))
+        di.append(sum(vi[:, j:j + 1] * onehot[j] for j in range(m)))
+    stack = functools.partial(jnp.stack, axis=1)
+    return stack(ivr), stack(ivi), stack(dr), stack(di)
 
 
 def subsets_from_masks_body(masks, m):
@@ -166,12 +178,14 @@ def subsets_from_masks_body(masks, m):
     """
     bq, n = masks.shape
     f32 = jnp.float32
+    dot = functools.partial(jnp.dot, precision=_HIGHEST,
+                            preferred_element_type=f32)
     mk = (masks.astype(f32) > 0.5).astype(f32)
     kp = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     kk = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
     tri = (kp < kk).astype(f32)                  # strictly-lower ones
-    rank = mk @ tri                              # (bq, n) availables before k
-    rank_nr = (1.0 - mk) @ tri                   # ... and unavailables
+    rank = dot(mk, tri)                          # (bq, n) availables before k
+    rank_nr = dot(1.0 - mk, tri)                 # ... and unavailables
     cnt = jnp.sum(mk, axis=1)[:, None, None]     # (bq, 1, 1) responder count
     jj = jax.lax.broadcasted_iota(jnp.int32, (bq, m, n), 1).astype(f32)
     kidx = jax.lax.broadcasted_iota(jnp.int32, (bq, m, n), 2).astype(f32)
@@ -181,52 +195,83 @@ def subsets_from_masks_body(masks, m):
     return jnp.sum(sel * kidx, axis=2).astype(jnp.int32)
 
 
-def bucket_body(xr, xi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
+def _masked_decode_planes(masks, m, n):
+    """Raw ``(bq, n)`` masks -> scatter decode planes, in-body."""
+    _, _, dr, di = lagrange_planes_body(subsets_from_masks_body(masks, m), n)
+    return dr, di
+
+
+# ======================================================= layout relabels
+def interleave_planes(xr, xi, m, a, b):
+    """Request planes ``(q, s)`` -> message planes ``(q, m, A, B)``.
+
+    ``c_i[j] = x[i + j*m]`` viewed as the four-step matrix
+    ``M_i[a, b] = c_i[a*B + b]``: a pure relabeling, run as XLA before the
+    kernel launch so the shard index never sits in the lane axis.
+    """
+    q = xr.shape[0]
+    view = lambda t: jnp.swapaxes(t.reshape(q, a * b, m), 1, 2).reshape(
+        q, m, a, b)
+    return view(xr), view(xi)
+
+
+def unscramble_planes(yr):
+    """Scrambled four-step planes ``(q, k, A, B)`` -- ``out[c, d] =
+    X[c + d*A]`` -- to natural flat order ``(q, k, A*B)``."""
+    q, k, a, b = yr.shape
+    return jnp.swapaxes(yr, -1, -2).reshape(q, k, a * b)
+
+
+# ================================================== the coded core (c2c)
+def coded_core_body(cr, ci, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
+                    inverse=False):
+    """Encode -> worker DFT -> decode on one block of message planes.
+
+    ``cr, ci``: (bq, m, A, B) message planes; ``dr, di``: (bq, m, N)
+    per-request scatter decode planes; ``gr, gi``: (N, m) generator.
+    Returns the decoded (bq, m, A, B) shard spectra in the scrambled
+    four-step order (decode only mixes the shard axis, so the payload
+    order is carried through untouched).  ``inverse=True`` makes the
+    worker an ifft through the conj trick on planes -- the caller passes
+    conjugated message and generator planes and the worker output is
+    conjugated and scaled by 1/(A*B) here, before the decode.
+    """
+    er, ei = encode_fourstep_body(cr, ci, gr, gi, far, fai, wr, wi,
+                                  fbr, fbi)                 # (bq, n, a, b)
+    if inverse:
+        n2 = cr.shape[2] * cr.shape[3]
+        er, ei = er / n2, ei / (-n2)
+    return shard_contract(dr, di, er, ei)
+
+
+def recombine_shards_body(hr, hi, twr, twi, fmr, fmi):
+    """c2c recombine on scrambled planes: twiddle ``twr`` (m, A, B), then
+    the length-m DFT across the shard axis."""
+    bq, m = hr.shape[:2]
+    ur = hr * twr - hi * twi
+    ui = hr * twi + hi * twr
+    return shard_contract(jnp.broadcast_to(fmr, (bq, m, m)),
+                          jnp.broadcast_to(fmi, (bq, m, m)), ur, ui)
+
+
+def bucket_body(cr, ci, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                 twr, twi, fmr, fmi):
-    """The full pipeline on one (bq, s) block of requests.
+    """The full c2c pipeline on one (bq, m, A, B) block of message planes.
 
     Shared between the Pallas kernel (one block per grid step, everything
     VMEM-resident) and the off-TPU direct path (full batch as straight
-    XLA, DESIGN.md §6).  Stages 1-4 are :func:`encode_fourstep_body`.
-
-    Layout note: the four-step DFT produces shard spectra in the scrambled
-    order ``B_k[c + d*A] = out[k, c, d]``.  Decode only mixes the shard
-    axis, so the scrambled payload order is carried THROUGH the decode and
-    undone by the single output transpose at the end -- ``twr/twi`` must be
-    the recombine twiddle pre-permuted to that order (``ops`` builds it),
-    which saves the largest intermediate copy (the (bq, N, L) unscramble).
+    XLA, DESIGN.md §6).  ``twr/twi`` is the recombine twiddle
+    pre-permuted to the scrambled payload order and viewed (m, A, B)
+    (``ops`` builds it), so the only unscramble is the one at the output.
+    Returns scrambled (bq, m, A, B) output planes: ``X_q[j*L + c + d*A] =
+    out[q, j, c, d]``.
     """
-    bq, s = xr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    ell = a * b
-    # interleave: c_i[j] = x[i + j*m] -- a relabeling, stays in VMEM
-    cr = jnp.transpose(xr.reshape(bq, ell, m), (0, 2, 1)).reshape(bq, m, a, b)
-    ci = jnp.transpose(xi.reshape(bq, ell, m), (0, 2, 1)).reshape(bq, m, a, b)
-    # stages 1-4: fused four-step DFT + MDS encode -> (bq, n, a, b)
-    er, ei = encode_fourstep_body(
-        cr, ci, gr, gi, far, fai, wr, wi, fbr, fbi)
-    # stage 5: per-request decode matrices (batched contraction over N) --
-    # payload stays in scrambled (c, d) order, decode never reads it
-    hr, hi = bcmatmul_body(dr, di, er.reshape(bq, n, ell),
-                           ei.reshape(bq, n, ell))
-    # stage 6: recombine twiddle (pre-scrambled) + length-m DFT
-    twr = twr[None]
-    twi = twi[None]
-    ur = hr * twr - hi * twi
-    ui = hr * twi + hi * twr
-    ur = jnp.transpose(ur, (1, 0, 2)).reshape(m, bq * ell)
-    ui = jnp.transpose(ui, (1, 0, 2)).reshape(m, bq * ell)
-    outr, outi = cmatmul_body(fmr, fmi, ur, ui)
-    # output + unscramble in ONE transpose: X_q[j*L + c + d*A] lives at
-    # out[j, q, c, d] -> (q, j, d, c)
-    outr = outr.reshape(m, bq, a, b).transpose(1, 0, 3, 2).reshape(bq, s)
-    outi = outi.reshape(m, bq, a, b).transpose(1, 0, 3, 2).reshape(bq, s)
-    return outr, outi
+    hr, hi = coded_core_body(cr, ci, dr, di, gr, gi, far, fai, wr, wi,
+                             fbr, fbi)
+    return recombine_shards_body(hr, hi, twr, twi, fmr, fmi)
 
 
-def bucket_body_masked(xr, xi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
+def bucket_body_masked(cr, ci, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                        twr, twi, fmr, fmi):
     """:func:`bucket_body` with the decode matrices built IN the body.
 
@@ -238,10 +283,121 @@ def bucket_body_masked(xr, xi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
     working set.
     """
     n, m = gr.shape
-    subsets = subsets_from_masks_body(masks, m)
-    _, _, dr, di = lagrange_planes_body(subsets, n)
-    return bucket_body(xr, xi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
+    dr, di = _masked_decode_planes(masks, m, n)
+    return bucket_body(cr, ci, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                        twr, twi, fmr, fmi)
+
+
+def _core_kernel(masked, inverse, n_rec, *refs):
+    cr_ref, ci_ref = refs[:2]
+    if masked:
+        mk = refs[2][...]
+        rest = refs[3:]
+    else:
+        dr, di = refs[2][...], refs[3][...]
+        rest = refs[4:]
+    planes = [r[...] for r in rest[:8 + n_rec]]
+    or_ref, oi_ref = rest[8 + n_rec:]
+    gr, gi = planes[:2]
+    if masked:
+        n, m = gr.shape
+        dr, di = _masked_decode_planes(mk.reshape(mk.shape[0], n), m, n)
+    hr, hi = coded_core_body(cr_ref[...], ci_ref[...], dr, di, *planes[:8],
+                             inverse=inverse)
+    if n_rec:
+        hr, hi = recombine_shards_body(hr, hi, *planes[8:])
+    or_ref[...] = hr
+    oi_ref[...] = hi
+
+
+def _core_call(cr, ci, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+               recombine=(), *, inverse=False, block_q=1, interpret=False,
+               name):
+    """One Pallas launch of the coded core over (q, m, A, B) message planes.
+
+    ``decode``: ``[dr, di]`` (q, m, N) scatter planes, or ``[masks]``
+    (q, N) raw responder masks (decode planes then built in-kernel).
+    ``recombine``: the c2c ``(twr, twi, fmr, fmi)`` planes, or empty for
+    the real kinds, whose butterflies run as XLA around the launch.
+    Every block keeps the batch in a leading axis and the (A, B) tile
+    whole, which is what the TPU block-shape rule needs.
+    """
+    q, m, a, b = cr.shape
+    n = gr.shape[0]
+    masked = len(decode) == 1
+    if masked:
+        decode = [decode[0].astype(cr.dtype).reshape(q, 1, n)]
+    block_q = max(1, min(block_q, q))
+
+    def blk(*shape):
+        return pl.BlockSpec((block_q, *shape),
+                            lambda i, r=len(shape): (i,) + (0,) * r)
+
+    def const(x):
+        return pl.BlockSpec(x.shape, lambda i, r=x.ndim: (0,) * r)
+
+    shared = [gr, gi, far, fai, wr, wi, fbr, fbi, *recombine]
+    decode_specs = [blk(1, n)] if masked else [blk(m, n)] * 2
+    return pl.pallas_call(
+        functools.partial(_core_kernel, masked, inverse, len(recombine)),
+        grid=(pl.cdiv(q, block_q),),
+        in_specs=[blk(m, a, b)] * 2 + decode_specs
+        + [const(x) for x in shared],
+        out_specs=[blk(m, a, b)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((q, m, a, b), cr.dtype)] * 2,
+        compiler_params=COMPILER_PARAMS,
+        interpret=interpret,
+        name=name,
+    )(cr, ci, *decode, *shared)
+
+
+def _bucket_call(xr, xi, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+                 twr, twi, fmr, fmi, block_q, interpret, name):
+    q, s = xr.shape
+    m = gr.shape[1]
+    a, b = far.shape[0], fbr.shape[0]
+    cr, ci = interleave_planes(xr, xi, m, a, b)
+    rec = (twr.reshape(m, a, b), twi.reshape(m, a, b), fmr, fmi)
+    outr, outi = _core_call(cr, ci, decode, gr, gi, far, fai, wr, wi,
+                            fbr, fbi, rec, block_q=block_q,
+                            interpret=interpret, name=name)
+    return (unscramble_planes(outr).reshape(q, s),
+            unscramble_planes(outi).reshape(q, s))
+
+
+def coded_fft_bucket(xr, xi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
+                     twr, twi, fmr, fmi, *, block_q: int = 1,
+                     interpret: bool = False):
+    """Fused bucket pipeline: request planes -> output spectrum planes.
+
+    ``xr, xi``: (q, s) request planes; ``dr, di``: (q, m, N) per-request
+    scatter decode matrices; ``gr, gi``: (N, m) generator;
+    ``far/wr/fbr``: four-step DFT/twiddle planes for L = s/m = A*B;
+    ``twr``: (m, L) recombine twiddle in the scrambled payload order;
+    ``fmr``: (m, m) DFT.  Returns (q, s) planes of ``fft(x, axis=-1)``
+    decoded from the masked worker subset each ``D_q`` encodes.  The
+    interleave and the final unscramble are XLA relabels around the one
+    kernel launch.
+    """
+    return _bucket_call(xr, xi, [dr, di], gr, gi, far, fai, wr, wi, fbr, fbi,
+                        twr, twi, fmr, fmi, block_q, interpret,
+                        "coded_fft_bucket")
+
+
+def coded_fft_bucket_masked(xr, xi, masks, gr, gi, far, fai, wr, wi,
+                            fbr, fbi, twr, twi, fmr, fmi, *, block_q: int = 1,
+                            interpret: bool = False):
+    """:func:`coded_fft_bucket` taking raw ``(q, N)`` responder masks in
+    place of the ``(q, m, N)`` decode planes.
+
+    Subset selection (first-m-available) AND the per-request Lagrange
+    decode matrices run INSIDE the kernel (VMEM-resident, DESIGN.md §8),
+    so the host ships the availability bits it already has -- zero decode
+    metadata, no host inversion or LRU at all.
+    """
+    return _bucket_call(xr, xi, [masks], gr, gi, far, fai, wr, wi, fbr, fbi,
+                        twr, twi, fmr, fmi, block_q, interpret,
+                        "coded_fft_bucket_masked")
 
 
 def bucket_body_fftworker(xr, xi, dvr, dvi, subsets, gr, gi,
@@ -299,8 +455,8 @@ def bucket_body_fftworker(xr, xi, dvr, dvi, subsets, gr, gi,
 # identical stage structure: the real request is relabeled into pair-packed
 # message shards z_i[j] = x[i + 2jm] + 1j*x[i + (2j+1)m] (free on planes --
 # the real input IS the plane), the fused encode+worker transforms L/2-point
-# shards, decode is the same batched matmul, and the one NEW stage is the
-# symmetry-aware postdecode: split each packed spectrum into the rfft of its
+# shards, decode is the same batched matmul (the coded core above), and the
+# one NEW stage is the symmetry-aware postdecode: split each packed spectrum into the rfft of its
 # real shard (conjugation = a sign flip on the imag plane, real-linear),
 # Hermitian-extend, and recombine only the m//2+1 butterfly rows that feed
 # the non-redundant bins X[0..s/2].
@@ -367,32 +523,30 @@ def half_postdecode_body(hr, hi, swr, swi, twr, twi, fhr, fhi, s):
     return outr[:, :sh], outi[:, :sh]
 
 
+def _packed_views(xr, m, a, b):
+    zr, zi = pack_real_planes(xr, m)
+    q = xr.shape[0]
+    return zr.reshape(q, m, a, b), zi.reshape(q, m, a, b)
+
+
 def rbucket_body(xr, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                  swr, swi, twr, twi, fhr, fhi, s):
-    """The full r2c pipeline on one (bq, s) block of REAL requests.
+    """The full r2c pipeline on a (bq, s) block of REAL requests.
 
-    Identical structure to :func:`bucket_body` on half-length payloads
-    (L/2 = A*B four-step planes), plus the symmetry postdecode.  Unlike the
-    c2c bucket, the scrambled four-step order is undone BEFORE the
-    butterfly -- the split needs natural reversed indexing -- which costs
-    one (bq, m, L/2) transpose instead of the c2c path's pre-permuted
-    twiddle trick.
+    The c2c coded core on half-length payloads (L/2 = A*B four-step
+    planes) between the pair-packing relabel and the symmetry postdecode.
+    The scrambled four-step order is undone BEFORE the butterfly -- the
+    split needs natural reversed indexing.  The compiled path runs the
+    same three steps with the core as one Pallas launch
+    (:func:`coded_rfft_bucket`).
     """
-    bq, s_ = xr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    n2 = a * b
-    zr, zi = pack_real_planes(xr, m)
-    er, ei = encode_fourstep_body(
-        zr.reshape(bq, m, a, b), zi.reshape(bq, m, a, b),
-        gr, gi, far, fai, wr, wi, fbr, fbi)      # (bq, n, a, b) scrambled
-    hr, hi = bcmatmul_body(dr, di, er.reshape(bq, n, n2),
-                           ei.reshape(bq, n, n2))
-    # unscramble: scr[c*B + d] holds B[c + d*A] -> natural flat index d*A + c
-    hr = hr.reshape(bq, m, a, b).transpose(0, 1, 3, 2).reshape(bq, m, n2)
-    hi = hi.reshape(bq, m, a, b).transpose(0, 1, 3, 2).reshape(bq, m, n2)
-    return half_postdecode_body(hr, hi, swr, swi, twr, twi, fhr, fhi, s)
+    m = gr.shape[1]
+    a, b = far.shape[0], fbr.shape[0]
+    zr, zi = _packed_views(xr, m, a, b)
+    hr, hi = coded_core_body(zr, zi, dr, di, gr, gi, far, fai, wr, wi,
+                             fbr, fbi)
+    return half_postdecode_body(unscramble_planes(hr), unscramble_planes(hi),
+                                swr, swi, twr, twi, fhr, fhi, s)
 
 
 def rbucket_body_masked(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
@@ -400,8 +554,7 @@ def rbucket_body_masked(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
     """:func:`rbucket_body` with in-kernel subset selection + in-VMEM
     Lagrange decode matrices (cf. :func:`bucket_body_masked`)."""
     n, m = gr.shape
-    subsets = subsets_from_masks_body(masks, m)
-    _, _, dr, di = lagrange_planes_body(subsets, n)
+    dr, di = _masked_decode_planes(masks, m, n)
     return rbucket_body(xr, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                         swr, swi, twr, twi, fhr, fhi, s)
 
@@ -430,81 +583,34 @@ def rbucket_body_fftworker(xr, dvr, dvi, subsets, gr, gi,
     return half_postdecode_body(hr, hi, swr, swi, twr, twi, fhr, fhi, s)
 
 
-def _rbucket_kernel(s):
-    def kernel(xr_ref, dr_ref, di_ref, gr_ref, gi_ref,
-               far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
-               swr_ref, swi_ref, twr_ref, twi_ref, fhr_ref, fhi_ref,
-               or_ref, oi_ref):
-        or_ref[...], oi_ref[...] = rbucket_body(
-            xr_ref[...], dr_ref[...], di_ref[...], gr_ref[...], gi_ref[...],
-            far_ref[...], fai_ref[...], wr_ref[...], wi_ref[...],
-            fbr_ref[...], fbi_ref[...], swr_ref[...], swi_ref[...],
-            twr_ref[...], twi_ref[...], fhr_ref[...], fhi_ref[...], s)
-
-    return kernel
+def _rbucket_call(xr, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+                  swr, swi, twr, twi, fhr, fhi, s, block_q, interpret, name):
+    m = gr.shape[1]
+    a, b = far.shape[0], fbr.shape[0]
+    zr, zi = _packed_views(xr, m, a, b)
+    hr, hi = _core_call(zr, zi, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+                        block_q=block_q, interpret=interpret, name=name)
+    return half_postdecode_body(unscramble_planes(hr), unscramble_planes(hi),
+                                swr, swi, twr, twi, fhr, fhi, s)
 
 
 def coded_rfft_bucket(xr, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                       swr, swi, twr, twi, fhr, fhi, s, *, block_q: int = 1,
                       interpret: bool = False):
     """Fused r2c bucket pipeline: real request planes -> half-spectrum
-    planes, one Pallas launch per grid step.
+    planes, the coded core as one Pallas launch.
 
     ``xr``: (q, s) REAL request plane (no imag plane exists); ``dr, di``:
     (q, m, N) scatter decode matrices; ``far/wr/fbr``: four-step planes for
     the HALF length L/2 = A*B; ``swr``: (1, L/2+1) split twiddle; ``twr``:
     (m, L) recombine twiddle; ``fhr``: (m//2+1, m) DFT rows.  Returns
-    (q, s//2+1) planes of ``rfft(x, axis=-1)``.
+    (q, s//2+1) planes of ``rfft(x, axis=-1)``.  The pair packing and the
+    symmetry butterfly (index reversal, odd-length edges) run as XLA
+    around the launch.
     """
-    q, s_ = xr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    n2 = a * b
-    ell = 2 * n2
-    sh = s // 2 + 1
-    rows = m // 2 + 1
-    block_q = max(1, min(block_q, q))
-    spec_x = pl.BlockSpec((block_q, s), lambda i: (i, 0))
-    spec_o = pl.BlockSpec((block_q, sh), lambda i: (i, 0))
-    spec_d = pl.BlockSpec((block_q, m, n), lambda i: (i, 0, 0))
-    spec_g = pl.BlockSpec((n, m), lambda i: (0, 0))
-    spec_fa = pl.BlockSpec((a, a), lambda i: (0, 0))
-    spec_w = pl.BlockSpec((a, b), lambda i: (0, 0))
-    spec_fb = pl.BlockSpec((b, b), lambda i: (0, 0))
-    spec_sw = pl.BlockSpec((1, n2 + 1), lambda i: (0, 0))
-    spec_tw = pl.BlockSpec((m, ell), lambda i: (0, 0))
-    spec_fh = pl.BlockSpec((rows, m), lambda i: (0, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((q, sh), xr.dtype),
-        jax.ShapeDtypeStruct((q, sh), xr.dtype),
-    ]
-    return pl.pallas_call(
-        _rbucket_kernel(s),
-        grid=(pl.cdiv(q, block_q),),
-        in_specs=[spec_x, spec_d, spec_d, spec_g, spec_g,
-                  spec_fa, spec_fa, spec_w, spec_w, spec_fb, spec_fb,
-                  spec_sw, spec_sw, spec_tw, spec_tw, spec_fh, spec_fh],
-        out_specs=[spec_o, spec_o],
-        out_shape=out_shape,
-        interpret=interpret,
-        name="coded_rfft_bucket",
-    )(xr, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
-      swr, swi, twr, twi, fhr, fhi)
-
-
-def _rbucket_kernel_masked(s):
-    def kernel(xr_ref, mk_ref, gr_ref, gi_ref,
-               far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
-               swr_ref, swi_ref, twr_ref, twi_ref, fhr_ref, fhi_ref,
-               or_ref, oi_ref):
-        or_ref[...], oi_ref[...] = rbucket_body_masked(
-            xr_ref[...], mk_ref[...], gr_ref[...], gi_ref[...],
-            far_ref[...], fai_ref[...], wr_ref[...], wi_ref[...],
-            fbr_ref[...], fbi_ref[...], swr_ref[...], swi_ref[...],
-            twr_ref[...], twi_ref[...], fhr_ref[...], fhi_ref[...], s)
-
-    return kernel
+    return _rbucket_call(xr, [dr, di], gr, gi, far, fai, wr, wi, fbr, fbi,
+                         swr, swi, twr, twi, fhr, fhi, s, block_q, interpret,
+                         "coded_rfft_bucket")
 
 
 def coded_rfft_bucket_masked(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
@@ -513,42 +619,9 @@ def coded_rfft_bucket_masked(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
     """:func:`coded_rfft_bucket` taking raw ``(q, N)`` responder masks in
     place of decode planes -- subset selection AND the Lagrange weights
     run in VMEM per grid step (DESIGN.md §8)."""
-    q, s_ = xr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    n2 = a * b
-    ell = 2 * n2
-    sh = s // 2 + 1
-    rows = m // 2 + 1
-    block_q = max(1, min(block_q, q))
-    masks = masks.astype(xr.dtype)
-    spec_x = pl.BlockSpec((block_q, s), lambda i: (i, 0))
-    spec_o = pl.BlockSpec((block_q, sh), lambda i: (i, 0))
-    spec_mk = pl.BlockSpec((block_q, n), lambda i: (i, 0))
-    spec_g = pl.BlockSpec((n, m), lambda i: (0, 0))
-    spec_fa = pl.BlockSpec((a, a), lambda i: (0, 0))
-    spec_w = pl.BlockSpec((a, b), lambda i: (0, 0))
-    spec_fb = pl.BlockSpec((b, b), lambda i: (0, 0))
-    spec_sw = pl.BlockSpec((1, n2 + 1), lambda i: (0, 0))
-    spec_tw = pl.BlockSpec((m, ell), lambda i: (0, 0))
-    spec_fh = pl.BlockSpec((rows, m), lambda i: (0, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((q, sh), xr.dtype),
-        jax.ShapeDtypeStruct((q, sh), xr.dtype),
-    ]
-    return pl.pallas_call(
-        _rbucket_kernel_masked(s),
-        grid=(pl.cdiv(q, block_q),),
-        in_specs=[spec_x, spec_mk, spec_g, spec_g,
-                  spec_fa, spec_fa, spec_w, spec_w, spec_fb, spec_fb,
-                  spec_sw, spec_sw, spec_tw, spec_tw, spec_fh, spec_fh],
-        out_specs=[spec_o, spec_o],
-        out_shape=out_shape,
-        interpret=interpret,
-        name="coded_rfft_bucket_masked",
-    )(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
-      swr, swi, twr, twi, fhr, fhi)
+    return _rbucket_call(xr, [masks], gr, gi, far, fai, wr, wi, fbr, fbi,
+                         swr, swi, twr, twi, fhr, fhi, s, block_q, interpret,
+                         "coded_rfft_bucket_masked")
 
 
 # ===================================================== real-output (c2r) path
@@ -607,40 +680,34 @@ def ir_unpack_body(hr, hi):
     return jnp.transpose(op, (0, 2, 1)).reshape(bq, m * ell)
 
 
+def _ir_message_views(yr, yi, fpr, fpi, ctwr, ctwi, pwr, pwi, s, m, a, b):
+    # conj of the packed message planes: the first half of the conj trick
+    # ifft(G @ z) = conj(fft(conj(G) @ conj(z))) / (L/2)
+    zr, zi = ir_message_body(yr, yi, fpr, fpi, ctwr, ctwi, pwr, pwi, s, m)
+    q = yr.shape[0]
+    return zr.reshape(q, m, a, b), (-zi).reshape(q, m, a, b)
+
+
 def irbucket_body(yr, yi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                   fpr, fpi, ctwr, ctwi, pwr, pwi, s):
-    """The full c2r pipeline on one (bq, s//2+1) block of half-spectrum
-    requests -- the last of the four kinds to get a whole-bucket body
-    (DESIGN.md §9; before this, c2r ran the stage path on TPU and the
-    direct body off-TPU).
+    """The full c2r pipeline on a (bq, s//2+1) block of half-spectrum
+    requests (DESIGN.md §9).
 
     Same stage skeleton as :func:`rbucket_body` run in reverse: adjoint
-    message butterfly (:func:`ir_message_body`), fused encode + HALF-length
-    ifft worker, batched scatter decode, relabel unpack.  The ifft worker
-    rides the forward four-step planes via the conj trick on planes --
-    ``ifft(G @ z) = conj(fft(conj(G) @ conj(z))) / (L/2)`` is two sign
-    flips of imaginary planes around :func:`encode_fourstep_body` plus one
-    rescale, so no inverse DFT planes exist anywhere.  The four-step's
-    scrambled payload order is carried through decode (decode only mixes
-    the shard axis) and undone just before the pair unpack, which needs
-    natural order.  Returns ONE real (bq, s) plane.
+    message butterfly (:func:`ir_message_body`), the coded core with a
+    HALF-length ifft worker, relabel unpack.  The ifft worker rides the
+    forward four-step planes via the conj trick on planes -- two sign
+    flips of imaginary planes around the forward core plus one rescale
+    (``coded_core_body(inverse=True)``), so no inverse DFT planes exist
+    anywhere.  Returns ONE real (bq, s) plane.
     """
-    bq = yr.shape[0]
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    n2 = a * b
-    zr, zi = ir_message_body(yr, yi, fpr, fpi, ctwr, ctwi, pwr, pwi, s, m)
-    er, ei = encode_fourstep_body(
-        zr.reshape(bq, m, a, b), (-zi).reshape(bq, m, a, b), gr, -gi,
-        far, fai, wr, wi, fbr, fbi)              # (bq, n, a, b) scrambled
-    er = er.reshape(bq, n, n2) / n2
-    ei = ei.reshape(bq, n, n2) / (-n2)           # conj + 1/(L/2): the ifft
-    hr, hi = bcmatmul_body(dr, di, er, ei)
-    # unscramble: scr[c*B + d] holds B[c + d*A] -> natural flat index d*A + c
-    hr = hr.reshape(bq, m, a, b).transpose(0, 1, 3, 2).reshape(bq, m, n2)
-    hi = hi.reshape(bq, m, a, b).transpose(0, 1, 3, 2).reshape(bq, m, n2)
-    return ir_unpack_body(hr, hi)
+    m = gr.shape[1]
+    a, b = far.shape[0], fbr.shape[0]
+    zr, zi = _ir_message_views(yr, yi, fpr, fpi, ctwr, ctwi, pwr, pwi,
+                               s, m, a, b)
+    hr, hi = coded_core_body(zr, zi, dr, di, gr, -gi, far, fai, wr, wi,
+                             fbr, fbi, inverse=True)
+    return ir_unpack_body(unscramble_planes(hr), unscramble_planes(hi))
 
 
 def irbucket_body_masked(yr, yi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
@@ -648,8 +715,7 @@ def irbucket_body_masked(yr, yi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
     """:func:`irbucket_body` with in-kernel subset selection + in-VMEM
     Lagrange decode matrices (cf. :func:`bucket_body_masked`)."""
     n, m = gr.shape
-    subsets = subsets_from_masks_body(masks, m)
-    _, _, dr, di = lagrange_planes_body(subsets, n)
+    dr, di = _masked_decode_planes(masks, m, n)
     return irbucket_body(yr, yi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                          fpr, fpi, ctwr, ctwi, pwr, pwi, s)
 
@@ -677,52 +743,23 @@ def irbucket_body_fftworker(yr, yi, dvr, dvi, subsets, gr, gi,
     return ir_unpack_body(hr, hi)
 
 
-def _irbucket_kernel(s):
-    def kernel(yr_ref, yi_ref, dr_ref, di_ref, gr_ref, gi_ref,
-               far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
-               fpr_ref, fpi_ref, ctwr_ref, ctwi_ref, pwr_ref, pwi_ref,
-               o_ref):
-        o_ref[...] = irbucket_body(
-            yr_ref[...], yi_ref[...], dr_ref[...], di_ref[...],
-            gr_ref[...], gi_ref[...], far_ref[...], fai_ref[...],
-            wr_ref[...], wi_ref[...], fbr_ref[...], fbi_ref[...],
-            fpr_ref[...], fpi_ref[...], ctwr_ref[...], ctwi_ref[...],
-            pwr_ref[...], pwi_ref[...], s)
-
-    return kernel
-
-
-def _irbucket_specs(s, m, n, a, b, block_q, masked: bool):
-    ell = a * b * 2
-    sh = s // 2 + 1
-    spec_y = pl.BlockSpec((block_q, sh), lambda i: (i, 0))
-    spec_o = pl.BlockSpec((block_q, s), lambda i: (i, 0))
-    decode = ([pl.BlockSpec((block_q, n), lambda i: (i, 0))] if masked
-              else [pl.BlockSpec((block_q, m, n), lambda i: (i, 0, 0))] * 2)
-    shared = [
-        pl.BlockSpec((n, m), lambda i: (0, 0)),       # gr
-        pl.BlockSpec((n, m), lambda i: (0, 0)),       # gi
-        pl.BlockSpec((a, a), lambda i: (0, 0)),       # far
-        pl.BlockSpec((a, a), lambda i: (0, 0)),       # fai
-        pl.BlockSpec((a, b), lambda i: (0, 0)),       # wr
-        pl.BlockSpec((a, b), lambda i: (0, 0)),       # wi
-        pl.BlockSpec((b, b), lambda i: (0, 0)),       # fbr
-        pl.BlockSpec((b, b), lambda i: (0, 0)),       # fbi
-        pl.BlockSpec((m, m), lambda i: (0, 0)),       # fpr
-        pl.BlockSpec((m, m), lambda i: (0, 0)),       # fpi
-        pl.BlockSpec((m, ell), lambda i: (0, 0)),     # ctwr
-        pl.BlockSpec((m, ell), lambda i: (0, 0)),     # ctwi
-        pl.BlockSpec((1, ell // 2 + 1), lambda i: (0, 0)),   # pwr
-        pl.BlockSpec((1, ell // 2 + 1), lambda i: (0, 0)),   # pwi
-    ]
-    return [spec_y, spec_y, *decode, *shared], spec_o
+def _irbucket_call(yr, yi, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+                   fpr, fpi, ctwr, ctwi, pwr, pwi, s, block_q, interpret, name):
+    m = gr.shape[1]
+    a, b = far.shape[0], fbr.shape[0]
+    zr, zi = _ir_message_views(yr, yi, fpr, fpi, ctwr, ctwi, pwr, pwi,
+                               s, m, a, b)
+    hr, hi = _core_call(zr, zi, decode, gr, -gi, far, fai, wr, wi, fbr, fbi,
+                        inverse=True, block_q=block_q, interpret=interpret,
+                        name=name)
+    return ir_unpack_body(unscramble_planes(hr), unscramble_planes(hi))
 
 
 def coded_irfft_bucket(yr, yi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
                        fpr, fpi, ctwr, ctwi, pwr, pwi, s, *, block_q: int = 1,
                        interpret: bool = False):
     """Fused c2r bucket pipeline: half-spectrum request planes -> ONE real
-    output plane, one Pallas launch per grid step (DESIGN.md §9).
+    output plane, the coded core as one Pallas launch (DESIGN.md §9).
 
     ``yr, yi``: (q, s//2+1) request planes; ``dr, di``: (q, m, N) scatter
     decode matrices; ``far/wr/fbr``: four-step planes for the HALF length
@@ -732,37 +769,9 @@ def coded_irfft_bucket(yr, yi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
     ``irfft(y, n=s, axis=-1)`` decoded from the masked worker subset each
     ``D_q`` encodes.
     """
-    q, _ = yr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    block_q = max(1, min(block_q, q))
-    in_specs, spec_o = _irbucket_specs(s, m, n, a, b, block_q, masked=False)
-    return pl.pallas_call(
-        _irbucket_kernel(s),
-        grid=(pl.cdiv(q, block_q),),
-        in_specs=in_specs,
-        out_specs=spec_o,
-        out_shape=jax.ShapeDtypeStruct((q, s), yr.dtype),
-        interpret=interpret,
-        name="coded_irfft_bucket",
-    )(yr, yi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
-      fpr, fpi, ctwr, ctwi, pwr, pwi)
-
-
-def _irbucket_kernel_masked(s):
-    def kernel(yr_ref, yi_ref, mk_ref, gr_ref, gi_ref,
-               far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
-               fpr_ref, fpi_ref, ctwr_ref, ctwi_ref, pwr_ref, pwi_ref,
-               o_ref):
-        o_ref[...] = irbucket_body_masked(
-            yr_ref[...], yi_ref[...], mk_ref[...],
-            gr_ref[...], gi_ref[...], far_ref[...], fai_ref[...],
-            wr_ref[...], wi_ref[...], fbr_ref[...], fbi_ref[...],
-            fpr_ref[...], fpi_ref[...], ctwr_ref[...], ctwi_ref[...],
-            pwr_ref[...], pwi_ref[...], s)
-
-    return kernel
+    return _irbucket_call(yr, yi, [dr, di], gr, gi, far, fai, wr, wi,
+                          fbr, fbi, fpr, fpi, ctwr, ctwi, pwr, pwi, s,
+                          block_q, interpret, "coded_irfft_bucket")
 
 
 def coded_irfft_bucket_masked(yr, yi, masks, gr, gi, far, fai, wr, wi,
@@ -772,147 +781,26 @@ def coded_irfft_bucket_masked(yr, yi, masks, gr, gi, far, fai, wr, wi,
     place of decode planes -- subset selection and the Lagrange weights are
     built in VMEM per grid step (DESIGN.md §8), completing the
     device-resident path for all four kinds."""
-    q, _ = yr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    block_q = max(1, min(block_q, q))
-    masks = masks.astype(yr.dtype)
-    in_specs, spec_o = _irbucket_specs(s, m, n, a, b, block_q, masked=True)
-    return pl.pallas_call(
-        _irbucket_kernel_masked(s),
-        grid=(pl.cdiv(q, block_q),),
-        in_specs=in_specs,
-        out_specs=spec_o,
-        out_shape=jax.ShapeDtypeStruct((q, s), yr.dtype),
-        interpret=interpret,
-        name="coded_irfft_bucket_masked",
-    )(yr, yi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
-      fpr, fpi, ctwr, ctwi, pwr, pwi)
-
-
-def _bucket_kernel(xr_ref, xi_ref, dr_ref, di_ref, gr_ref, gi_ref,
-                   far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
-                   twr_ref, twi_ref, fmr_ref, fmi_ref, or_ref, oi_ref):
-    or_ref[...], oi_ref[...] = bucket_body(
-        xr_ref[...], xi_ref[...], dr_ref[...], di_ref[...],
-        gr_ref[...], gi_ref[...], far_ref[...], fai_ref[...],
-        wr_ref[...], wi_ref[...], fbr_ref[...], fbi_ref[...],
-        twr_ref[...], twi_ref[...], fmr_ref[...], fmi_ref[...])
-
-
-def coded_fft_bucket(xr, xi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
-                     twr, twi, fmr, fmi, *, block_q: int = 1,
-                     interpret: bool = False):
-    """Fused bucket pipeline: request planes -> output spectrum planes.
-
-    ``xr, xi``: (q, s) request planes; ``dr, di``: (q, m, N) per-request
-    scatter decode matrices; ``gr, gi``: (N, m) generator;
-    ``far/wr/fbr``: four-step DFT/twiddle planes for L = s/m = A*B;
-    ``twr``: (m, L) recombine twiddle; ``fmr``: (m, m) DFT.
-    Returns (q, s) planes of ``fft(x, axis=-1)`` decoded from the masked
-    worker subset each ``D_q`` encodes.
-    """
-    q, s = xr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    ell = a * b
-    block_q = max(1, min(block_q, q))
-    spec_x = pl.BlockSpec((block_q, s), lambda i: (i, 0))
-    spec_d = pl.BlockSpec((block_q, m, n), lambda i: (i, 0, 0))
-    spec_g = pl.BlockSpec((n, m), lambda i: (0, 0))
-    spec_fa = pl.BlockSpec((a, a), lambda i: (0, 0))
-    spec_w = pl.BlockSpec((a, b), lambda i: (0, 0))
-    spec_fb = pl.BlockSpec((b, b), lambda i: (0, 0))
-    spec_tw = pl.BlockSpec((m, ell), lambda i: (0, 0))
-    spec_fm = pl.BlockSpec((m, m), lambda i: (0, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((q, s), xr.dtype),
-        jax.ShapeDtypeStruct((q, s), xr.dtype),
-    ]
-    return pl.pallas_call(
-        _bucket_kernel,
-        grid=(pl.cdiv(q, block_q),),
-        in_specs=[spec_x, spec_x, spec_d, spec_d, spec_g, spec_g,
-                  spec_fa, spec_fa, spec_w, spec_w, spec_fb, spec_fb,
-                  spec_tw, spec_tw, spec_fm, spec_fm],
-        out_specs=[spec_x, spec_x],
-        out_shape=out_shape,
-        interpret=interpret,
-        name="coded_fft_bucket",
-    )(xr, xi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi, twr, twi, fmr, fmi)
-
-
-def _bucket_kernel_masked(xr_ref, xi_ref, mk_ref, gr_ref, gi_ref,
-                          far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
-                          twr_ref, twi_ref, fmr_ref, fmi_ref, or_ref, oi_ref):
-    or_ref[...], oi_ref[...] = bucket_body_masked(
-        xr_ref[...], xi_ref[...], mk_ref[...],
-        gr_ref[...], gi_ref[...], far_ref[...], fai_ref[...],
-        wr_ref[...], wi_ref[...], fbr_ref[...], fbi_ref[...],
-        twr_ref[...], twi_ref[...], fmr_ref[...], fmi_ref[...])
-
-
-def coded_fft_bucket_masked(xr, xi, masks, gr, gi, far, fai, wr, wi,
-                            fbr, fbi, twr, twi, fmr, fmi, *, block_q: int = 1,
-                            interpret: bool = False):
-    """:func:`coded_fft_bucket` taking raw ``(q, N)`` responder masks in
-    place of the ``(q, m, N)`` decode planes.
-
-    Subset selection (first-m-available) AND the per-request Lagrange
-    decode matrices run INSIDE the kernel (VMEM-resident, DESIGN.md §8),
-    so the host ships the availability bits it already has -- zero decode
-    metadata, no host inversion or LRU at all.
-    """
-    q, s = xr.shape
-    n, m = gr.shape
-    a = far.shape[0]
-    b = fbr.shape[0]
-    ell = a * b
-    block_q = max(1, min(block_q, q))
-    masks = masks.astype(xr.dtype)
-    spec_x = pl.BlockSpec((block_q, s), lambda i: (i, 0))
-    spec_mk = pl.BlockSpec((block_q, n), lambda i: (i, 0))
-    spec_g = pl.BlockSpec((n, m), lambda i: (0, 0))
-    spec_fa = pl.BlockSpec((a, a), lambda i: (0, 0))
-    spec_w = pl.BlockSpec((a, b), lambda i: (0, 0))
-    spec_fb = pl.BlockSpec((b, b), lambda i: (0, 0))
-    spec_tw = pl.BlockSpec((m, ell), lambda i: (0, 0))
-    spec_fm = pl.BlockSpec((m, m), lambda i: (0, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((q, s), xr.dtype),
-        jax.ShapeDtypeStruct((q, s), xr.dtype),
-    ]
-    return pl.pallas_call(
-        _bucket_kernel_masked,
-        grid=(pl.cdiv(q, block_q),),
-        in_specs=[spec_x, spec_x, spec_mk, spec_g, spec_g,
-                  spec_fa, spec_fa, spec_w, spec_w, spec_fb, spec_fb,
-                  spec_tw, spec_tw, spec_fm, spec_fm],
-        out_specs=[spec_x, spec_x],
-        out_shape=out_shape,
-        interpret=interpret,
-        name="coded_fft_bucket_masked",
-    )(xr, xi, masks, gr, gi, far, fai, wr, wi, fbr, fbi, twr, twi, fmr, fmi)
+    return _irbucket_call(yr, yi, [masks], gr, gi, far, fai, wr, wi,
+                          fbr, fbi, fpr, fpi, ctwr, ctwi, pwr, pwi, s,
+                          block_q, interpret, "coded_irfft_bucket_masked")
 
 
 # ===================== streaming bucket: one launch beyond the VMEM budget
 #
-# The fused bucket kernel needs the whole (bq, s) working set VMEM-resident;
-# past ~1M elements the ops layer used to FALL BACK to the multi-launch
-# stage path.  The streaming kernel keeps the ONE-launch contract for
-# arbitrarily large (s, m): payload and the inter-stage scratch live in HBM
-# (ANY memory space) and the kernel hand-rolls double-buffered DMA over
-# column tiles (stage 1+2, column-local) then row tiles (stage 3 + encode +
-# decode + recombine, all row-local on the scrambled payload), staging tile
-# k+1 while tile k computes.  The input is VIEWED as (q, A, B, m) -- the
-# interleave relabeling composed with the four-step matrix view is still a
-# free reshape of the flat request -- and the output is written NATURALLY
-# ordered as (q, m, B, A) via an in-VMEM tile transpose, so no XLA
-# pre/post-pass brackets the launch.  Only the c2c bucket streams: the r2c
-# split butterfly pairs bin p with n2-p, which is not column-local, so the
-# real kinds keep the stage fallback for over-budget shapes.
+# The fused bucket kernel needs the whole (bq, m, A, B) working set
+# VMEM-resident; past ~1M elements the ops layer used to FALL BACK to the
+# multi-launch stage path.  The streaming kernel keeps the ONE-launch
+# contract for arbitrarily large (s, m): payload and the inter-stage
+# scratch live in HBM (ANY memory space) and the kernel hand-rolls
+# double-buffered DMA over column tiles (stage 1 + twiddle, column-local)
+# then row tiles (stage 3 + encode + decode + recombine, all row-local on
+# the scrambled payload), staging tile k+1 while tile k computes.  The
+# message planes arrive as (q, m, A, B) from the same XLA interleave as
+# the fused kernel, and the scrambled (q, m, A, B) output is unscrambled
+# by XLA.  Only the c2c bucket streams: the r2c split butterfly pairs bin
+# p with n2-p, which is not column-local, so the real kinds keep the
+# stage fallback for over-budget shapes.
 
 
 def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
@@ -939,8 +827,8 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
 
     # per-request decode planes, once per batch block (tiny: (bq, m, n))
     if masked:
-        subsets = subsets_from_masks_body(mk_ref[...], m)
-        _, _, dr, di = lagrange_planes_body(subsets, n)
+        mk = mk_ref[...]
+        dr, di = _masked_decode_planes(mk.reshape(bq, n), m, n)
     else:
         dr, di = dr_ref[...], di_ref[...]
 
@@ -949,10 +837,10 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
         cols = pl.ds(j * block_b, block_b)
         return (
             pltpu.make_async_copy(
-                xr_hbm.at[pl.ds(q0, bq), :, cols, :], abr.at[slot],
+                xr_hbm.at[pl.ds(q0, bq), :, :, cols], abr.at[slot],
                 sem_a.at[slot, 0]),
             pltpu.make_async_copy(
-                xi_hbm.at[pl.ds(q0, bq), :, cols, :], abi.at[slot],
+                xi_hbm.at[pl.ds(q0, bq), :, :, cols], abi.at[slot],
                 sem_a.at[slot, 1]),
         )
 
@@ -960,8 +848,6 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
         c.start()
     far = far_ref[...]
     fai = fai_ref[...]
-    wr = wr_ref[...]
-    wi = wi_ref[...]
 
     def phase_a(j, carry):
         slot = jax.lax.rem(j, 2)
@@ -973,26 +859,19 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
 
         for c in a_copies(j, slot):
             c.wait()
-        # column DFT per message shard: contract A, (bq, b-tile, m) folded
-        mr = abr[slot].transpose(1, 0, 2, 3).reshape(a, bq * block_b * m)
-        mi = abi[slot].transpose(1, 0, 2, 3).reshape(a, bq * block_b * m)
-        t1r, t1i = _cmul_mm(far, fai, mr, mi)
-        t1r = t1r.reshape(a, bq, block_b, m)
-        t1i = t1i.reshape(a, bq, block_b, m)
-        w_r = jax.lax.dynamic_slice_in_dim(
-            wr, j * block_b, block_b, 1)[:, None, :, None]
-        w_i = jax.lax.dynamic_slice_in_dim(
-            wi, j * block_b, block_b, 1)[:, None, :, None]
-        t2r = t1r * w_r - t1i * w_i
-        t2i = t1r * w_i + t1i * w_r
-        t1s_r[...] = t2r.transpose(1, 0, 2, 3)
-        t1s_i[...] = t2i.transpose(1, 0, 2, 3)
-        cols = pl.ds(j * block_b, block_b)
+        # column DFT per message shard: contract A, the B tile stays lanes
+        cols = pl.ds(pl.multiple_of(j * block_b, block_b), block_b)
+        t2r, t2i = stage1_body(
+            abr[slot].reshape(bq * m, a, block_b),
+            abi[slot].reshape(bq * m, a, block_b),
+            far, fai, wr_ref[:, cols], wi_ref[:, cols])
+        t1s_r[...] = t2r.reshape(bq, m, a, block_b)
+        t1s_i[...] = t2i.reshape(bq, m, a, block_b)
         outs = (
             pltpu.make_async_copy(
-                t1s_r, t1r_hbm.at[pl.ds(q0, bq), :, cols, :], sem_t1.at[0]),
+                t1s_r, t1r_hbm.at[pl.ds(q0, bq), :, :, cols], sem_t1.at[0]),
             pltpu.make_async_copy(
-                t1s_i, t1i_hbm.at[pl.ds(q0, bq), :, cols, :], sem_t1.at[1]),
+                t1s_i, t1i_hbm.at[pl.ds(q0, bq), :, :, cols], sem_t1.at[1]),
         )
         for c in outs:
             c.start()
@@ -1007,24 +886,21 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
         rows = pl.ds(i * block_a, block_a)
         return (
             pltpu.make_async_copy(
-                t1r_hbm.at[pl.ds(q0, bq), rows, :, :], bbr.at[slot],
+                t1r_hbm.at[pl.ds(q0, bq), :, rows, :], bbr.at[slot],
                 sem_b.at[slot, 0]),
             pltpu.make_async_copy(
-                t1i_hbm.at[pl.ds(q0, bq), rows, :, :], bbi.at[slot],
+                t1i_hbm.at[pl.ds(q0, bq), :, rows, :], bbi.at[slot],
                 sem_b.at[slot, 1]),
         )
 
     for c in b_copies(0, 0):
         c.start()
-    gr = gr_ref[...]
-    gi = gi_ref[...]
+    gr = jnp.broadcast_to(gr_ref[...], (bq, n, m))
+    gi = jnp.broadcast_to(gi_ref[...], (bq, n, m))
     fbr = fbr_ref[...]
     fbi = fbi_ref[...]
-    twr = twr_ref[...]
-    twi = twi_ref[...]
     fmr = fmr_ref[...]
     fmi = fmi_ref[...]
-    tile = block_a * b
 
     def phase_b(i, carry):
         slot = jax.lax.rem(i, 2)
@@ -1036,36 +912,27 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
 
         for c in b_copies(i, slot):
             c.wait()
-        # row DFT per shard: contract B with (bq, a-tile, m) folded in rows
-        tr = bbr[slot].transpose(0, 1, 3, 2).reshape(bq * block_a * m, b)
-        ti = bbi[slot].transpose(0, 1, 3, 2).reshape(bq * block_a * m, b)
-        s3r, s3i = _cmul_mm(tr, ti, fbr, fbi)
-        # MDS encode: contract the shard axis with G
-        s3r = s3r.reshape(bq, block_a, m, b).transpose(2, 0, 1, 3).reshape(m, -1)
-        s3i = s3i.reshape(bq, block_a, m, b).transpose(2, 0, 1, 3).reshape(m, -1)
-        er, ei = _cmul_mm(gr, gi, s3r, s3i)
-        er = er.reshape(n, bq, tile).transpose(1, 0, 2)
-        ei = ei.reshape(n, bq, tile).transpose(1, 0, 2)
-        # per-request decode (scrambled payload order carried through)
-        hr, hi = bcmatmul_body(dr, di, er, ei)
-        # recombine: the scrambled payload slice [c*B+d for c in tile i] is
-        # CONTIGUOUS, so the pre-scrambled twiddle slices per tile
-        tw_r = jax.lax.dynamic_slice_in_dim(twr, i * tile, tile, 1)[None]
-        tw_i = jax.lax.dynamic_slice_in_dim(twi, i * tile, tile, 1)[None]
-        ur = hr * tw_r - hi * tw_i
-        ui = hr * tw_i + hi * tw_r
-        ur = ur.transpose(1, 0, 2).reshape(m, bq * tile)
-        ui = ui.transpose(1, 0, 2).reshape(m, bq * tile)
-        outr, outi = _cmul_mm(fmr, fmi, ur, ui)
-        # natural order: out[j, q, c, d] -> output[q, j, d, c-tile]
-        obr[...] = outr.reshape(m, bq, block_a, b).transpose(1, 0, 3, 2)
-        obi[...] = outi.reshape(m, bq, block_a, b).transpose(1, 0, 3, 2)
-        cols = pl.ds(i * block_a, block_a)
+        # row DFT per shard: contract B with (bq, m, a-tile) folded in rows
+        s3r, s3i = stage2_body(bbr[slot].reshape(bq * m, block_a, b),
+                               bbi[slot].reshape(bq * m, block_a, b),
+                               fbr, fbi)
+        s3r = s3r.reshape(bq, m, block_a, b)
+        s3i = s3i.reshape(bq, m, block_a, b)
+        # MDS encode, then the per-request decode (scrambled order carried)
+        er, ei = shard_contract(gr, gi, s3r, s3i)
+        hr, hi = shard_contract(dr, di, er, ei)
+        # recombine: the scrambled payload rows of tile i pair with the
+        # same rows of the pre-scrambled (m, A, B) twiddle
+        rows = pl.ds(pl.multiple_of(i * block_a, block_a), block_a)
+        outr, outi = recombine_shards_body(
+            hr, hi, twr_ref[:, rows, :], twi_ref[:, rows, :], fmr, fmi)
+        obr[...] = outr
+        obi[...] = outi
         outs = (
             pltpu.make_async_copy(
-                obr, or_hbm.at[pl.ds(q0, bq), :, :, cols], sem_o.at[0]),
+                obr, or_hbm.at[pl.ds(q0, bq), :, rows, :], sem_o.at[0]),
             pltpu.make_async_copy(
-                obi, oi_hbm.at[pl.ds(q0, bq), :, :, cols], sem_o.at[1]),
+                obi, oi_hbm.at[pl.ds(q0, bq), :, rows, :], sem_o.at[1]),
         )
         for c in outs:
             c.start()
@@ -1076,13 +943,6 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
     jax.lax.fori_loop(0, nat, phase_b, 0)
 
 
-def _even_divisor(n: int, cap: int) -> int:
-    d = max(1, min(cap, n))
-    while n % d:
-        d -= 1
-    return d
-
-
 def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
                            wr, wi, fbr, fbi, twr, twi, fmr, fmi,
                            block_q, block_a, block_b, interpret, name):
@@ -1090,16 +950,13 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
     n, m = gr.shape
     a = far.shape[0]
     b = fbr.shape[0]
-    ell = a * b
     f32 = xr.dtype
-    # interleave + matrix view in one free reshape: x4[q, a, b, i] = M_i[a, b]
-    x4r = xr.reshape(q, a, b, m)
-    x4i = xi.reshape(q, a, b, m)
+    cr, ci = interleave_planes(xr, xi, m, a, b)
     block_q = max(1, min(block_q, q))
     pad = (-q) % block_q
     if pad:  # DMA tile sizes are static: round the batch up
-        x4r = jnp.concatenate([x4r, jnp.zeros((pad, a, b, m), f32)])
-        x4i = jnp.concatenate([x4i, jnp.zeros((pad, a, b, m), f32)])
+        cr = jnp.concatenate([cr, jnp.zeros((pad, m, a, b), f32)])
+        ci = jnp.concatenate([ci, jnp.zeros((pad, m, a, b), f32)])
         if masked:  # all-available filler keeps the Lagrange nodes distinct
             decode_args = [jnp.concatenate(
                 [decode_args[0], jnp.ones((pad, n), f32)])]
@@ -1113,35 +970,36 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
     nat = a // block_a
     nbt = b // block_b
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     def vspec(*shape):
         return pl.BlockSpec(shape, lambda i, r=len(shape): (0,) * r)
 
     if masked:
-        decode_specs = [pl.BlockSpec((block_q, n), lambda i: (i, 0))]
+        decode_args = [decode_args[0].reshape(qp, 1, n)]
+        decode_specs = [pl.BlockSpec((block_q, 1, n), lambda i: (i, 0, 0))]
     else:
         decode_specs = [
             pl.BlockSpec((block_q, m, n), lambda i: (i, 0, 0))] * 2
     in_specs = [any_spec, any_spec, *decode_specs,
                 vspec(n, m), vspec(n, m), vspec(a, a), vspec(a, a),
                 vspec(a, b), vspec(a, b), vspec(b, b), vspec(b, b),
-                vspec(m, ell), vspec(m, ell), vspec(m, m), vspec(m, m)]
+                vspec(m, a, b), vspec(m, a, b), vspec(m, m), vspec(m, m)]
     out_shape = [
-        jax.ShapeDtypeStruct((qp, m, b, a), f32),   # natural-order output
-        jax.ShapeDtypeStruct((qp, m, b, a), f32),
-        jax.ShapeDtypeStruct((qp, a, b, m), f32),   # t1 HBM scratch
-        jax.ShapeDtypeStruct((qp, a, b, m), f32),
+        jax.ShapeDtypeStruct((qp, m, a, b), f32),   # scrambled output
+        jax.ShapeDtypeStruct((qp, m, a, b), f32),
+        jax.ShapeDtypeStruct((qp, m, a, b), f32),   # t1 HBM scratch
+        jax.ShapeDtypeStruct((qp, m, a, b), f32),
     ]
     scratch = [
-        pltpu.VMEM((2, block_q, a, block_b, m), f32),   # phase A in (x2)
-        pltpu.VMEM((2, block_q, a, block_b, m), f32),
-        pltpu.VMEM((block_q, a, block_b, m), f32),      # phase A staging
-        pltpu.VMEM((block_q, a, block_b, m), f32),
-        pltpu.VMEM((2, block_q, block_a, b, m), f32),   # phase B in (x2)
-        pltpu.VMEM((2, block_q, block_a, b, m), f32),
-        pltpu.VMEM((block_q, m, b, block_a), f32),      # phase B staging
-        pltpu.VMEM((block_q, m, b, block_a), f32),
+        pltpu.VMEM((2, block_q, m, a, block_b), f32),   # phase A in (x2)
+        pltpu.VMEM((2, block_q, m, a, block_b), f32),
+        pltpu.VMEM((block_q, m, a, block_b), f32),      # phase A staging
+        pltpu.VMEM((block_q, m, a, block_b), f32),
+        pltpu.VMEM((2, block_q, m, block_a, b), f32),   # phase B in (x2)
+        pltpu.VMEM((2, block_q, m, block_a, b), f32),
+        pltpu.VMEM((block_q, m, block_a, b), f32),      # phase B staging
+        pltpu.VMEM((block_q, m, block_a, b), f32),
         pltpu.SemaphoreType.DMA((2, 2)),
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2, 2)),
@@ -1155,11 +1013,13 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
         out_specs=[any_spec, any_spec, any_spec, any_spec],
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name=name,
-    )(x4r, x4i, *decode_args, gr, gi, far, fai, wr, wi, fbr, fbi,
-      twr, twi, fmr, fmi)
-    return outs[0][:q].reshape(q, s), outs[1][:q].reshape(q, s)
+    )(cr, ci, *decode_args, gr, gi, far, fai, wr, wi, fbr, fbi,
+      twr.reshape(m, a, b), twi.reshape(m, a, b), fmr, fmi)
+    return (unscramble_planes(outs[0][:q]).reshape(q, s),
+            unscramble_planes(outs[1][:q]).reshape(q, s))
 
 
 def coded_fft_bucket_streaming(xr, xi, dr, di, gr, gi, far, fai, wr, wi,

@@ -67,16 +67,56 @@ __all__ = [
     "fourstep_stage2",
     "encode_fourstep_body",
     "encode_fourstep_fused",
+    "shard_contract",
     "multistep_body",
     "multistep_fused",
     "fourstep_streaming",
 ]
 
 
+# Scoped-VMEM cap of the four-step and bucket kernels.  The compiler's
+# default scope (16 MiB on v5e) is below the working set of one
+# double-buffered 512 x 512 block with its DFT planes; v5e has 128 MiB of
+# VMEM per core.
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024)
+
+
+# f32 matmuls on the MXU default to a single bf16 pass on TPU; the DFT and
+# coding contractions need the full-f32 multi-pass product to meet the
+# f32 error budget the tests and the chip smoke hold them to.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _cmul_mm(ar, ai, br, bi):
     """Complex matmul on planes with f32 accumulation (4 real matmuls)."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.dot, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
     return dot(ar, br) - dot(ai, bi), dot(ar, bi) + dot(ai, br)
+
+
+def _cmul_einsum(spec, ar, ai, br, bi):
+    """Complex einsum on planes (4 real contractions, f32 accumulation)."""
+    ein = functools.partial(jnp.einsum, spec, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
+    return ein(ar, br) - ein(ai, bi), ein(ar, bi) + ein(ai, br)
+
+
+def _column_dft(xr, xi, far, fai):
+    """``F_A @ M`` for every (A, B) slab of a (p, A, B) stack.
+
+    The contraction runs over the sublane axis with the lane axis B left
+    in place, so no relayout of the payload is needed inside a Mosaic
+    kernel (folding the batch into the lane axis is not lowerable when B
+    is not a multiple of 128)."""
+    return _cmul_einsum("ca,pab->pcb", far, fai, xr, xi)
+
+
+def _row_dft(xr, xi, fbr, fbi):
+    """``M @ F_B`` for every slab of a (p, A, B) stack: the leading axes
+    fold into the matmul rows, a layout-free merge."""
+    p, a, b = xr.shape
+    tr, ti = _cmul_mm(xr.reshape(p * a, b), xi.reshape(p * a, b), fbr, fbi)
+    return tr.reshape(p, a, b), ti.reshape(p, a, b)
 
 
 def fourstep_body(xr, xi, far, fai, wr, wi, fbr, fbi):
@@ -84,27 +124,13 @@ def fourstep_body(xr, xi, far, fai, wr, wi, fbr, fbi):
 
     Shared between the Pallas kernel (one block per grid step) and the
     off-TPU direct path, which evaluates the body on the full batch as
-    straight XLA (DESIGN.md §6).  The batch block is folded into the
-    contraction dims (columns for stage 1, rows for stage 3), so the MXU
-    sees two dense matmuls per call for any bq.
+    straight XLA (DESIGN.md §6).  Stage 1 contracts A per slab, stage 3
+    folds the batch into the rows of one dense matmul.
     """
-    bq, a, b = xr.shape
-    # step 1: column DFTs -- contract A with the batch folded into columns
-    mr = jnp.transpose(xr, (1, 0, 2)).reshape(a, bq * b)
-    mi = jnp.transpose(xi, (1, 0, 2)).reshape(a, bq * b)
-    t1r, t1i = _cmul_mm(far, fai, mr, mi)
-    t1r = t1r.reshape(a, bq, b)
-    t1i = t1i.reshape(a, bq, b)
-    # step 2: twiddle (elementwise, VPU), broadcast over the batch block
-    wr = wr[:, None, :]
-    wi = wi[:, None, :]
+    t1r, t1i = _column_dft(xr, xi, far, fai)
     t2r = t1r * wr - t1i * wi
     t2i = t1r * wi + t1i * wr
-    # step 3: row DFTs -- contract B with the batch folded into rows
-    rr = jnp.transpose(t2r, (1, 0, 2)).reshape(bq * a, b)
-    ri = jnp.transpose(t2i, (1, 0, 2)).reshape(bq * a, b)
-    t3r, t3i = _cmul_mm(rr, ri, fbr, fbi)
-    return t3r.reshape(bq, a, b), t3i.reshape(bq, a, b)
+    return _row_dft(t2r, t2i, fbr, fbi)
 
 
 def _fused_kernel(xr_ref, xi_ref, far_ref, fai_ref, wr_ref, wi_ref,
@@ -139,6 +165,7 @@ def fourstep_fused(xr, xi, far, fai, wr, wi, fbr, fbi, *, block_q: int = 1,
         in_specs=[spec_x, spec_x, spec_fa, spec_fa, spec_w, spec_w, spec_fb, spec_fb],
         out_specs=[spec_x, spec_x],
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="fourstep_fft_fused",
     )(xr, xi, far, fai, wr, wi, fbr, fbi)
@@ -150,30 +177,28 @@ def encode_fourstep_body(cr, ci, gr, gi, far, fai, wr, wi, fbr, fbi):
     ``c`` block: (bq, m, A, B) message planes; ``g``: (n, m) generator
     planes.  The DFT stages act per shard and the generator contraction
     acts across shards, so they commute: transforming the m message shards
-    first saves an N/m factor of DFT flops, and the encode is one more
-    (n, m) x (m, bq*A*B) MXU matmul on VMEM-resident data.
+    first saves an N/m factor of DFT flops, and the encode is one batched
+    (n, m) x (m, A, B) contraction on VMEM-resident data.  Returns
+    (bq, n, A, B) planes in the scrambled four-step order.
     """
     bq, m, a, b = cr.shape
     n = gr.shape[0]
-    # stage 1: column DFTs of every message shard -- contract A
-    mr = jnp.transpose(cr, (2, 0, 1, 3)).reshape(a, bq * m * b)
-    mi = jnp.transpose(ci, (2, 0, 1, 3)).reshape(a, bq * m * b)
-    t1r, t1i = _cmul_mm(far, fai, mr, mi)
-    t1r = t1r.reshape(a, bq, m, b)
-    t1i = t1i.reshape(a, bq, m, b)
-    # stage 2: twiddle, shared across batch and shard index
-    wr = wr[:, None, None, :]
-    wi = wi[:, None, None, :]
-    t2r = t1r * wr - t1i * wi
-    t2i = t1r * wi + t1i * wr
-    # stage 3: row DFTs -- contract B ((a, bq, m, b) rows are contiguous)
-    t3r, t3i = _cmul_mm(t2r.reshape(-1, b), t2i.reshape(-1, b), fbr, fbi)
-    # stage 4: MDS encode -- contract the shard axis m with G
-    t3r = t3r.reshape(a, bq, m, b).transpose(2, 1, 0, 3).reshape(m, -1)
-    t3i = t3i.reshape(a, bq, m, b).transpose(2, 1, 0, 3).reshape(m, -1)
-    er, ei = _cmul_mm(gr, gi, t3r, t3i)
-    return (er.reshape(n, bq, a, b).transpose(1, 0, 2, 3),
-            ei.reshape(n, bq, a, b).transpose(1, 0, 2, 3))
+    tr, ti = fourstep_body(cr.reshape(bq * m, a, b), ci.reshape(bq * m, a, b),
+                           far, fai, wr, wi, fbr, fbi)
+    return shard_contract(jnp.broadcast_to(gr, (bq, n, m)),
+                          jnp.broadcast_to(gi, (bq, n, m)),
+                          tr.reshape(bq, m, a, b), ti.reshape(bq, m, a, b))
+
+
+def shard_contract(dr, di, xr, xi):
+    """Per-request ``(k, i) x (i, A, B)`` contraction across the shard axis.
+
+    ``dr, di``: (bq, k, i) planes (a generator, a decode matrix or a DFT
+    across shards, one per request); ``xr, xi``: (bq, i, A, B).  The
+    payload keeps its (A, B) tile layout, so this lowers inside a Mosaic
+    kernel for any shard count.
+    """
+    return _cmul_einsum("qki,qicd->qkcd", dr, di, xr, xi)
 
 
 def _encode_fused_kernel(cr_ref, ci_ref, gr_ref, gi_ref, far_ref, fai_ref,
@@ -214,6 +239,7 @@ def encode_fourstep_fused(cr, ci, gr, gi, far, fai, wr, wi, fbr, fbi, *,
                   spec_w, spec_w, spec_fb, spec_fb],
         out_specs=[spec_o, spec_o],
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="encode_fourstep_fused",
     )(cr, ci, gr, gi, far, fai, wr, wi, fbr, fbi)
@@ -221,16 +247,8 @@ def encode_fourstep_fused(cr, ci, gr, gi, far, fai, wr, wi, fbr, fbi, *,
 
 def stage1_body(xr, xi, far, fai, wr, wi):
     """Column-blocked: out = (F_A @ M_block) * W_block, batch folded in."""
-    bq, a, bb = xr.shape
-    mr = jnp.transpose(xr, (1, 0, 2)).reshape(a, bq * bb)
-    mi = jnp.transpose(xi, (1, 0, 2)).reshape(a, bq * bb)
-    t1r, t1i = _cmul_mm(far, fai, mr, mi)
-    t1r = t1r.reshape(a, bq, bb)
-    t1i = t1i.reshape(a, bq, bb)
-    wr = wr[:, None, :]
-    wi = wi[:, None, :]
-    return (jnp.transpose(t1r * wr - t1i * wi, (1, 0, 2)),
-            jnp.transpose(t1r * wi + t1i * wr, (1, 0, 2)))
+    t1r, t1i = _column_dft(xr, xi, far, fai)
+    return t1r * wr - t1i * wi, t1r * wi + t1i * wr
 
 
 def _stage1_kernel(xr_ref, xi_ref, far_ref, fai_ref, wr_ref, wi_ref,
@@ -260,6 +278,7 @@ def fourstep_stage1(xr, xi, far, fai, wr, wi, *, block_q: int = 1,
         in_specs=[spec_x, spec_x, spec_fa, spec_fa, spec_w, spec_w],
         out_specs=[spec_x, spec_x],
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="fourstep_fft_stage1",
     )(xr, xi, far, fai, wr, wi)
@@ -267,10 +286,7 @@ def fourstep_stage1(xr, xi, far, fai, wr, wi, *, block_q: int = 1,
 
 def stage2_body(tr, ti, fbr, fbi):
     """Row-blocked: out = T_block @ F_B, batch folded into the rows."""
-    bq, ba, b = tr.shape
-    t3r, t3i = _cmul_mm(tr.reshape(bq * ba, b), ti.reshape(bq * ba, b),
-                        fbr, fbi)
-    return t3r.reshape(bq, ba, b), t3i.reshape(bq, ba, b)
+    return _row_dft(tr, ti, fbr, fbi)
 
 
 def _stage2_kernel(tr_ref, ti_ref, fbr_ref, fbi_ref, or_ref, oi_ref):
@@ -297,6 +313,7 @@ def fourstep_stage2(tr, ti, fbr, fbi, *, block_q: int = 1, block_a=256,
         in_specs=[spec_t, spec_t, spec_fb, spec_fb],
         out_specs=[spec_t, spec_t],
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="fourstep_fft_stage2",
     )(tr, ti, fbr, fbi)
@@ -397,6 +414,7 @@ def multistep_fused(xr, xi, planes, factors, *, block_q: int = 1,
         in_specs=in_specs,
         out_specs=[spec_x, spec_x],
         out_shape=out_shape,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="fourstep_fft_multistep",
     )(xr, xi, *planes)
@@ -440,8 +458,6 @@ def _streaming_kernel(nbt, nat, block_q, block_a, block_b,
         c.start()
     far = far_ref[...]
     fai = fai_ref[...]
-    wr = wr_ref[...]
-    wi = wi_ref[...]
 
     def phase_a(j, carry):
         slot = jax.lax.rem(j, 2)
@@ -453,13 +469,11 @@ def _streaming_kernel(nbt, nat, block_q, block_a, block_b,
 
         for c in a_copies(j, slot):
             c.wait()
-        tr, ti = stage1_body(
-            abr[slot], abi[slot], far, fai,
-            jax.lax.dynamic_slice_in_dim(wr, j * block_b, block_b, 1),
-            jax.lax.dynamic_slice_in_dim(wi, j * block_b, block_b, 1))
+        cols = pl.ds(pl.multiple_of(j * block_b, block_b), block_b)
+        tr, ti = stage1_body(abr[slot], abi[slot], far, fai,
+                             wr_ref[:, cols], wi_ref[:, cols])
         t1s_r[...] = tr
         t1s_i[...] = ti
-        cols = pl.ds(j * block_b, block_b)
         outs = (
             pltpu.make_async_copy(
                 t1s_r, t1r_hbm.at[pl.ds(q0, block_q), :, cols],
@@ -555,7 +569,7 @@ def fourstep_streaming(xr, xi, far, fai, wr, wi, fbr, fbi, *,
     nbt = b // block_b
     f32 = xr.dtype
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     def vspec(*shape):
         return pl.BlockSpec(shape, lambda i, r=len(shape): (0,) * r)
@@ -589,6 +603,7 @@ def fourstep_streaming(xr, xi, far, fai, wr, wi, fbr, fbi, *,
         out_specs=[any_spec, any_spec, any_spec, any_spec],
         out_shape=out_shape,
         scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name="fourstep_fft_streaming",
     )(xr, xi, far, fai, wr, wi, fbr, fbi)

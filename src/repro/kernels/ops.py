@@ -53,6 +53,7 @@ from repro.kernels.coded_pipeline import (
     coded_rfft_bucket,
     coded_rfft_bucket_masked,
     half_postdecode_body,
+    interleave_planes,
     ir_message_body,
     ir_unpack_body,
     irbucket_body,
@@ -64,6 +65,7 @@ from repro.kernels.coded_pipeline import (
     rbucket_body_fftworker,
     rbucket_body_masked,
     subsets_from_masks_body,
+    unscramble_planes,
 )
 from repro.kernels.fourstep_fft import (
     _parse_stage_planes,
@@ -169,13 +171,20 @@ def kernel_backend_supported(dtype) -> bool:
 
 
 def split_factor(n: int) -> tuple[int, int]:
-    """Factor ``n = a * b`` with a, b as close as possible (a <= b).
+    """Factor ``n = a * b`` for the four-step kernels (a <= b).
 
-    MXU-friendliness: prefers multiples of 128 when available; for powers of
-    two this returns (2^floor(k/2), 2^ceil(k/2)).  Primes fall back to
-    (1, n): stage 1 degenerates to the identity and stage 2 is one dense
-    DFT matmul.
+    MXU- and tile-friendliness: when 128 divides ``n`` the lane factor
+    ``b`` is the smallest multiple of 128 that is at least ``sqrt(n)`` and
+    divides ``n`` -- a lane-dense (A, B) tile is what lets every kernel
+    body lower on a TPU without relayouts.  Otherwise ``a, b`` are as
+    close as possible; primes fall back to (1, n): stage 1 degenerates to
+    the identity and stage 2 is one dense DFT matmul.
     """
+    if n >= 128 and n % 128 == 0:
+        k = max(1, -(-math.isqrt(n) // 128))
+        while n % (128 * k):
+            k += 1
+        return n // (128 * k), 128 * k
     a = int(math.isqrt(n))
     while a > 1 and n % a != 0:
         a -= 1
@@ -371,14 +380,11 @@ def fourstep_planar(xr: jax.Array, xi: jax.Array, *,
     fbr, fbi = _dft_planes(b, dt)
     wr, wi = _twiddle_planes(a, b, dt)
     if variant == "streaming" and mode != "direct":
-        ent = autotune.lookup("fourstep", L=ell, mode=mode) or {}
+        bq, ba, bb = _streaming_blocks("fourstep", mode, L=ell)
         outr, outi = fourstep_streaming(
             xr.reshape(batch, a, b), xi.reshape(batch, a, b),
             far, fai, wr, wi, fbr, fbi,
-            block_q=int(ent.get("block_q", 1) or 1),
-            block_a=int(ent.get("block_a", 256) or 256),
-            block_b=int(ent.get("block_b", 256) or 256),
-            interpret=itp)
+            block_q=bq, block_a=ba, block_b=bb, interpret=itp)
         # natural-order (batch, B, A) output: flat X, no unscramble
         return outr.reshape(batch, ell), outi.reshape(batch, ell)
     if variant == "streaming":
@@ -593,13 +599,34 @@ def coded_bucket_streamable(s: int, m: int, n: int) -> bool:
             and m * ell <= 4 * _FUSED_MAX_ELEMS)
 
 
+# Default streaming tile edge: the Mosaic compile time of the streaming
+# kernels grows with the tile's volume (about a minute at 128 for
+# s = 2^20, three at 256, on the v5e compiler).
+_STREAM_TILE = 128
+
+
 def _streaming_blocks(kind: str, mode: str, **params):
     """(block_q, block_a, block_b) for a streaming launch: tuned entry if
-    the autotune table has one, else the 256-tile default."""
+    the autotune table has one, else the default tile."""
     ent = autotune.lookup(kind, mode=mode, **params) or {}
     return (max(1, int(ent.get("block_q", 1) or 1)),
-            int(ent.get("block_a", 256) or 256),
-            int(ent.get("block_b", 256) or 256))
+            int(ent.get("block_a", _STREAM_TILE) or _STREAM_TILE),
+            int(ent.get("block_b", _STREAM_TILE) or _STREAM_TILE))
+
+
+def _bucket_direct(body, xr, xi, decode, gr, gi, planes):
+    """The c2c bucket body on the full batch as straight XLA: the same
+    interleave -> body -> unscramble the kernel wrappers run around their
+    launch."""
+    q, s = xr.shape
+    m = gr.shape[1]
+    a, b = planes[0].shape[0], planes[4].shape[0]
+    twr, twi = (p.reshape(m, a, b) for p in planes[6:8])
+    cr, ci = interleave_planes(xr, xi, m, a, b)
+    yr, yi = body(cr, ci, *decode, gr, gi, *planes[:6], twr, twi,
+                  *planes[8:])
+    return (unscramble_planes(yr).reshape(q, s),
+            unscramble_planes(yi).reshape(q, s))
 
 
 def coded_bucket(xr: jax.Array, xi: jax.Array,
@@ -628,7 +655,7 @@ def coded_bucket(xr: jax.Array, xi: jax.Array,
     planes = (*_dft_planes(a, dt), *_twiddle_planes(a, b, dt),
               *_dft_planes(b, dt), *_recombine_planes_scrambled(s, m, a, b, dt))
     if mode == "direct":
-        return bucket_body(xr, xi, dr, di, gr, gi, *planes)
+        return _bucket_direct(bucket_body, xr, xi, [dr, di], gr, gi, planes)
     itp = mode == "interpret"
     if not coded_bucket_fusable(s, m, n) and coded_bucket_streamable(s, m, n):
         bq, ba, bb = _streaming_blocks("bucket", mode, s=s, m=m, n=n)
@@ -665,7 +692,8 @@ def coded_bucket_masked(xr: jax.Array, xi: jax.Array, masks: jax.Array,
     planes = (*_dft_planes(a, dt), *_twiddle_planes(a, b, dt),
               *_dft_planes(b, dt), *_recombine_planes_scrambled(s, m, a, b, dt))
     if mode == "direct":
-        return bucket_body_masked(xr, xi, masks, gr, gi, *planes)
+        return _bucket_direct(bucket_body_masked, xr, xi, [masks], gr, gi,
+                              planes)
     itp = mode == "interpret"
     if not coded_bucket_fusable(s, m, n) and coded_bucket_streamable(s, m, n):
         bq, ba, bb = _streaming_blocks("bucket", mode, s=s, m=m, n=n)

@@ -28,7 +28,8 @@ __all__ = [
 
 def recombine_body(cr, ci, wr, wi, fr, fi):
     """One recombine block: twiddle in VMEM (never hits HBM) + m-DFT."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     tr = cr * wr - ci * wi
     ti = cr * wi + ci * wr
     return dot(fr, tr) - dot(fi, ti), dot(fr, ti) + dot(fi, tr)
@@ -68,7 +69,8 @@ def recombine_twiddle_dft(
 def recombine_batched_body(cr, ci, wr, wi, fr, fi):
     """Batched recombine block: the twiddle/DFT planes are shared across
     the bucket, so the batch block folds into the matmul columns."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     bq, m, bl = cr.shape
     wr = wr[None]                              # (1, m, bl)
     wi = wi[None]

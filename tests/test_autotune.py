@@ -141,3 +141,52 @@ def test_service_warmup_runs_search_once(fresh_cache):
     assert after_first > 0
     FFTService(cfg).warmup(kinds=("c2c",))
     assert autotune.searches_run() == after_first
+
+
+def test_failing_candidate_is_reported_and_skipped(fresh_cache, monkeypatch):
+    """A candidate that cannot lower or compile is warned about with its
+    error and skipped; the search still records a measured winner."""
+    real = autotune._fourstep_candidate_fn
+
+    def two_pass_refused(variant, factors, interpret):
+        if variant != "two_pass":
+            return real(variant, factors, interpret)
+
+        def fn(xr, xi):
+            raise RuntimeError("refused by the compiler")
+
+        return fn
+
+    monkeypatch.setattr(autotune, "_fourstep_candidate_fn", two_pass_refused)
+    with pytest.warns(RuntimeWarning,
+                      match="two_pass.*refused by the compiler"):
+        ent = autotune.tune_fourstep(64, batch=2, mode="direct", reps=1)
+    assert ent["variant"] != "two_pass"
+    assert np.isfinite(ent["ms"])
+
+
+def test_search_without_a_working_candidate_raises(fresh_cache, monkeypatch):
+    """Every candidate failing is an error, not a recorded NaN default:
+    a device that cannot run the kernels shows up at warmup."""
+    def refused(variant, factors, interpret):
+        def fn(xr, xi):
+            raise RuntimeError("refused")
+
+        return fn
+
+    monkeypatch.setattr(autotune, "_fourstep_candidate_fn", refused)
+    with pytest.warns(RuntimeWarning), \
+            pytest.raises(autotune.AutotuneError,
+                          match="every candidate failed"):
+        autotune.tune_fourstep(64, batch=2, mode="compiled", reps=1)
+    assert autotune.lookup("fourstep", L=64, mode="compiled") is None
+
+    def bucket_refused(*args, **kwargs):
+        raise RuntimeError("block refused")
+
+    monkeypatch.setattr(ops, "coded_bucket_masked", bucket_refused)
+    with pytest.warns(RuntimeWarning, match="block_q=.*block refused"), \
+            pytest.raises(autotune.AutotuneError):
+        autotune.tune_bucket("bucket", 64, 2, 4, q=2, mode="compiled",
+                             reps=1)
+    assert autotune.lookup("bucket", s=64, m=2, n=4, mode="compiled") is None

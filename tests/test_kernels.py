@@ -80,9 +80,14 @@ def test_fourstep_two_pass_matches_fused():
 
 
 def test_split_factor():
-    assert split_factor(4096) == (64, 64)
-    assert split_factor(2048) == (32, 64)
-    assert split_factor(384) in [(16, 24), (12, 32)] or np.prod(split_factor(384)) == 384
+    # lane factor b: the smallest multiple of 128 >= sqrt(n) dividing n
+    assert split_factor(4096) == (32, 128)
+    assert split_factor(2048) == (16, 128)
+    assert split_factor(65536) == (256, 256)
+    assert split_factor(1 << 18) == (512, 512)
+    assert split_factor(384) == (3, 128)
+    # no lane-aligned split: as square as possible
+    assert split_factor(96) == (8, 12)
     a, b = split_factor(1)
     assert a * b == 1
 

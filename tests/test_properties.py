@@ -519,3 +519,31 @@ def test_bf16_probe_auto_disables_per_shape(monkeypatch, tmp_path):
         autotune._TABLES.update(saved)
         autotune._LOADED.clear()
         autotune._LOADED.update(saved_loaded)
+
+
+def test_bf16_probe_catches_only_the_bf16_variant(monkeypatch):
+    """The probe may find the bf16 planes unsupported (a warning and
+    ``False``); a failure of the f32 twin it compares against is a real
+    fault of the production kernel and propagates."""
+    from repro.kernels import ops
+    from repro.serving.fft_service import FFTService, FFTServiceConfig
+
+    svc = FFTService(FFTServiceConfig(s=64, m=2, n_workers=4,
+                                      autotune=False))
+    real = ops.coded_bucket_masked
+
+    def bf16_refused(*args, precision="f32", **kwargs):
+        if precision == "bf16":
+            raise RuntimeError("bf16 refused")
+        return real(*args, precision=precision, **kwargs)
+
+    monkeypatch.setattr(ops, "coded_bucket_masked", bf16_refused)
+    with pytest.warns(RuntimeWarning, match="bf16 refused"):
+        assert svc._probe_bf16(64, "c2c") is False
+
+    def f32_refused(*args, **kwargs):
+        raise RuntimeError("f32 refused")
+
+    monkeypatch.setattr(ops, "coded_bucket_masked", f32_refused)
+    with pytest.raises(RuntimeError, match="f32 refused"):
+        svc._probe_bf16(64, "c2c")
