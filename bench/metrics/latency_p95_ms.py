@@ -1,0 +1,8 @@
+"""95th percentile latency over every request due in the window, from due to resolved."""
+
+import numpy as np
+
+
+def read(run):
+    lat = run.latencies_s
+    return float(np.percentile(lat, 95) * 1e3) if lat.size else None
