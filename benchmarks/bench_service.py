@@ -310,7 +310,6 @@ def _open_loop_section(lines: list[str]) -> dict:
                 "deadline_dispatches": st["deadline_dispatches"],
                 "drain_dispatches": st["drain_dispatches"],
                 "queue_peak": st["queue_peak"],
-                "staging_overlap_s": st["staging_overlap_s"],
             })
             lines.append(
                 f"  open-loop[{mode}] {rate} rps: p50 "

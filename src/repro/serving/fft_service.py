@@ -79,6 +79,8 @@ from repro.distributed.worker_runtime import MeasuredWorkerRuntime
 from repro.kernels import autotune, ops, ref
 from repro.serving.batching import LatencyHistogram, bucket_size
 from repro.serving.decode_cache import DecodeMatrixCache
+from repro.serving.spans import (FETCH_COPY, FETCH_WAIT, STAGE_H2D,
+                                 STAGE_LAUNCH, STAGE_PACK, span)
 
 __all__ = ["DegradedResult", "FAILURE_REASONS", "FFTService",
            "FFTServiceConfig", "ServiceError", "ServiceStats"]
@@ -258,8 +260,6 @@ class ServiceStats:
     deadline_dispatches: int = 0   # ... because the earliest deadline
     #                                across bucket heads expired (EDF)
     drain_dispatches: int = 0      # ... flushed by drain()/close()
-    staging_overlap_s: float = 0.0  # host staging wall time hidden behind
-    #                                 a downstream bucket's device compute
     # -- fault-tolerant runtime observables (§12) -----------------------
     retries: int = 0               # retry rounds performed (window extensions)
     redispatched_shards: int = 0   # shard computations re-dispatched to
@@ -295,7 +295,6 @@ class ServiceStats:
             "fill_dispatches": self.fill_dispatches,
             "deadline_dispatches": self.deadline_dispatches,
             "drain_dispatches": self.drain_dispatches,
-            "staging_overlap_s": self.staging_overlap_s,
             "retries": self.retries,
             "redispatched_shards": self.redispatched_shards,
             "degraded": self.degraded,
@@ -1223,12 +1222,19 @@ class FFTService:
 
         The streaming syncer calls this instead of ``jax.device_get`` so
         the robust path's per-row :class:`ServiceError` objects never go
-        through a device transfer (host rows pass straight through)."""
+        through a device transfer (host rows pass straight through).  It
+        waits for the device, then copies: the copy cannot start before
+        the result is ready either way, and the two steps are spans of
+        their own."""
+        errors = None
         if isinstance(out, _Launched):
-            rows = (out.out if isinstance(out.out, np.ndarray)
-                    else jax.device_get(out.out))
-            return rows, out.errors
-        return jax.device_get(out), None
+            out, errors = out.out, out.errors
+            if isinstance(out, np.ndarray):
+                return out, errors
+        with span(FETCH_WAIT):
+            jax.block_until_ready(out)
+        with span(FETCH_COPY):
+            return jax.device_get(out), errors
 
     # ------------------------------------------------------------------
     def submit(self, x: jax.Array) -> np.ndarray:
@@ -1428,24 +1434,27 @@ class FFTService:
         path (``m > LAGRANGE_MAX_M`` or ``device_decode=False``): per-mask
         matrices from the host LRU, shared across every (s, kind) bucket.
         """
-        if self._kernel_path(s, kind) and not self._device_decode():
-            cache = self._decode_cache_for()
-            h0, m0 = cache.hits, cache.misses
-            if ops.default_interpret():
-                invs, subsets = cache.compact(masks)
-                dplanes = np.stack([invs.real, invs.imag]).astype(np.float32)
-                args = (jnp.asarray(xb), jnp.asarray(dplanes),
-                        jnp.asarray(subsets))
-            else:
-                dmats = cache.matrices(masks)
-                dplanes = np.stack([dmats.real, dmats.imag]).astype(np.float32)
-                args = (jnp.asarray(xb), jnp.asarray(dplanes))
-            # deltas, not lifetime cache totals: every other ServiceStats
-            # field accumulates, so a stats reset must window these too
-            self.stats.decode_cache_hits += cache.hits - h0
-            self.stats.decode_cache_misses += cache.misses - m0
-            return args
-        return (jnp.asarray(xb), jnp.asarray(masks))
+        with span(STAGE_H2D):
+            if self._kernel_path(s, kind) and not self._device_decode():
+                cache = self._decode_cache_for()
+                h0, m0 = cache.hits, cache.misses
+                if ops.default_interpret():
+                    invs, subsets = cache.compact(masks)
+                    dplanes = np.stack(
+                        [invs.real, invs.imag]).astype(np.float32)
+                    args = (jnp.asarray(xb), jnp.asarray(dplanes),
+                            jnp.asarray(subsets))
+                else:
+                    dmats = cache.matrices(masks)
+                    dplanes = np.stack(
+                        [dmats.real, dmats.imag]).astype(np.float32)
+                    args = (jnp.asarray(xb), jnp.asarray(dplanes))
+                # deltas, not lifetime cache totals: every other ServiceStats
+                # field accumulates, so a stats reset must window these too
+                self.stats.decode_cache_hits += cache.hits - h0
+                self.stats.decode_cache_misses += cache.misses - m0
+                return args
+            return (jnp.asarray(xb), jnp.asarray(masks))
 
     # -- staging seam (shared with serving/streaming.py, DESIGN.md §11) --
     def bucket_key(self, x, kind: str):
@@ -1480,22 +1489,25 @@ class FFTService:
         bucket = bucket_size(n_live, cfg.max_batch)
         self.stats.batches += 1
 
-        xb = self._bucket_buffer(s, bucket, kind)
-        real_in = kind in ("r2c", "rfftn")
-        for row, x in enumerate(reqs):
-            x = np.asarray(x)
-            xb[row] = x.real if real_in and np.iscomplexobj(x) else x
-        if self._robust:
-            # fault path: masks are derived at LAUNCH time -- the deadline/
-            # retry state machine mutates health + round state, which the
-            # launch step owns (stager-thread-confined on the streaming
-            # path, exactly like the non-robust service internals)
-            return bucket, (xb, n_live)
-        lat, mask = self._simulate_arrivals(n_live, kind)
-        self._account(lat, mask)
-        # padded rows: every worker "responds" so decode stays well-posed
-        masks = self._full_masks(s, kind, bucket)
-        masks[:n_live] = mask
+        with span(STAGE_PACK):
+            xb = self._bucket_buffer(s, bucket, kind)
+            real_in = kind in ("r2c", "rfftn")
+            for row, x in enumerate(reqs):
+                x = np.asarray(x)
+                xb[row] = x.real if real_in and np.iscomplexobj(x) else x
+            if self._robust:
+                # fault path: masks are derived at LAUNCH time -- the
+                # deadline/retry state machine mutates health + round
+                # state, which the launch step owns (stager-thread-confined
+                # on the streaming path, exactly like the non-robust
+                # service internals)
+                return bucket, (xb, n_live)
+            lat, mask = self._simulate_arrivals(n_live, kind)
+            self._account(lat, mask)
+            # padded rows: every worker "responds" so decode stays
+            # well-posed
+            masks = self._full_masks(s, kind, bucket)
+            masks[:n_live] = mask
         return bucket, self._bucket_args(s, kind, xb, masks)
 
     def launch_bucket(self, s, bucket: int, kind: str, args: tuple
@@ -1508,10 +1520,11 @@ class FFTService:
         (device/host rows + per-row errors); fetch it with
         :meth:`fetch_bucket` rather than ``jax.device_get``.
         """
-        if self._robust:
-            xb, n_live = args
-            return self._robust_launch(s, bucket, kind, xb, n_live)
-        return self._runner_for(s, bucket, kind)(*args)
+        with span(STAGE_LAUNCH):
+            if self._robust:
+                xb, n_live = args
+                return self._robust_launch(s, bucket, kind, xb, n_live)
+            return self._runner_for(s, bucket, kind)(*args)
 
     def _dispatch_bucket(self, s, idxs: list[int], xs,
                          kind: str = "c2c") -> jax.Array:

@@ -31,10 +31,15 @@ continuously-batching service with an SLO story:
 * **Double-buffered host->device staging** -- a dedicated staging
   thread packs bucket k+1's numpy buffers and launches its (async)
   device call while the sync thread is still blocked fetching bucket k.
-  ``ServiceStats.staging_overlap_s`` measures exactly the staging
-  sub-interval that ran while a downstream bucket was in flight
-  (explicit in-flight counter under the scheduler lock -- no unlocked
-  queue-internals peeking).
+* **Spans on the device trace's clock** -- every step of a bucket opens
+  a ``jax.profiler.TraceAnnotation`` named in ``serving/spans.py``
+  (recorded only while a profiler session runs).  One bucket's timeline
+  reads ``fft.bucket.form`` (scheduler) -> ``fft.bucket.stage`` =
+  ``fft.stage.pack`` + ``fft.stage.h2d`` + ``fft.stage.launch``
+  (stager) -> the kernel on the device's op line -> ``fft.bucket.fetch``
+  = ``fft.fetch.wait`` + ``fft.fetch.copy`` -> ``fft.bucket.resolve``
+  (syncer), all carrying the bucket's ``bucket`` number; the staging
+  overlap is where a stage span lies beside another bucket's fetch.
 
 The pipeline is three threads around two depth-bounded queues::
 
@@ -46,8 +51,9 @@ The pipeline is three threads around two depth-bounded queues::
         v
     sync_q   (depth 1  ==  double buffer: bucket k+1 stages/computes
         |                   while bucket k is being fetched)
-        v syncer: jax.device_get -> resolve futures -> latency histograms
-                  (one histogram per tier + the global one)
+        v syncer: wait for the device -> copy to the host -> resolve
+                  futures -> latency histograms (one histogram per tier
+                  + the global one)
 
 Every ``FFTService`` internal (plan/runner caches, the staging numpy
 work, ``stats.batches`` accounting) is touched ONLY by the staging
@@ -89,6 +95,8 @@ import numpy as np
 
 from repro.serving.batching import LatencyHistogram
 from repro.serving.fft_service import FFTService
+from repro.serving.spans import (BUCKET_FETCH, BUCKET_FORM, BUCKET_RESOLVE,
+                                 BUCKET_STAGE, span)
 
 __all__ = ["AdmissionError", "StreamConfig", "StreamingFFTService"]
 
@@ -162,6 +170,7 @@ class _BucketPlan:
     kind: str
     reqs: list
     reason: str                 # "fill" | "deadline" | "drain"
+    seq: int                    # bucket number: the spans' ``bucket`` arg
     stage_s: float = 0.0        # filled by the stager; the syncer adds its
     #                             sync share and feeds the compute EWMA
 
@@ -170,7 +179,7 @@ class StreamingFFTService:
     """Multi-tier EDF continuous batching over one :class:`FFTService`.
 
     The wrapped service's ``stats`` object is extended in place (queue
-    peak, dispatch reasons, staging overlap, cancellations, the global
+    peak, dispatch reasons, cancellations, the global
     AND per-tier latency histograms), so one ``ServiceStats.summary()``
     tells the whole story.
 
@@ -201,6 +210,7 @@ class StreamingFFTService:
         # when they surface.
         self._heads: list[tuple] = []
         self._seq = 0                    # submit counter (EDF tie-break)
+        self._buckets = 0                # bucket counter (span ``bucket``)
         self._gen = 0                    # flush generation counter
         self._flush_upto: Optional[int] = None   # drain gens <= this
         self._depth = 0                  # undispatched requests
@@ -208,13 +218,6 @@ class StreamingFFTService:
         self._closed = False
         # compute-time EWMA per (s, kind): stage + launch + sync seconds
         self._ewma: dict[tuple, float] = {}
-        # launched-but-not-yet-fetched buckets, and the "busy clock" that
-        # integrates the wall time with at least one bucket in flight --
-        # the overlap accounting reads this under the lock instead of
-        # racing on Queue.unfinished_tasks
-        self._inflight = 0
-        self._busy_total = 0.0
-        self._busy_since: Optional[float] = None
         self._stage_q: Queue = Queue(maxsize=max(1, scfg.stage_depth))
         self._sync_q: Queue = Queue(maxsize=1)
         self._threads = [threading.Thread(
@@ -401,26 +404,34 @@ class StreamingFFTService:
                 choice, reason = key, "deadline"
         if choice is None:
             return None
-        heap = self._pending[choice]
-        if reason == "drain":
-            # take only the requests inside the drain scope, EDF order
-            keep, take = [], []
-            while heap and len(take) < cap:
-                entry = heapq.heappop(heap)
-                (take if self._drains_locked(entry[2]) else keep).append(
-                    entry)
-            for entry in keep:
-                heapq.heappush(heap, entry)
-        else:
-            take = [heapq.heappop(heap) for _ in range(min(cap, len(heap)))]
-        if heap:
-            # re-index the new bucket head in the deadline heap
-            heapq.heappush(self._heads, (heap[0][0], heap[0][1], choice))
-        else:
-            del self._pending[choice]
-        self._depth -= len(take)
-        return _BucketPlan(choice[0], choice[1],
-                           [entry[2] for entry in take], reason)
+        with span(BUCKET_FORM) as ann:
+            heap = self._pending[choice]
+            if reason == "drain":
+                # take only the requests inside the drain scope, EDF order
+                keep, take = [], []
+                while heap and len(take) < cap:
+                    entry = heapq.heappop(heap)
+                    (take if self._drains_locked(entry[2]) else keep
+                     ).append(entry)
+                for entry in keep:
+                    heapq.heappush(heap, entry)
+            else:
+                take = [heapq.heappop(heap)
+                        for _ in range(min(cap, len(heap)))]
+            if heap:
+                # re-index the new bucket head in the deadline heap
+                heapq.heappush(self._heads, (heap[0][0], heap[0][1], choice))
+            else:
+                del self._pending[choice]
+            self._depth -= len(take)
+            self._buckets += 1
+            reqs = [entry[2] for entry in take]
+            waits = [now - r.arrival for r in reqs]
+            ann.set_metadata(bucket=self._buckets, reason=reason,
+                             n=len(reqs), wait_max_ms=max(waits) * 1e3,
+                             wait_mean_ms=sum(waits) / len(waits) * 1e3)
+        return _BucketPlan(choice[0], choice[1], reqs, reason,
+                           self._buckets)
 
     def _drains_locked(self, req: _Request) -> bool:
         """Is this request inside the current drain scope?  close()
@@ -439,27 +450,6 @@ class StreamingFFTService:
             return None
         return max(self._pending[key][0][0] - time.perf_counter(), 0.0)
 
-    # -- in-flight accounting (the staging-overlap clock) ---------------
-    def _busy_clock_locked(self, now: float) -> float:
-        """Total wall seconds, so far, with >= 1 launched-but-unfetched
-        bucket; differences of this clock measure exactly the overlapped
-        sub-interval of any window."""
-        busy = self._busy_total
-        if self._busy_since is not None:
-            busy += now - self._busy_since
-        return busy
-
-    def _inflight_inc_locked(self, now: float) -> None:
-        self._inflight += 1
-        if self._inflight == 1:
-            self._busy_since = now
-
-    def _inflight_dec_locked(self, now: float) -> None:
-        self._inflight -= 1
-        if self._inflight == 0:
-            self._busy_total += now - self._busy_since
-            self._busy_since = None
-
     # -- stager: numpy pack + H2D + async launch ------------------------
     def _stager(self) -> None:
         while True:
@@ -467,35 +457,33 @@ class StreamingFFTService:
             if plan is None:
                 break
             t0 = time.perf_counter()
-            with self._lock:
-                busy0 = self._busy_clock_locked(t0)
             try:
                 out = self._stage_and_launch(plan)
             except Exception as e:                # noqa: BLE001
                 self._resolve(plan, error=e)
                 continue
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            dt = time.perf_counter() - t0
             plan.stage_s = dt
             with self._lock:
-                # the sub-interval of [t0, t1] during which a downstream
-                # bucket was between launch and fetch-completion: the
-                # double-buffer win, measured -- not inferred from a
-                # point sample of queue internals
-                overlap = min(self._busy_clock_locked(t1) - busy0, dt)
                 self.stats.dispatch_s += dt
-                self.stats.staging_overlap_s += max(overlap, 0.0)
-                self._inflight_inc_locked(t1)
             self._sync_q.put((plan, out))
         self._sync_q.put(None)                    # sentinel for the syncer
 
     def _stage_and_launch(self, plan: _BucketPlan):
         svc = self.service
-        bucket, args = svc.stage_bucket(
-            plan.s, plan.kind, [r.x for r in plan.reqs])
-        return svc.launch_bucket(plan.s, bucket, plan.kind, args)
+        with span(BUCKET_STAGE, bucket=plan.seq, n=len(plan.reqs)):
+            bucket, args = svc.stage_bucket(
+                plan.s, plan.kind, [r.x for r in plan.reqs])
+            return svc.launch_bucket(plan.s, bucket, plan.kind, args)
 
     # -- syncer: one device->host fetch per bucket ----------------------
+    def _fetch(self, plan: _BucketPlan, out):
+        # fetch_bucket (not a bare device_get): the fault-tolerant path
+        # returns host rows plus per-row ServiceErrors, which must become
+        # per-request Future exceptions
+        with span(BUCKET_FETCH, bucket=plan.seq):
+            return self.service.fetch_bucket(out)
+
     def _syncer(self) -> None:
         while True:
             item = self._sync_q.get()
@@ -505,21 +493,14 @@ class StreamingFFTService:
             plan, out = item
             t0 = time.perf_counter()
             try:
-                # fetch_bucket (not a bare device_get): the fault-tolerant
-                # path returns host rows plus per-row ServiceErrors, which
-                # must become per-request Future exceptions
-                rows, row_errors = self.service.fetch_bucket(out)
+                rows, row_errors = self._fetch(plan, out)
             except Exception as e:                # noqa: BLE001
                 self._sync_q.task_done()
-                with self._lock:
-                    self._inflight_dec_locked(time.perf_counter())
                 self._resolve(plan, error=e)
                 continue
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            dt = time.perf_counter() - t0
             self._sync_q.task_done()
             with self._lock:
-                self._inflight_dec_locked(t1)
                 self.stats.sync_s += dt
                 self.stats.host_transfers += 1
                 self._record_compute_locked(
@@ -536,7 +517,7 @@ class StreamingFFTService:
             self._resolve(plan, error=e)
             return
         t1 = time.perf_counter()
-        rows, row_errors = self.service.fetch_bucket(out)
+        rows, row_errors = self._fetch(plan, out)
         t2 = time.perf_counter()
         with self._lock:
             self.stats.dispatch_s += t1 - t0
@@ -548,34 +529,35 @@ class StreamingFFTService:
     def _resolve(self, plan: _BucketPlan, rows=None,
                  error: Optional[Exception] = None,
                  row_errors: Optional[list] = None) -> None:
-        now = time.perf_counter()
-        with self._cv:
-            for req in plan.reqs:
-                self.stats.latency.record(now - req.arrival)
-                self.stats.tier_latency.setdefault(
-                    req.tier, LatencyHistogram()).record(now - req.arrival)
-            self._outstanding -= len(plan.reqs)
-            self._cv.notify_all()
-        # futures resolve OUTSIDE the lock: done-callbacks may re-enter
-        # submit()
-        cancelled = 0
-        for row, req in enumerate(plan.reqs):
-            req.future.latency_s = now - req.arrival
-            # claim the future first: a caller's .cancel() on a pending
-            # future would otherwise make set_result/set_exception raise
-            # InvalidStateError and kill this pipeline thread
-            if not req.future.set_running_or_notify_cancel():
-                cancelled += 1
-                continue
-            # a bucket-wide error beats per-row errors; a per-row
-            # ServiceError (fault path) fails ONLY its own request --
-            # the rest of the bucket resolves normally
-            err = error if error is not None else (
-                row_errors[row] if row_errors is not None else None)
-            if err is not None:
-                req.future.set_exception(err)
-            else:
-                req.future.set_result(rows[row])
-        if cancelled:
-            with self._lock:
-                self.stats.cancelled += cancelled
+        with span(BUCKET_RESOLVE, bucket=plan.seq):
+            now = time.perf_counter()
+            with self._cv:
+                for req in plan.reqs:
+                    self.stats.latency.record(now - req.arrival)
+                    self.stats.tier_latency.setdefault(
+                        req.tier, LatencyHistogram()).record(now - req.arrival)
+                self._outstanding -= len(plan.reqs)
+                self._cv.notify_all()
+            # futures resolve OUTSIDE the lock: done-callbacks may re-enter
+            # submit()
+            cancelled = 0
+            for row, req in enumerate(plan.reqs):
+                req.future.latency_s = now - req.arrival
+                # claim the future first: a caller's .cancel() on a pending
+                # future would otherwise make set_result/set_exception raise
+                # InvalidStateError and kill this pipeline thread
+                if not req.future.set_running_or_notify_cancel():
+                    cancelled += 1
+                    continue
+                # a bucket-wide error beats per-row errors; a per-row
+                # ServiceError (fault path) fails ONLY its own request --
+                # the rest of the bucket resolves normally
+                err = error if error is not None else (
+                    row_errors[row] if row_errors is not None else None)
+                if err is not None:
+                    req.future.set_exception(err)
+                else:
+                    req.future.set_result(rows[row])
+            if cancelled:
+                with self._lock:
+                    self.stats.cancelled += cancelled
