@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import autotune, ref
+from repro.kernels import autotune, ref, words
 from repro.kernels.cmatmul import (
     bcmatmul,
     bcmatmul_body,
@@ -119,6 +119,7 @@ __all__ = [
     "irfft_unpack_planar",
     "mds_apply",
     "recombine_fused",
+    "interleave_words",
     "make_kernel_worker_fn",
     "make_kernel_fftn_fn",
 ]
@@ -970,6 +971,30 @@ def _recombine_impl(c_hat, s, interpret):
 def recombine_fused(c_hat: jax.Array, s: int, *, interpret: bool | None = None):
     """Kernel-backed master recombination: (m, s/m) decoded C -> X (s,)."""
     return _recombine_impl(c_hat, s, interpret)
+
+
+def _words_block(n: int) -> int | None:
+    """Rows of 128 lanes per grid step of the interleave kernel for ``n``
+    elements, or None where its tiling does not fit: whole multiples of
+    128 rows (the transposed tile's lanes), up to 512."""
+    if n % (words.LANES * 128):
+        return None
+    rows = n // words.LANES
+    return next(b for b in (512, 256, 128) if rows % b == 0)
+
+
+def interleave_words(re: jax.Array, im: jax.Array, *,
+                     interpret: bool | None = None) -> jax.Array:
+    """``(..., k)`` f32 planes -> ``(..., 2k)`` (re, im) words
+    (kernels/words.py): the Pallas kernel where its tiling fits, the
+    direct body elsewhere (small buckets, where XLA's padded interleave
+    costs little)."""
+    mode = _mode(interpret)
+    block = _words_block(re.size)
+    if mode == "direct" or block is None or re.dtype != jnp.float32:
+        return words.interleave_body(re, im)
+    return words.interleave_words(re, im, block=block,
+                                  interpret=mode == "interpret")
 
 
 # ------------------------------------------------------------- worker fns

@@ -55,6 +55,7 @@ local device with identical semantics.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 import warnings
 from typing import Optional, Sequence, Union
@@ -62,6 +63,7 @@ from typing import Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import Mesh
 
 from repro.core import mds
@@ -77,6 +79,7 @@ from repro.distributed.health import WorkerHealthTracker
 from repro.distributed.straggler import StragglerModel
 from repro.distributed.worker_runtime import MeasuredWorkerRuntime
 from repro.kernels import autotune, ops, ref
+from repro.kernels.words import interleave_body
 from repro.serving.batching import LatencyHistogram, bucket_size
 from repro.serving.decode_cache import DecodeMatrixCache
 from repro.serving.spans import (FETCH_COPY, FETCH_WAIT, STAGE_H2D,
@@ -136,13 +139,59 @@ class DegradedResult:
 
 
 class _Launched:
-    """A launched robust bucket: device/host rows + per-row errors."""
+    """A launched bucket: its rows, on the device or the host, and the
+    per-row errors of the fault path (``None`` elsewhere).
 
-    __slots__ = ("out", "errors")
+    ``words``: ``out`` is complex rows carried as real words
+    (:func:`to_words`), so the fetched host array is viewed back."""
 
-    def __init__(self, out, errors):
+    __slots__ = ("out", "errors", "words")
+
+    def __init__(self, out, errors=None, words=False):
         self.out = out          # device array or host ndarray (verify path)
         self.errors = errors    # per-bucket-row Optional[ServiceError]
+        self.words = words
+
+    def rows(self, host: np.ndarray) -> np.ndarray:
+        """The bucket's host rows from the fetched ``out``."""
+        return _host_complex(host) if self.words else host
+
+
+# -- the host link (DESIGN.md §8) ----------------------------------------
+# No complex array crosses between host and device as complex: on a TPU
+# v5e a complex64 copy runs ~10x slower than float32 of the same bytes
+# (PERF.md §5).  It crosses as real words, (re, im) interleaved as numpy
+# lays complex out, so both host sides are views; the two conversions run
+# on the device, each its own jitted call around the unchanged runners.
+@functools.partial(jax.jit, static_argnames="on_mesh")
+def to_words(z: jax.Array, on_mesh: bool = False) -> jax.Array:
+    """Egress, on the device: ``complex[..., k]`` -> ``real[..., 2k]``.
+    ``on_mesh``: ``z`` spans a mesh, where XLA cannot partition the
+    interleave kernel, so the plain XLA interleave runs there."""
+    if on_mesh:
+        return interleave_body(z.real, z.imag)
+    return ops.interleave_words(z.real, z.imag)
+
+
+@jax.jit
+def from_words(w: jax.Array) -> jax.Array:
+    """Ingress, on the device: ``real[..., 2k]`` -> ``complex[..., k]``.
+    Two strided slices (``w[..., 0::2]`` would lower to a gather)."""
+    n = w.ndim
+    step = (1,) * (n - 1) + (2,)
+    re = lax.slice(w, (0,) * n, w.shape, step)
+    im = lax.slice(w, (0,) * (n - 1) + (1,), w.shape, step)
+    return lax.complex(re, im)
+
+
+def _host_words(x: np.ndarray) -> np.ndarray:
+    """Ingress, on the host: a complex buffer's bytes as real words."""
+    return x.view(np.finfo(x.dtype).dtype) if np.iscomplexobj(x) else x
+
+
+def _host_complex(w: np.ndarray) -> np.ndarray:
+    """Egress, on the host: fetched words viewed as complex rows."""
+    return w.view(np.result_type(w.dtype, np.complex64))
 
 
 def _donate_ingress(fn):
@@ -250,6 +299,9 @@ class ServiceStats:
     sync_s: float = 0.0            # wall time blocked on device results
     host_transfers: int = 0        # device->host fetches (1 per submit_batch
     #                                call; 1 per bucket on the streaming path)
+    h2d_bytes: int = 0             # bucket arguments copied host->device
+    d2h_bytes: int = 0             # ... and results copied device->host
+    #                                (complex crosses as real words, §8)
     # -- open-loop streaming observables (serving/streaming.py, §11) ----
     queue_peak: int = 0            # high-water mark of undispatched requests
     rejected: int = 0              # admission-control rejections (both
@@ -289,6 +341,8 @@ class ServiceStats:
             "dispatch_s": self.dispatch_s,
             "sync_s": self.sync_s,
             "host_transfers": self.host_transfers,
+            "h2d_bytes": self.h2d_bytes,
+            "d2h_bytes": self.d2h_bytes,
             "queue_peak": self.queue_peak,
             "rejected": self.rejected,
             "cancelled": self.cancelled,
@@ -1072,9 +1126,10 @@ class FFTService:
         if cfg.verify == "off" and not live_corrupt:
             # fault-free data path: reuse the jitted bucket executor with
             # the deadline-derived masks
-            out = self._runner_for(s, bucket, kind)(
-                *self._bucket_args(s, kind, xb, full))
-            return _Launched(out, errors)
+            launched = self._execute(s, bucket, kind,
+                                     self._bucket_args(s, kind, xb, full))
+            launched.errors = errors
+            return launched
         # instrumented path: corruption must land in real worker rows and
         # verification must see them, so execute host-visibly
         rows, errors = self._verify_execute(s, kind, xb, full, errors,
@@ -1217,7 +1272,8 @@ class FFTService:
         return _Launched(*self._decode_collected(s, "c2c", res.b, masks,
                                                  errors, n_live))
 
-    def fetch_bucket(self, out) -> tuple[np.ndarray, Optional[list]]:
+    def fetch_bucket(self, launched: _Launched
+                     ) -> tuple[np.ndarray, Optional[list]]:
         """Host rows + per-row errors for one launched bucket.
 
         The streaming syncer calls this instead of ``jax.device_get`` so
@@ -1225,16 +1281,17 @@ class FFTService:
         through a device transfer (host rows pass straight through).  It
         waits for the device, then copies: the copy cannot start before
         the result is ready either way, and the two steps are spans of
-        their own."""
-        errors = None
-        if isinstance(out, _Launched):
-            out, errors = out.out, out.errors
-            if isinstance(out, np.ndarray):
-                return out, errors
+        their own.  Complex rows arrive as real words and are viewed
+        back (DESIGN.md §8)."""
+        out = launched.out
+        if isinstance(out, np.ndarray):
+            return out, launched.errors
         with span(FETCH_WAIT):
             jax.block_until_ready(out)
-        with span(FETCH_COPY):
-            return jax.device_get(out), errors
+        with span(FETCH_COPY, bytes=out.nbytes, dtype=out.dtype.name):
+            host = jax.device_get(out)
+        self.stats.d2h_bytes += host.nbytes
+        return launched.rows(host), launched.errors
 
     # ------------------------------------------------------------------
     def submit(self, x: jax.Array) -> np.ndarray:
@@ -1305,7 +1362,7 @@ class FFTService:
 
         # phase 1 -- dispatch: stage + launch every bucket, no host sync
         t0 = time.perf_counter()
-        pending: list[tuple[list[int], jax.Array]] = []
+        pending: list[tuple[list[int], _Launched]] = []
         for (s, k), idxs in by_bucket.items():
             for start in range(0, len(idxs), cfg.max_batch):
                 chunk = idxs[start:start + cfg.max_batch]
@@ -1313,16 +1370,16 @@ class FFTService:
         self.stats.dispatch_s += time.perf_counter() - t0
 
         # phase 2 -- sync: ONE device->host transfer for the whole call
-        # (robust _Launched buckets contribute their device/host rows;
-        # numpy rows pass through device_get unchanged)
+        # (numpy rows of the verify path pass through device_get unchanged)
         t0 = time.perf_counter()
-        fetched = jax.device_get(
-            [out.out if isinstance(out, _Launched) else out
-             for _, out in pending])
+        outs = [launched.out for _, launched in pending]
+        fetched = jax.device_get(outs)
         self.stats.host_transfers += 1
+        self.stats.d2h_bytes += sum(h.nbytes for o, h in zip(outs, fetched)
+                                    if not isinstance(o, np.ndarray))
         self.stats.sync_s += time.perf_counter() - t0
-        for (chunk, out), rows in zip(pending, fetched):
-            errors = out.errors if isinstance(out, _Launched) else None
+        for (chunk, launched), host in zip(pending, fetched):
+            rows, errors = launched.rows(host), launched.errors
             for row, i in enumerate(chunk):
                 err = errors[row] if errors is not None else None
                 if err is not None:
@@ -1391,30 +1448,29 @@ class FFTService:
                     # always the FAST executors: the robust path reuses
                     # them whenever no corruption/verification is in play,
                     # so precompiling here serves both modes
-                    outs.append(self._runner_for(s, b, k)(
-                        *self._bucket_args(s, k, xb, masks)))
+                    outs.append(self._execute(
+                        s, b, k, self._bucket_args(s, k, xb, masks)).out)
         jax.block_until_ready(outs)
         return len(outs)
 
+    def _ingress_dtype(self, kind: str) -> np.dtype:
+        """A kind's request dtype: real requests stay a single real plane
+        end-to-end, the rest take the service dtype (NOT the first
+        request's -- a real-valued request must not narrow the whole
+        bucket's buffer)."""
+        cdt = np.dtype(self.cfg.dtype)
+        return np.finfo(cdt).dtype if kind in ("r2c", "rfftn") else cdt
+
     def _bucket_buffer(self, s, bucket: int, kind: str) -> np.ndarray:
         """The request staging buffer for one bucket, in the kind's ingress
-        dtype: real requests stay a single real plane end-to-end.  ``s``
-        is the scalar time-domain length (1-D kinds) or shape tuple (n-D
-        kinds)."""
-        cdt = np.dtype(self.cfg.dtype)
-        rdt = np.real(np.zeros(1, cdt)).dtype
-        if kind == "rfftn":
-            return np.zeros((bucket,) + tuple(s), dtype=rdt)
-        if kind == "irfftn":
-            shape = tuple(s[:-1]) + (s[-1] // 2 + 1,)
-            return np.zeros((bucket,) + shape, dtype=cdt)
-        if kind == "r2c":
-            return np.zeros((bucket, s), dtype=rdt)
-        if kind == "c2r":
-            return np.zeros((bucket, s // 2 + 1), dtype=cdt)
-        # allocate in the service dtype (NOT the first request's dtype --
-        # a real-valued request must not narrow the whole bucket's buffer)
-        return np.zeros((bucket, s), dtype=cdt)
+        dtype.  ``s`` is the scalar time-domain length (1-D kinds) or
+        shape tuple (n-D kinds)."""
+        if kind in self.ND_KINDS:
+            shape = tuple(s) if kind == "rfftn" else (
+                tuple(s[:-1]) + (s[-1] // 2 + 1,))
+        else:
+            shape = (s // 2 + 1,) if kind == "c2r" else (s,)
+        return np.zeros((bucket,) + shape, dtype=self._ingress_dtype(kind))
 
     def _full_masks(self, s, kind: str, bucket: int) -> np.ndarray:
         """All-responders mask block for one bucket: ``(bucket, N)``, or
@@ -1433,28 +1489,30 @@ class FFTService:
         everything else happens in-jit (DESIGN.md §8).  Fallback kernel
         path (``m > LAGRANGE_MAX_M`` or ``device_decode=False``): per-mask
         matrices from the host LRU, shared across every (s, kind) bucket.
+        A complex request buffer crosses as real words; the executor gets
+        it back as complex in :meth:`_execute`.
         """
-        with span(STAGE_H2D):
-            if self._kernel_path(s, kind) and not self._device_decode():
-                cache = self._decode_cache_for()
-                h0, m0 = cache.hits, cache.misses
-                if ops.default_interpret():
-                    invs, subsets = cache.compact(masks)
-                    dplanes = np.stack(
-                        [invs.real, invs.imag]).astype(np.float32)
-                    args = (jnp.asarray(xb), jnp.asarray(dplanes),
-                            jnp.asarray(subsets))
-                else:
-                    dmats = cache.matrices(masks)
-                    dplanes = np.stack(
-                        [dmats.real, dmats.imag]).astype(np.float32)
-                    args = (jnp.asarray(xb), jnp.asarray(dplanes))
-                # deltas, not lifetime cache totals: every other ServiceStats
-                # field accumulates, so a stats reset must window these too
-                self.stats.decode_cache_hits += cache.hits - h0
-                self.stats.decode_cache_misses += cache.misses - m0
-                return args
-            return (jnp.asarray(xb), jnp.asarray(masks))
+        host: tuple = (masks,)
+        if self._kernel_path(s, kind) and not self._device_decode():
+            cache = self._decode_cache_for()
+            h0, m0 = cache.hits, cache.misses
+            if ops.default_interpret():
+                invs, subsets = cache.compact(masks)
+                host = (np.stack([invs.real, invs.imag]).astype(np.float32),
+                        subsets)
+            else:
+                dmats = cache.matrices(masks)
+                host = (np.stack([dmats.real, dmats.imag]).astype(np.float32),)
+            # deltas, not lifetime cache totals: every other ServiceStats
+            # field accumulates, so a stats reset must window these too
+            self.stats.decode_cache_hits += cache.hits - h0
+            self.stats.decode_cache_misses += cache.misses - m0
+        host = (_host_words(xb),) + host
+        nbytes = sum(a.nbytes for a in host)
+        with span(STAGE_H2D, bytes=nbytes, dtype=host[0].dtype.name):
+            args = tuple(jnp.asarray(a) for a in host)
+        self.stats.h2d_bytes += nbytes
+        return args
 
     # -- staging seam (shared with serving/streaming.py, DESIGN.md §11) --
     def bucket_key(self, x, kind: str):
@@ -1511,23 +1569,39 @@ class FFTService:
         return bucket, self._bucket_args(s, kind, xb, masks)
 
     def launch_bucket(self, s, bucket: int, kind: str, args: tuple
-                      ) -> jax.Array:
-        """Launch one staged bucket; returns the UNSYNCED device result.
+                      ) -> _Launched:
+        """Launch one staged bucket; returns the UNSYNCED result.
 
-        The jitted call returns immediately (async dispatch), so callers
-        can launch every bucket before blocking once on all of them.  On
-        the fault-tolerant path the return value is a :class:`_Launched`
-        (device/host rows + per-row errors); fetch it with
-        :meth:`fetch_bucket` rather than ``jax.device_get``.
+        The jitted calls return immediately (async dispatch), so callers
+        can launch every bucket before blocking once on all of them.  The
+        return value is a :class:`_Launched` (device rows, or host rows on
+        the verify path, + per-row errors on the fault path); fetch it
+        with :meth:`fetch_bucket`.
         """
         with span(STAGE_LAUNCH):
             if self._robust:
                 xb, n_live = args
                 return self._robust_launch(s, bucket, kind, xb, n_live)
-            return self._runner_for(s, bucket, kind)(*args)
+            return self._execute(s, bucket, kind, args)
+
+    def _execute(self, s, bucket: int, kind: str, args: tuple) -> _Launched:
+        """The bucket executor between the host link's two conversions
+        (DESIGN.md §8): complex requests arrive as real words and are
+        rebuilt on the device, a complex result leaves as real words.
+        The executor keeps its complex signature, so the c2c donation
+        alias holds and whatever wraps :meth:`_runner_for` sees complex
+        arrays."""
+        x, *rest = args
+        if self._ingress_dtype(kind).kind == "c":
+            x = from_words(x)
+        out = self._runner_for(s, bucket, kind)(x, *rest)
+        if jnp.iscomplexobj(out):
+            return _Launched(to_words(out, on_mesh=self.mesh is not None),
+                             words=True)
+        return _Launched(out)
 
     def _dispatch_bucket(self, s, idxs: list[int], xs,
-                         kind: str = "c2c") -> jax.Array:
+                         kind: str = "c2c") -> _Launched:
         """Stage + launch one bucket (the closed-loop submit_batch path)."""
         bucket, args = self.stage_bucket(s, kind, [xs[i] for i in idxs])
         return self.launch_bucket(s, bucket, kind, args)

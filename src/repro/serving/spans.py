@@ -22,7 +22,8 @@ BUCKET_FORM = "fft.bucket.form"
 BUCKET_STAGE = "fft.bucket.stage"
 # straggler draw and numpy pack into the padded bucket buffer
 STAGE_PACK = "fft.stage.pack"
-# host-to-device conversion of the bucket's arguments
+# host-to-device copy of the bucket's arguments; args bytes, dtype (of
+# the request array: float32 words for complex, DESIGN.md §8)
 STAGE_H2D = "fft.stage.h2d"
 # dispatch of the jitted bucket call (the fault path's launch entire)
 STAGE_LAUNCH = "fft.stage.launch"
@@ -30,7 +31,8 @@ STAGE_LAUNCH = "fft.stage.launch"
 BUCKET_FETCH = "fft.bucket.fetch"
 # block_until_ready on the device result: waiting for the device
 FETCH_WAIT = "fft.fetch.wait"
-# device_get of the ready result: the device-to-host copy
+# device_get of the ready result: the device-to-host copy; args bytes,
+# dtype (float32 words for complex rows)
 FETCH_COPY = "fft.fetch.copy"
 # resolving the bucket's futures, client done-callbacks included; arg bucket
 BUCKET_RESOLVE = "fft.bucket.resolve"

@@ -20,6 +20,7 @@ from repro.distributed.straggler import StragglerModel
 from repro.kernels import ops
 from repro.serving import FFTService, FFTServiceConfig
 from repro.serving.decode_cache import DecodeMatrixCache
+from repro.serving.fft_service import from_words
 
 pytestmark = pytest.mark.kernels
 
@@ -177,13 +178,15 @@ def test_coded_rbucket_masked_kernel_parity(s, m, n):
 def _runner_jaxpr(svc: FFTService, bucket: int = 2) -> str:
     """The jaxpr of the service's compiled bucket executor at its default
     (s, c2c) key, traced over the exact argument layout the scheduler
-    feeds it."""
+    feeds it: the staged arguments, the requests rebuilt from the words
+    that crossed the host link."""
     cfg = svc.cfg
     runner = svc._runner_for(cfg.s, bucket, "c2c")
     xb = svc._bucket_buffer(cfg.s, bucket, "c2c")
     masks = np.ones((bucket, cfg.n_workers), bool)
-    args = svc._bucket_args(cfg.s, "c2c", xb, masks)
-    return str(jax.make_jaxpr(lambda *a: runner(*a))(*args))
+    words, *rest = svc._bucket_args(cfg.s, "c2c", xb, masks)
+    return str(jax.make_jaxpr(lambda *a: runner(*a))(from_words(words),
+                                                     *rest))
 
 
 def test_device_decode_below_boundary_builds_weights_in_trace():

@@ -4,7 +4,8 @@ A small streaming run under a ``jax.profiler`` trace, read back with
 ``jax.profiler.ProfileData``: every span appears, children nest in their
 parent on one thread, and the ``bucket`` argument ties a bucket's
 scheduler, stager and syncer spans together.  Splitting the fetch into a
-wait and a copy changes no row it returns."""
+wait and a copy changes no row it returns, and carrying complex rows
+across the host link as float32 words changes no bit of them."""
 
 import glob
 
@@ -146,25 +147,84 @@ def test_submit_batch_opens_the_stage_children(tmp_path):
         assert np.abs(y - np.fft.fft(x)).max() < 1e-2
 
 
-@pytest.mark.parametrize("path", ["plain", "robust", "robust_host_rows"])
-def test_fetch_bucket_rows_match_device_get(path):
-    """Waiting, then copying, returns what one ``jax.device_get`` of the
-    same launched result returns, on the plain and the fault paths."""
+def test_link_spans_carry_bytes_and_dtype(traced):
+    """The host-to-device and device-to-host spans name what crossed: a
+    bucket of complex64 requests goes as float32 words of the same bytes
+    both ways (DESIGN.md §8)."""
+    events, _ = traced
+    payload = CAP * S * np.dtype(np.complex64).itemsize
+    masks = CAP * 8 * np.dtype(bool).itemsize
+    for name, want in ((spans.STAGE_H2D, payload + masks),
+                       (spans.FETCH_COPY, payload)):
+        got = _by_name(events, name)
+        assert len(got) == N_REQ // CAP, name
+        for _, _, _, stats, _ in got:
+            assert stats["dtype"] == "float32", (name, stats)
+            assert int(stats["bytes"]) == want, (name, stats)
+
+
+def _spy_runner(svc):
+    """Record every bucket executor call's arguments and raw result."""
+    make, calls = svc._runner_for, []
+
+    def runner_for(s, bucket, kind="c2c"):
+        fn = make(s, bucket, kind)
+
+        def call(*args):
+            out = fn(*args)
+            calls.append((args, out))
+            return out
+
+        return call
+
+    svc._runner_for = runner_for
+    return calls
+
+
+def _kind_reqs(kind, n, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "r2c":
+        return [rng.normal(size=S).astype(np.float32) for _ in range(n)]
+    if kind == "c2r":
+        return [np.fft.rfft(rng.normal(size=S)).astype(np.complex64)
+                for _ in range(n)]
+    return _reqs(n, seed)
+
+
+def _numpy_fft(kind, x):
+    return {"c2c": np.fft.fft, "r2c": np.fft.rfft,
+            "c2r": np.fft.irfft}[kind](x)
+
+
+@pytest.mark.parametrize("path,kind", [
+    ("plain", "c2c"), ("plain", "r2c"), ("plain", "c2r"),
+    ("robust", "c2c"), ("robust_host_rows", "c2c")])
+def test_fetch_bucket_rows_match_device_get(path, kind):
+    """Waiting, then copying the result as words, returns bit for bit
+    what one ``jax.device_get`` of the bucket executor's own result
+    returns, with its dtype and shape, on the plain and the fault
+    paths."""
     kw = {"plain": {}, "robust": {"health": True},
           "robust_host_rows": {"verify": "detect"}}[path]
     svc = FFTService(_cfg(**kw))
-    xs = _reqs(CAP, seed=3)
-    bucket, args = svc.stage_bucket(S, "c2c", xs)
-    out = svc.launch_bucket(S, bucket, "c2c", args)
+    calls = _spy_runner(svc)
+    xs = _kind_reqs(kind, CAP, seed=3)
+    bucket, args = svc.stage_bucket(S, kind, xs)
+    out = svc.launch_bucket(S, bucket, kind, args)
     rows, errors = svc.fetch_bucket(out)
+    assert errors == out.errors
     if path == "plain":
         assert errors is None
-        want = jax.device_get(out)
+    if path == "robust_host_rows":
+        assert isinstance(out.out, np.ndarray) and not calls
+        want = out.out
     else:
-        assert errors == out.errors
-        assert isinstance(out.out, np.ndarray) == (path == "robust_host_rows")
-        want = jax.device_get(out.out)
+        (_, raw), = calls
+        want = jax.device_get(raw)
     assert isinstance(rows, np.ndarray)
+    assert rows.dtype == want.dtype and rows.shape == want.shape
+    assert rows.dtype == (np.float32 if kind == "c2r" else np.complex64)
     np.testing.assert_array_equal(rows, want)
     for x, y in zip(xs, rows):
-        assert np.abs(y - np.fft.fft(x)).max() < 1e-2
+        ref = _numpy_fft(kind, x)
+        assert np.abs(y - ref).max() < 1e-2

@@ -91,6 +91,11 @@ def _fourstep(ell, q, variant):
     return fn, [((q, ell), jnp.float32)] * 2
 
 
+def _words(q, s):
+    fn = lambda re, im: ops.interleave_words(re, im, interpret=False)
+    return fn, [((q, s), jnp.float32)] * 2
+
+
 CASES = {
     # (case constructor, kernel launch the program must contain)
     "c2c_masked_s65536": (lambda: _c2c_masked(1 << 16, 16),
@@ -108,6 +113,8 @@ CASES = {
                              "fourstep_fft_fused"),
     "fourstep_two_pass_L4096": (lambda: _fourstep(4096, 8, "two_pass"),
                                 "fourstep_fft_stage2"),
+    "words_interleave_s1048576": (lambda: _words(16, 1 << 20),
+                                  "interleave_words"),
 }
 
 
@@ -120,3 +127,41 @@ def test_main_path_kernel_compiles_for_v5e(case, one_chip, chip_config):
     assert kernel in str(jax.make_jaxpr(fn)(*args))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("direction", ["to_words", "from_words"])
+def test_host_link_conversion_stays_near_bucket_size(direction, one_chip,
+                                                     chip_config,
+                                                     monkeypatch):
+    """The host link's conversions at the 2^20 c2c bucket (16 requests,
+    128 MiB each way) keep their temporaries within twice the bucket:
+    no pair axis of 2 padded to a full tile (XLA's own interleave takes
+    8x the bucket).  The dispatch asks the default backend, which is the
+    CPU here, so the test steers it to the chip's branch."""
+    from repro.serving import fft_service
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+
+    q, s = 16, 1 << 20
+    shape, dtype = (((q, s), jnp.complex64) if direction == "to_words"
+                    else ((q, 2 * s), jnp.float32))
+    arg = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = getattr(fft_service, direction).lower(arg).compile()
+    bucket = q * s * 8
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * bucket
+
+
+def test_to_words_compiles_over_a_mesh(topo, chip_config, monkeypatch):
+    """A mesh service's result spans the 2x2 mesh, where XLA cannot
+    partition a Pallas kernel: ``to_words`` takes the XLA interleave
+    there, and the chip's compiler accepts it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.serving import fft_service
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices), ("workers",))
+    arg = jax.ShapeDtypeStruct((16, 1 << 12), jnp.complex64,
+                               sharding=NamedSharding(mesh, PartitionSpec()))
+    fft_service.to_words.lower(arg, on_mesh=True).compile()
