@@ -95,8 +95,26 @@ class DistributedCodedPlan:
                 f"size {size}")
 
     @property
+    def n_devices(self) -> int:
+        return self.mesh.shape[self.axis]
+
+    @property
     def n_local(self) -> int:
-        return self.plan.n_workers // self.mesh.shape[self.axis]
+        return self.plan.n_workers // self.n_devices
+
+    def exchange_bytes(self, batch: int, arg_bytes: int) -> tuple[int, int]:
+        """``(broadcast, gather)``: the bytes one :meth:`run` over
+        ``batch`` requests moves between devices, reckoned from the plan's
+        shapes.  ``broadcast``: what the devices copy among themselves to
+        replicate the arguments (``arg_bytes``), (D-1) x ``arg_bytes``
+        whether one device holds them or each a slice.  ``gather``: what
+        each device receives in the all-gather, (D-1)/D of the N coded
+        results of every request."""
+        d = self.n_devices
+        results = (self.plan.n_workers * batch
+                   * math.prod(self.plan.worker_shard_shape)
+                   * jnp.dtype(self.plan.dtype).itemsize)
+        return (d - 1) * arg_bytes, results * (d - 1) // d
 
     # ------------------------------------------------------------------
     @_full_f32_matmuls

@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import mds
 from repro.core.coded_fft import CodedFFT, plan_factors
@@ -283,6 +283,13 @@ class FFTServiceConfig:
     strategy_param: Optional[int] = None  # the strategy's own knob (r for
     #                               partial, q for comm_efficient); None
     #                               means the registry entry's default
+    # -- a mesh's ingress (DESIGN.md §14) -------------------------------
+    mesh_ingress: str = "split"   # how a bucket's arguments reach a mesh:
+    #                               "split", each device's host link takes
+    #                               its rows and the runner all-gathers them
+    #                               over the interconnect; "first", the whole
+    #                               bucket lands on the first device, which
+    #                               copies it to the others before the runner
 
 
 @dataclasses.dataclass
@@ -302,6 +309,15 @@ class ServiceStats:
     h2d_bytes: int = 0             # bucket arguments copied host->device
     d2h_bytes: int = 0             # ... and results copied device->host
     #                                (complex crosses as real words, §8)
+    # -- what a mesh moves between its devices, summed over launched
+    #    buckets; DistributedCodedPlan.exchange_bytes reckons it from the
+    #    plan's shapes.  Both stay 0 without a mesh --------------------
+    broadcast_bytes: int = 0       # what the devices copy among themselves
+    #                                to replicate the bucket's arguments:
+    #                                (D-1) x their bytes, whether one device
+    #                                holds them or each a slice
+    gather_bytes: int = 0          # bytes EACH device receives in the
+    #                                all-gather: (D-1)/D x N x bucket x payload
     # -- open-loop streaming observables (serving/streaming.py, §11) ----
     queue_peak: int = 0            # high-water mark of undispatched requests
     rejected: int = 0              # admission-control rejections (both
@@ -343,6 +359,8 @@ class ServiceStats:
             "host_transfers": self.host_transfers,
             "h2d_bytes": self.h2d_bytes,
             "d2h_bytes": self.d2h_bytes,
+            "broadcast_bytes": self.broadcast_bytes,
+            "gather_bytes": self.gather_bytes,
             "queue_peak": self.queue_peak,
             "rejected": self.rejected,
             "cancelled": self.cancelled,
@@ -412,6 +430,10 @@ class FFTService:
         if mesh is not None and not REGISTRY[cfg.strategy].mesh_ok:
             raise ValueError(
                 f"strategy {cfg.strategy!r} does not compose with a mesh")
+        if cfg.mesh_ingress not in ("split", "first"):
+            raise ValueError(
+                f'mesh_ingress must be "split"|"first", '
+                f'got {cfg.mesh_ingress!r}')
         if pool is not None and pool.m != cfg.m:
             raise ValueError(
                 f"pool threshold m={pool.m} must match cfg.m={cfg.m}")
@@ -1510,9 +1532,22 @@ class FFTService:
         host = (_host_words(xb),) + host
         nbytes = sum(a.nbytes for a in host)
         with span(STAGE_H2D, bytes=nbytes, dtype=host[0].dtype.name):
-            args = tuple(jnp.asarray(a) for a in host)
+            args = tuple(self._to_device(a) for a in host)
         self.stats.h2d_bytes += nbytes
         return args
+
+    def _to_device(self, a: np.ndarray) -> jax.Array:
+        """One bucket argument onto the device(s).  Without a mesh, or with
+        ``mesh_ingress="first"``, it lands on the default device (a mesh
+        runner's call then copies it to the others).  With ``"split"``
+        each device takes its slice of the leading (bucket) axis over its
+        own host link, and the runner all-gathers the slices; a bucket
+        that does not divide over the devices goes whole to each."""
+        if self.mesh is None or self.cfg.mesh_ingress == "first":
+            return jnp.asarray(a)
+        rows = a.shape[0] % self.mesh.shape[self.axis] == 0
+        return jax.device_put(
+            a, NamedSharding(self.mesh, P(self.axis) if rows else P()))
 
     # -- staging seam (shared with serving/streaming.py, DESIGN.md §11) --
     def bucket_key(self, x, kind: str):
@@ -1578,11 +1613,43 @@ class FFTService:
         the verify path, + per-row errors on the fault path); fetch it
         with :meth:`fetch_bucket`.
         """
-        with span(STAGE_LAUNCH):
+        with span(STAGE_LAUNCH, **self._account_launch(s, bucket, kind,
+                                                       args)):
             if self._robust:
                 xb, n_live = args
                 return self._robust_launch(s, bucket, kind, xb, n_live)
             return self._execute(s, bucket, kind, args)
+
+    def _account_launch(self, s, bucket: int, kind: str, args: tuple
+                        ) -> dict:
+        """The ``fft.stage.launch`` span's arguments: how many devices the
+        bucket's runner spans and which runner it is; on a mesh also its
+        ingress and the bytes the launch moves between the devices, which
+        are added to :class:`ServiceStats` here."""
+        if self.mesh is None:
+            return {"devices": 1, "runner": self._runner_name(s, kind)}
+        runtime = self._runtime_for(s, kind)
+        # an argument that came whole to every device from the host moves
+        # nothing between them; any other is replicated by the devices
+        held = [a for a in args if not (a.sharding.is_fully_replicated
+                                        and len(a.sharding.device_set) > 1)]
+        bcast, gather = runtime.exchange_bytes(
+            bucket, sum(a.nbytes for a in held))
+        self.stats.broadcast_bytes += bcast
+        self.stats.gather_bytes += gather
+        return {"devices": runtime.n_devices, "runner": "mesh",
+                "ingress": self.cfg.mesh_ingress,
+                "broadcast_bytes": bcast, "gather_bytes": gather}
+
+    def _runner_name(self, s, kind: str) -> str:
+        """The local runner a bucket takes: the fault path, the kernel
+        executor with in-jit decode matrices (``kernel_masked``) or with
+        host ones (``kernel``), or the jitted ``plan.run``."""
+        if self._robust:
+            return "robust"
+        if not self._kernel_path(s, kind):
+            return "plan"
+        return "kernel_masked" if self._device_decode() else "kernel"
 
     def _execute(self, s, bucket: int, kind: str, args: tuple) -> _Launched:
         """The bucket executor between the host link's two conversions

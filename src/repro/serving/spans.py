@@ -25,7 +25,12 @@ STAGE_PACK = "fft.stage.pack"
 # host-to-device copy of the bucket's arguments; args bytes, dtype (of
 # the request array: float32 words for complex, DESIGN.md §8)
 STAGE_H2D = "fft.stage.h2d"
-# dispatch of the jitted bucket call (the fault path's launch entire)
+# dispatch of the jitted bucket call (the fault path's launch entire);
+# args devices (the runner's device count) and runner ("mesh", or the
+# local runner: kernel_masked, kernel, plan, robust); on a mesh also
+# ingress (FFTServiceConfig.mesh_ingress) and broadcast_bytes and
+# gather_bytes, the bucket's share of ServiceStats' counters of the same
+# names
 STAGE_LAUNCH = "fft.stage.launch"
 # the syncer's whole fetch of one bucket (wait, copy); arg bucket
 BUCKET_FETCH = "fft.bucket.fetch"

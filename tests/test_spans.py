@@ -163,6 +163,17 @@ def test_link_spans_carry_bytes_and_dtype(traced):
             assert int(stats["bytes"]) == want, (name, stats)
 
 
+def test_launch_span_names_its_runner(traced):
+    """Without a mesh a bucket runs on one device, on the kernel executor
+    that builds its decode matrices in the jit; no exchange is counted
+    (the mesh's launch: tests/test_mesh_service.py)."""
+    events, _ = traced
+    got = _by_name(events, spans.STAGE_LAUNCH)
+    assert len(got) == N_REQ // CAP
+    for _, _, _, stats, _ in got:
+        assert stats == {"devices": 1, "runner": "kernel_masked"}, stats
+
+
 def _spy_runner(svc):
     """Record every bucket executor call's arguments and raw result."""
     make, calls = svc._runner_for, []
