@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import time
 import warnings
 from typing import Optional, Sequence, Union
@@ -143,18 +144,36 @@ class _Launched:
     per-row errors of the fault path (``None`` elsewhere).
 
     ``words``: ``out`` is complex rows carried as real words
-    (:func:`to_words`), so the fetched host array is viewed back."""
+    (:func:`to_words`), so the fetched host array is viewed back.
+    ``staging``: the host buffer the bucket was packed in, handed back to
+    the service's free list once ``out`` is ready (DESIGN.md §11)."""
 
-    __slots__ = ("out", "errors", "words")
+    __slots__ = ("out", "errors", "words", "staging")
 
     def __init__(self, out, errors=None, words=False):
         self.out = out          # device array or host ndarray (verify path)
         self.errors = errors    # per-bucket-row Optional[ServiceError]
         self.words = words
+        self.staging = None
 
     def rows(self, host: np.ndarray) -> np.ndarray:
         """The bucket's host rows from the fetched ``out``."""
         return _host_complex(host) if self.words else host
+
+
+class _StagedArgs(tuple):
+    """A staged bucket's arguments for :meth:`FFTService.launch_bucket`,
+    carrying the host buffer they were packed in as ``staging``."""
+
+    def __new__(cls, args, staging: np.ndarray):
+        self = super().__new__(cls, args)
+        self.staging = staging
+        return self
+
+
+# staging buffers kept per (shape, dtype) for reuse: the streaming pipeline
+# holds at most three buckets between pack and fetch (DESIGN.md §11)
+STAGING_KEEP = 3
 
 
 # -- the host link (DESIGN.md §8) ----------------------------------------
@@ -309,6 +328,9 @@ class ServiceStats:
     h2d_bytes: int = 0             # bucket arguments copied host->device
     d2h_bytes: int = 0             # ... and results copied device->host
     #                                (complex crosses as real words, §8)
+    staging_reused: int = 0        # buckets packed into a staging buffer
+    #                                from the free list (DESIGN.md §11)
+    staging_allocated: int = 0     # ... and into a newly allocated one
     # -- what a mesh moves between its devices, summed over launched
     #    buckets; DistributedCodedPlan.exchange_bytes reckons it from the
     #    plan's shapes.  Both stay 0 without a mesh --------------------
@@ -359,6 +381,8 @@ class ServiceStats:
             "host_transfers": self.host_transfers,
             "h2d_bytes": self.h2d_bytes,
             "d2h_bytes": self.d2h_bytes,
+            "staging_reused": self.staging_reused,
+            "staging_allocated": self.staging_allocated,
             "broadcast_bytes": self.broadcast_bytes,
             "gather_bytes": self.gather_bytes,
             "queue_peak": self.queue_peak,
@@ -456,6 +480,10 @@ class FFTService:
         # buckets at every length share hits (DESIGN.md §7).  Keyed by N
         # (dict) only because elastic growth changes the generator.
         self._decode_caches: dict[int, DecodeMatrixCache] = {}
+        # free staging buffers by (shape, dtype): taken by the stager,
+        # handed back by the syncer once their bucket's result is ready
+        self._staging_free: dict[tuple, list[np.ndarray]] = {}
+        self._staging_lock = threading.Lock()
         # -- fault-tolerant runtime state (DESIGN.md §12) ---------------
         self._robust = (cfg.faults is not None or cfg.health
                         or cfg.verify != "off" or cfg.measured
@@ -1307,9 +1335,11 @@ class FFTService:
         back (DESIGN.md §8)."""
         out = launched.out
         if isinstance(out, np.ndarray):
+            self._release(launched)
             return out, launched.errors
         with span(FETCH_WAIT):
             jax.block_until_ready(out)
+        self._release(launched)
         with span(FETCH_COPY, bytes=out.nbytes, dtype=out.dtype.name):
             host = jax.device_get(out)
         self.stats.d2h_bytes += host.nbytes
@@ -1396,6 +1426,8 @@ class FFTService:
         t0 = time.perf_counter()
         outs = [launched.out for _, launched in pending]
         fetched = jax.device_get(outs)
+        for _, launched in pending:
+            self._release(launched)
         self.stats.host_transfers += 1
         self.stats.d2h_bytes += sum(h.nbytes for o, h in zip(outs, fetched)
                                     if not isinstance(o, np.ndarray))
@@ -1457,7 +1489,7 @@ class FFTService:
                     autotune.ensure_bucket(
                         kind_keys[k], s, cfg.m, self._n_workers(), q=qmax,
                         mode=mode, reps=cfg.autotune_reps)
-        outs = []
+        outs, staged = [], []
         for s in lengths:
             if isinstance(s, (tuple, list)):
                 s = tuple(int(d) for d in s)      # hashable bucket key
@@ -1465,7 +1497,8 @@ class FFTService:
                 if isinstance(s, tuple) != (k in self.ND_KINDS):
                     continue        # scalar<->1-D, tuple<->n-D only
                 for b in sorted(set(buckets)):
-                    xb = self._bucket_buffer(s, b, k)
+                    xb, _ = self._take_staging(s, b, k)
+                    staged.append(xb)
                     masks = self._full_masks(s, k, b)
                     # always the FAST executors: the robust path reuses
                     # them whenever no corruption/verification is in play,
@@ -1473,6 +1506,8 @@ class FFTService:
                     outs.append(self._execute(
                         s, b, k, self._bucket_args(s, k, xb, masks)).out)
         jax.block_until_ready(outs)
+        for xb in staged:
+            self._give_back(xb)
         return len(outs)
 
     def _ingress_dtype(self, kind: str) -> np.dtype:
@@ -1483,16 +1518,52 @@ class FFTService:
         cdt = np.dtype(self.cfg.dtype)
         return np.finfo(cdt).dtype if kind in ("r2c", "rfftn") else cdt
 
-    def _bucket_buffer(self, s, bucket: int, kind: str) -> np.ndarray:
-        """The request staging buffer for one bucket, in the kind's ingress
-        dtype.  ``s`` is the scalar time-domain length (1-D kinds) or
-        shape tuple (n-D kinds)."""
+    def _bucket_layout(self, s, bucket: int, kind: str
+                       ) -> tuple[tuple, np.dtype]:
+        """Shape and dtype of one bucket's request buffer, in the kind's
+        ingress dtype.  ``s`` is the scalar time-domain length (1-D kinds)
+        or shape tuple (n-D kinds)."""
         if kind in self.ND_KINDS:
             shape = tuple(s) if kind == "rfftn" else (
                 tuple(s[:-1]) + (s[-1] // 2 + 1,))
         else:
             shape = (s // 2 + 1,) if kind == "c2r" else (s,)
-        return np.zeros((bucket,) + shape, dtype=self._ingress_dtype(kind))
+        return (bucket,) + shape, self._ingress_dtype(kind)
+
+    def _bucket_buffer(self, s, bucket: int, kind: str) -> np.ndarray:
+        """A new, zeroed request buffer for one bucket."""
+        return np.zeros(*self._bucket_layout(s, bucket, kind))
+
+    def _take_staging(self, s, bucket: int, kind: str
+                      ) -> tuple[np.ndarray, bool]:
+        """A request buffer for one bucket from the free list, else a new
+        one; and whether it was reused.  A reused buffer still holds an
+        earlier bucket's requests.  A new 128 MiB buffer is a fresh
+        mapping whose pages fault on first touch, most of the pack's time
+        (PERF.md §5); a reused one faults none."""
+        shape, dtype = self._bucket_layout(s, bucket, kind)
+        with self._staging_lock:
+            free = self._staging_free.get((shape, dtype))
+            if free:
+                return free.pop(), True
+        return np.zeros(shape, dtype), False
+
+    def _give_back(self, xb: np.ndarray) -> None:
+        """Put a staging buffer on the free list, up to
+        :data:`STAGING_KEEP` per shape; beyond that it is dropped."""
+        with self._staging_lock:
+            free = self._staging_free.setdefault((xb.shape, xb.dtype), [])
+            if len(free) < STAGING_KEEP:
+                free.append(xb)
+
+    def _release(self, launched: _Launched) -> None:
+        """Hand a bucket's staging buffer back once its result is ready.
+        Not sooner: on the CPU ``device_put`` may alias the host buffer,
+        and a transfer to a chip may still read it, until the computation
+        that consumed the arguments has finished (DESIGN.md §11)."""
+        xb, launched.staging = launched.staging, None
+        if xb is not None:
+            self._give_back(xb)
 
     def _full_masks(self, s, kind: str, bucket: int) -> np.ndarray:
         """All-responders mask block for one bucket: ``(bucket, N)``, or
@@ -1582,8 +1653,15 @@ class FFTService:
         bucket = bucket_size(n_live, cfg.max_batch)
         self.stats.batches += 1
 
-        with span(STAGE_PACK):
-            xb = self._bucket_buffer(s, bucket, kind)
+        with span(STAGE_PACK) as ann:
+            xb, reused = self._take_staging(s, bucket, kind)
+            ann.set_metadata(reused=reused)
+            if reused:
+                self.stats.staging_reused += 1
+            else:
+                self.stats.staging_allocated += 1
+            # every live row is overwritten; padding rows must read zero
+            xb[n_live:] = 0
             real_in = kind in ("r2c", "rfftn")
             for row, x in enumerate(reqs):
                 x = np.asarray(x)
@@ -1594,14 +1672,14 @@ class FFTService:
                 # state, which the launch step owns (stager-thread-confined
                 # on the streaming path, exactly like the non-robust
                 # service internals)
-                return bucket, (xb, n_live)
+                return bucket, _StagedArgs((xb, n_live), xb)
             lat, mask = self._simulate_arrivals(n_live, kind)
             self._account(lat, mask)
             # padded rows: every worker "responds" so decode stays
             # well-posed
             masks = self._full_masks(s, kind, bucket)
             masks[:n_live] = mask
-        return bucket, self._bucket_args(s, kind, xb, masks)
+        return bucket, _StagedArgs(self._bucket_args(s, kind, xb, masks), xb)
 
     def launch_bucket(self, s, bucket: int, kind: str, args: tuple
                       ) -> _Launched:
@@ -1611,14 +1689,18 @@ class FFTService:
         can launch every bucket before blocking once on all of them.  The
         return value is a :class:`_Launched` (device rows, or host rows on
         the verify path, + per-row errors on the fault path); fetch it
-        with :meth:`fetch_bucket`.
+        with :meth:`fetch_bucket`, which hands the bucket's staging buffer
+        back.
         """
         with span(STAGE_LAUNCH, **self._account_launch(s, bucket, kind,
                                                        args)):
             if self._robust:
                 xb, n_live = args
-                return self._robust_launch(s, bucket, kind, xb, n_live)
-            return self._execute(s, bucket, kind, args)
+                launched = self._robust_launch(s, bucket, kind, xb, n_live)
+            else:
+                launched = self._execute(s, bucket, kind, args)
+        launched.staging = getattr(args, "staging", None)
+        return launched
 
     def _account_launch(self, s, bucket: int, kind: str, args: tuple
                         ) -> dict:

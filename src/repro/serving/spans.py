@@ -20,7 +20,8 @@ __all__ = ["BUCKET_FETCH", "BUCKET_FORM", "BUCKET_RESOLVE", "BUCKET_STAGE",
 BUCKET_FORM = "fft.bucket.form"
 # the stager's whole stage of one bucket (pack, h2d, launch); args bucket, n
 BUCKET_STAGE = "fft.bucket.stage"
-# straggler draw and numpy pack into the padded bucket buffer
+# straggler draw and numpy pack into the padded bucket buffer; arg
+# reused, whether the buffer came from the service's free list
 STAGE_PACK = "fft.stage.pack"
 # host-to-device copy of the bucket's arguments; args bytes, dtype (of
 # the request array: float32 words for complex, DESIGN.md §8)

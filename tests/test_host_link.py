@@ -2,14 +2,19 @@
 and device as real words of the same bytes, rebuilt on the far side, and
 no bit of a request or an answer changes on the way."""
 
+import glob
+import importlib.util
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.kernels import ops
 from repro.serving import (FFTService, FFTServiceConfig, StreamConfig,
-                           StreamingFFTService)
+                           StreamingFFTService, spans)
 from repro.serving.fft_service import (_host_complex, _host_words,
                                        from_words, to_words)
 
@@ -167,3 +172,57 @@ def test_served_answers_match_numpy(front, kind):
         assert np.abs(y - ref).max() < 1e-2
     assert svc.stats.d2h_bytes == sum(
         b * (S if kind == "c2c" else S // 2 + 1) * 8 for b in (CAP, 2))
+
+
+def _c2c_reference():
+    """The benchmark's plain float64 reference of the c2c transform."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "references" / "c2c.py")
+    spec = importlib.util.spec_from_file_location("bench_ref_c2c", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.transform
+
+
+def _pack_spans(log_dir):
+    """The arguments of every ``fft.stage.pack`` span in a trace."""
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    return [dict(e.stats) for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU" for line in plane.lines
+            for e in line.events if e.name == spans.STAGE_PACK]
+
+
+def test_staging_buffer_is_not_reused_while_its_bucket_is_in_flight(
+        tmp_path):
+    """Two buckets staged and launched before either is fetched each pack
+    into a buffer of their own, and both answer the reference.  Once they
+    are fetched, the next bucket packs into a reused buffer, and its pack
+    span says so."""
+    svc = FFTService(_cfg())
+    transform = _c2c_reference()
+    buckets = [[_request("c2c", 10 * b + i) for i in range(CAP)]
+               for b in (1, 2)]
+    launched = []
+    for xs in buckets:
+        bucket, args = svc.stage_bucket(S, "c2c", xs)
+        launched.append(svc.launch_bucket(S, bucket, "c2c", args))
+    assert svc.stats.staging_allocated == 2
+    assert svc.stats.staging_reused == 0
+    for xs, done in zip(buckets, launched):
+        rows, _ = svc.fetch_bucket(done)
+        for x, y in zip(xs, rows):
+            ref = transform(x)
+            assert np.linalg.norm(y - ref) <= 1e-4 * np.linalg.norm(ref)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        bucket, args = svc.stage_bucket(S, "c2c", buckets[0])
+    finally:
+        jax.profiler.stop_trace()
+    assert svc.stats.staging_reused == 1
+    assert svc.stats.staging_allocated == 2
+    assert [bool(a["reused"]) for a in _pack_spans(tmp_path)] == [True]
+    rows, _ = svc.fetch_bucket(svc.launch_bucket(S, bucket, "c2c", args))
+    for x, y in zip(buckets[0], rows):
+        ref = transform(x)
+        assert np.linalg.norm(y - ref) <= 1e-4 * np.linalg.norm(ref)

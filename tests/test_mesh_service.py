@@ -5,7 +5,7 @@ device, with the service's own straggler masks.
 One subprocess (the host platform needs its device count before JAX
 starts) serves two full buckets under a profiler trace, then a
 real-input bucket, then one bucket with the whole of it landing on the
-first device.  The answers match the plain reference of
+first device, then two buckets in flight at once on the split ingress.  The answers match the plain reference of
 ``bench/references/c2c.py``; ``ServiceStats.broadcast_bytes`` and
 ``gather_bytes`` equal the bytes reckoned from the plan's shapes; each
 ``fft.stage.launch`` span names the mesh runner and its four devices."""
@@ -76,6 +76,18 @@ rows = first.submit_batch(xs[:cap])
 out["first_errors"] = [rel_l2(y, c2c.transform(x))
                        for x, y in zip(xs, rows)]
 out["first"] = dict(first.stats.summary(), latency=None, tiers=None)
+
+# two buckets in flight on the split ingress, where device_put may alias
+# the host buffer: the second packs into a buffer of its own
+held, staging = [], []
+for chunk in (xs[:cap], xs[cap:]):
+    bucket, args = svc.stage_bucket(s, "c2c", chunk)
+    staging.append(args.staging)
+    held.append(svc.launch_bucket(s, bucket, "c2c", args))
+out["in_flight_shared"] = bool(np.shares_memory(*staging))
+rows = [row for launched in held for row in svc.fetch_bucket(launched)[0]]
+out["in_flight_errors"] = [rel_l2(y, c2c.transform(x))
+                           for x, y in zip(xs, rows)]
 
 print(json.dumps(out))
 """
@@ -148,3 +160,9 @@ def test_first_device_ingress_matches_the_reference(served):
     first = served["first"]
     assert first["broadcast_bytes"] == (D - 1) * CAP * (S * 8 + N)
     assert first["gather_bytes"] == gathered(1, S // M)
+
+
+def test_buckets_in_flight_keep_their_staging_buffers(served):
+    assert not served["in_flight_shared"]
+    assert len(served["in_flight_errors"]) == 2 * CAP
+    assert max(served["in_flight_errors"]) <= 1e-4

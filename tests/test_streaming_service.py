@@ -527,6 +527,45 @@ def test_scheduler_stress_with_fault_injection():
     assert time.perf_counter() - t_start < 90.0, "wall-clock guard"
 
 
+@pytest.mark.parametrize("kind", ["c2c", "r2c"])
+def test_reused_staging_buffer_pads_with_zeros(kind):
+    """A partial bucket packs into the buffer a full bucket of the same
+    shape left behind: its padding rows read zero, not the earlier
+    requests, and its live rows answer numpy.fft.  Nine requests form a
+    bucket of 16, as the full one does (bucket_size rounds up to a power
+    of two)."""
+    cap, n = 16, 9
+    svc = FFTService(_cfg(max_batch=cap))
+    staged, bucket_args = [], svc._bucket_args
+
+    def snapshot(s, kind_, xb, masks):
+        staged.append(xb.copy())
+        return bucket_args(s, kind_, xb, masks)
+
+    svc._bucket_args = snapshot
+    if kind == "r2c":
+        rng = np.random.default_rng(7)
+        xs = [rng.normal(size=256).astype(np.float32)
+              for _ in range(cap + n)]
+        reference = np.fft.rfft
+    else:
+        xs = _reqs(cap + n, seed=7)
+        reference = np.fft.fft
+    with StreamingFFTService(svc, StreamConfig(slack_s=30.0)) as stream:
+        ys = [f.result(timeout=120)
+              for f in [stream.submit(x, kind=kind) for x in xs[:cap]]]
+        futs = [stream.submit(x, kind=kind) for x in xs[cap:]]
+        stream.flush()
+        ys += [f.result(timeout=120) for f in futs]
+    assert svc.stats.staging_allocated == 1
+    assert svc.stats.staging_reused == 1
+    assert [b.shape[0] for b in staged] == [cap, cap]
+    assert np.all(staged[0][n:] != 0)     # the full bucket's requests
+    assert np.all(staged[1][n:] == 0)
+    for x, y in zip(xs, ys):
+        assert np.abs(y - reference(x)).max() < 1e-2
+
+
 def test_latency_histogram_percentiles():
     h = LatencyHistogram()
     for v in [0.001] * 90 + [1.0] * 10:
