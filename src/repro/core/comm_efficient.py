@@ -32,7 +32,7 @@ Construction, on top of the standard (N, m) coded-FFT pipeline:
 .straggler.StragglerModel`'s wire model -- while the threshold rises from
 ``m`` to ``m*q``: the plan wins exactly when ``wire_frac`` is high and
 loses when compute dominates (the master now waits for the ``m*q``-th
-order statistic).  ``benchmarks/bench_comm_load.py`` races the crossover.
+order statistic).
 
 Decode is inherited wholesale from :class:`~repro.core.plan.MDSPlanBase`
 via the ``decode_generator`` / ``decode_width`` hooks -- the fold changed

@@ -269,7 +269,7 @@ class MDSPlanBase:
         return mds.encode_dft(c, self.n_workers).astype(self.dtype)
 
     def encode_dense(self, x: jax.Array) -> jax.Array:
-        """Reference O(N*m) matrix encode (kept for tests/benchmarks)."""
+        """Reference O(N*m) matrix encode (the tests' check of ``encode``)."""
         return self._map_batched(
             lambda xi: mds.encode(self.generator, self._message1(xi)),
             x, len(self.input_shape), "plan input")

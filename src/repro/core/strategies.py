@@ -21,7 +21,7 @@ et al. [13]; we cite the threshold rather than re-implement that paper).
 ``UncodedRepetitionFFT`` implements the :class:`repro.core.plan.CodedPlan`
 protocol (shape metadata, leading batch axes through encode/worker/decode)
 but NOT ``MDSPlan`` -- its replication code is not subset-decodable, which
-is exactly the Remark-4 gap the benchmarks demonstrate.
+is exactly the Remark-4 gap.
 """
 
 from __future__ import annotations
@@ -234,8 +234,7 @@ class UncodedRepetitionFFT:
 # so new plans auto-enroll everywhere a strategy choice exists: the
 # registry-parametrized property suite differentially verifies each entry
 # against numpy.fft under drawn configs/masks with zero new test code,
-# `FFTService(strategy=...)` resolves its bucket plans here, and the
-# benchmarks race whatever is registered.
+# and `FFTService(strategy=...)` resolves its bucket plans here.
 
 
 @dataclasses.dataclass(frozen=True)

@@ -24,8 +24,8 @@ so the service charges them ``payload_scale=0.5``:
 With the default ``payload_scale=1`` every formula reduces to the
 literature model above, whatever ``wire_frac`` is.
 
-These drive benchmarks/bench_latency.py: coded FFT (k=m, w=1/m) vs
-uncoded (k=N partitions, w=1/N) vs repetition / short-dot thresholds.
+The same model prices coded FFT (k=m, w=1/m) against uncoded (k=N
+partitions, w=1/N) and the repetition / short-dot thresholds.
 """
 
 from __future__ import annotations

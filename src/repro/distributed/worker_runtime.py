@@ -20,8 +20,12 @@ inside the FFT); the master
 
 ``require_all=True`` is the UNCODED baseline: the master needs every row
 (an uncoded partition has no slack), so one killed or delayed worker
-stalls the round into the retry machinery -- the measured bench races this
-against the coded ``threshold=m`` run under identical fault plans.
+stalls the round into the retry machinery.
+
+Time is read from a :class:`Clock`: the host's clock by default, or one
+slowed by a factor, which stretches every window, deadline and injected
+delay of a round over that many host seconds so its outcome does not hang
+on how busy the host is.
 
 Fault injection rides the same :class:`~repro.distributed.faults
 .FaultInjector` hook as the simulated path: killed workers never respond,
@@ -45,7 +49,33 @@ import numpy as np
 from repro.distributed.faults import FaultInjector, RoundFaults
 from repro.distributed.health import WorkerHealthTracker
 
-__all__ = ["MeasuredRound", "MeasuredWorkerRuntime"]
+__all__ = ["Clock", "MeasuredRound", "MeasuredWorkerRuntime"]
+
+
+class Clock:
+    """The measured runtime's time source, in the runtime's seconds.
+
+    ``slowdown``: host seconds per runtime second.  At 1 (the default) the
+    runtime keeps the host's time; above 1 its windows, deadlines and
+    injected delays all last ``slowdown`` times longer on the host, while
+    the work a worker does takes the same host time -- so a round's
+    arrivals are judged with that much more room for host scheduling.
+    """
+
+    def __init__(self, slowdown: float = 1.0):
+        if slowdown <= 0:
+            raise ValueError("slowdown must be > 0")
+        self.slowdown = float(slowdown)
+
+    def now(self) -> float:
+        return time.perf_counter() / self.slowdown
+
+    def sleep(self, seconds: float) -> None:
+        time.sleep(seconds * self.slowdown)
+
+    def host_seconds(self, seconds: float) -> float:
+        """A span of runtime seconds on the host's clock."""
+        return seconds * self.slowdown
 
 
 class MeasuredRound:
@@ -77,14 +107,16 @@ class MeasuredWorkerRuntime:
     body = fft along the last axis).  ``health`` is shared with the owning
     service so deadlines learn across rounds.  ``min_deadline_s`` floors
     the wait budget against scheduler jitter at sub-millisecond compute.
+    ``clock`` times the rounds (the host's clock unless given).
     """
 
     def __init__(self, plan, health: WorkerHealthTracker, *,
                  injector: Optional[FaultInjector] = None,
                  max_retries: int = 2, retry_backoff: float = 2.0,
                  require_all: bool = False, min_deadline_s: float = 2e-3,
-                 threshold_extra: int = 0):
+                 threshold_extra: int = 0, clock: Optional[Clock] = None):
         self.plan = plan
+        self.clock = clock if clock is not None else Clock()
         self.health = health
         self.injector = injector
         self.max_retries = int(max_retries)
@@ -127,7 +159,8 @@ class MeasuredWorkerRuntime:
         threshold = (int(alive.sum()) if self.require_all
                      else min(m + self.threshold_extra, int(alive.sum())))
         resq: queue_mod.Queue = queue_mod.Queue()
-        t_start = time.perf_counter()
+        clock = self.clock
+        t_start = clock.now()
 
         def compute_row(row: int) -> np.ndarray:
             a = np.tensordot(self.generator[row], c, axes=([0], [1]))  # (q, ell)
@@ -141,14 +174,14 @@ class MeasuredWorkerRuntime:
                 b_k = self.injector.corrupt_payload(b_k, k, round_idx)
             d = delay_map.get(k)
             if d:
-                time.sleep(d)
-            resq.put((k, b_k, time.perf_counter() - t_start))
+                clock.sleep(d)
+            resq.put((k, b_k, clock.now() - t_start))
 
         def redispatch(row: int) -> None:
             # a healthy thread recomputes the missing shard row: no fault
             # applies (the faulty worker is not the one computing it)
             b_k = compute_row(row)
-            resq.put((row, b_k, time.perf_counter() - t_start))
+            resq.put((row, b_k, clock.now() - t_start))
 
         for k in np.flatnonzero(alive):
             self.pool.submit(worker, int(k))
@@ -210,14 +243,13 @@ class MeasuredWorkerRuntime:
                              retries=retries, redispatched=redispatched,
                              times=times)
 
-    @staticmethod
-    def _collect(resq: queue_mod.Queue, got: dict, times: np.ndarray,
+    def _collect(self, resq: queue_mod.Queue, got: dict, times: np.ndarray,
                  window: float, t_start: float, threshold: int) -> None:
         """Drain arrivals until ``threshold`` rows are in or the window
         closes (first arrival per row wins: an original beating its
         re-dispatched copy is kept)."""
         while len(got) < threshold:
-            remaining = window - (time.perf_counter() - t_start)
+            remaining = window - (self.clock.now() - t_start)
             if remaining <= 0:
                 # non-blocking final sweep: arrivals already queued count
                 try:
@@ -230,7 +262,8 @@ class MeasuredWorkerRuntime:
                     return
                 continue
             try:
-                k, row, t = resq.get(timeout=remaining)
+                k, row, t = resq.get(
+                    timeout=self.clock.host_seconds(remaining))
             except queue_mod.Empty:
                 continue
             if k not in got:

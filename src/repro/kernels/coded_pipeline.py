@@ -59,23 +59,19 @@ __all__ = [
     "bucket_body",
     "bucket_body_masked",
     "bucket_body_fftworker",
-    "coded_fft_bucket",
     "coded_fft_bucket_masked",
-    "coded_fft_bucket_streaming",
     "coded_fft_bucket_streaming_masked",
     "pack_real_planes",
     "half_postdecode_body",
     "rbucket_body",
     "rbucket_body_masked",
     "rbucket_body_fftworker",
-    "coded_rfft_bucket",
     "coded_rfft_bucket_masked",
     "ir_message_body",
     "ir_unpack_body",
     "irbucket_body",
     "irbucket_body_masked",
     "irbucket_body_fftworker",
-    "coded_irfft_bucket",
     "coded_irfft_bucket_masked",
 ]
 
@@ -288,20 +284,15 @@ def bucket_body_masked(cr, ci, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                        twr, twi, fmr, fmi)
 
 
-def _core_kernel(masked, inverse, n_rec, *refs):
+def _core_kernel(inverse, n_rec, *refs):
     cr_ref, ci_ref = refs[:2]
-    if masked:
-        mk = refs[2][...]
-        rest = refs[3:]
-    else:
-        dr, di = refs[2][...], refs[3][...]
-        rest = refs[4:]
+    mk = refs[2][...]
+    rest = refs[3:]
     planes = [r[...] for r in rest[:8 + n_rec]]
     or_ref, oi_ref = rest[8 + n_rec:]
     gr, gi = planes[:2]
-    if masked:
-        n, m = gr.shape
-        dr, di = _masked_decode_planes(mk.reshape(mk.shape[0], n), m, n)
+    n, m = gr.shape
+    dr, di = _masked_decode_planes(mk.reshape(mk.shape[0], n), m, n)
     hr, hi = coded_core_body(cr_ref[...], ci_ref[...], dr, di, *planes[:8],
                              inverse=inverse)
     if n_rec:
@@ -310,13 +301,13 @@ def _core_kernel(masked, inverse, n_rec, *refs):
     oi_ref[...] = hi
 
 
-def _core_call(cr, ci, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+def _core_call(cr, ci, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                recombine=(), *, inverse=False, block_q=1, interpret=False,
                name):
     """One Pallas launch of the coded core over (q, m, A, B) message planes.
 
-    ``decode``: ``[dr, di]`` (q, m, N) scatter planes, or ``[masks]``
-    (q, N) raw responder masks (decode planes then built in-kernel).
+    ``masks``: (q, N) raw responder masks; each grid step builds its
+    requests' decode planes from them in VMEM (DESIGN.md §8).
     ``recombine``: the c2c ``(twr, twi, fmr, fmi)`` planes, or empty for
     the real kinds, whose butterflies run as XLA around the launch.
     Every block keeps the batch in a leading axis and the (A, B) tile
@@ -324,9 +315,7 @@ def _core_call(cr, ci, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
     """
     q, m, a, b = cr.shape
     n = gr.shape[0]
-    masked = len(decode) == 1
-    if masked:
-        decode = [decode[0].astype(cr.dtype).reshape(q, 1, n)]
+    masks = masks.astype(cr.dtype).reshape(q, 1, n)
     block_q = max(1, min(block_q, q))
 
     def blk(*shape):
@@ -337,65 +326,50 @@ def _core_call(cr, ci, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
         return pl.BlockSpec(x.shape, lambda i, r=x.ndim: (0,) * r)
 
     shared = [gr, gi, far, fai, wr, wi, fbr, fbi, *recombine]
-    decode_specs = [blk(1, n)] if masked else [blk(m, n)] * 2
     return pl.pallas_call(
-        functools.partial(_core_kernel, masked, inverse, len(recombine)),
+        functools.partial(_core_kernel, inverse, len(recombine)),
         grid=(pl.cdiv(q, block_q),),
-        in_specs=[blk(m, a, b)] * 2 + decode_specs
+        in_specs=[blk(m, a, b)] * 2 + [blk(1, n)]
         + [const(x) for x in shared],
         out_specs=[blk(m, a, b)] * 2,
         out_shape=[jax.ShapeDtypeStruct((q, m, a, b), cr.dtype)] * 2,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name=name,
-    )(cr, ci, *decode, *shared)
+    )(cr, ci, masks, *shared)
 
 
-def _bucket_call(xr, xi, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+def _bucket_call(xr, xi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                  twr, twi, fmr, fmi, block_q, interpret, name):
     q, s = xr.shape
     m = gr.shape[1]
     a, b = far.shape[0], fbr.shape[0]
     cr, ci = interleave_planes(xr, xi, m, a, b)
     rec = (twr.reshape(m, a, b), twi.reshape(m, a, b), fmr, fmi)
-    outr, outi = _core_call(cr, ci, decode, gr, gi, far, fai, wr, wi,
+    outr, outi = _core_call(cr, ci, masks, gr, gi, far, fai, wr, wi,
                             fbr, fbi, rec, block_q=block_q,
                             interpret=interpret, name=name)
     return (unscramble_planes(outr).reshape(q, s),
             unscramble_planes(outi).reshape(q, s))
 
 
-def coded_fft_bucket(xr, xi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
-                     twr, twi, fmr, fmi, *, block_q: int = 1,
-                     interpret: bool = False):
-    """Fused bucket pipeline: request planes -> output spectrum planes.
-
-    ``xr, xi``: (q, s) request planes; ``dr, di``: (q, m, N) per-request
-    scatter decode matrices; ``gr, gi``: (N, m) generator;
-    ``far/wr/fbr``: four-step DFT/twiddle planes for L = s/m = A*B;
-    ``twr``: (m, L) recombine twiddle in the scrambled payload order;
-    ``fmr``: (m, m) DFT.  Returns (q, s) planes of ``fft(x, axis=-1)``
-    decoded from the masked worker subset each ``D_q`` encodes.  The
-    interleave and the final unscramble are XLA relabels around the one
-    kernel launch.
-    """
-    return _bucket_call(xr, xi, [dr, di], gr, gi, far, fai, wr, wi, fbr, fbi,
-                        twr, twi, fmr, fmi, block_q, interpret,
-                        "coded_fft_bucket")
-
-
 def coded_fft_bucket_masked(xr, xi, masks, gr, gi, far, fai, wr, wi,
                             fbr, fbi, twr, twi, fmr, fmi, *, block_q: int = 1,
                             interpret: bool = False):
-    """:func:`coded_fft_bucket` taking raw ``(q, N)`` responder masks in
-    place of the ``(q, m, N)`` decode planes.
+    """Fused bucket pipeline: request planes -> output spectrum planes.
 
-    Subset selection (first-m-available) AND the per-request Lagrange
+    ``xr, xi``: (q, s) request planes; ``masks``: (q, N) raw responder
+    masks; ``gr, gi``: (N, m) generator; ``far/wr/fbr``: four-step
+    DFT/twiddle planes for L = s/m = A*B; ``twr``: (m, L) recombine
+    twiddle in the scrambled payload order; ``fmr``: (m, m) DFT.  Returns
+    (q, s) planes of ``fft(x, axis=-1)`` decoded from each request's
+    first-m responders.  Subset selection AND the per-request Lagrange
     decode matrices run INSIDE the kernel (VMEM-resident, DESIGN.md §8),
-    so the host ships the availability bits it already has -- zero decode
-    metadata, no host inversion or LRU at all.
+    so the host ships the availability bits it already has.  The
+    interleave and the final unscramble are XLA relabels around the one
+    kernel launch.
     """
-    return _bucket_call(xr, xi, [masks], gr, gi, far, fai, wr, wi, fbr, fbi,
+    return _bucket_call(xr, xi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                         twr, twi, fmr, fmi, block_q, interpret,
                         "coded_fft_bucket_masked")
 
@@ -538,7 +512,7 @@ def rbucket_body(xr, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
     The scrambled four-step order is undone BEFORE the butterfly -- the
     split needs natural reversed indexing.  The compiled path runs the
     same three steps with the core as one Pallas launch
-    (:func:`coded_rfft_bucket`).
+    (:func:`coded_rfft_bucket_masked`).
     """
     m = gr.shape[1]
     a, b = far.shape[0], fbr.shape[0]
@@ -583,43 +557,32 @@ def rbucket_body_fftworker(xr, dvr, dvi, subsets, gr, gi,
     return half_postdecode_body(hr, hi, swr, swi, twr, twi, fhr, fhi, s)
 
 
-def _rbucket_call(xr, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+def _rbucket_call(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                   swr, swi, twr, twi, fhr, fhi, s, block_q, interpret, name):
     m = gr.shape[1]
     a, b = far.shape[0], fbr.shape[0]
     zr, zi = _packed_views(xr, m, a, b)
-    hr, hi = _core_call(zr, zi, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+    hr, hi = _core_call(zr, zi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                         block_q=block_q, interpret=interpret, name=name)
     return half_postdecode_body(unscramble_planes(hr), unscramble_planes(hi),
                                 swr, swi, twr, twi, fhr, fhi, s)
 
 
-def coded_rfft_bucket(xr, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
-                      swr, swi, twr, twi, fhr, fhi, s, *, block_q: int = 1,
-                      interpret: bool = False):
-    """Fused r2c bucket pipeline: real request planes -> half-spectrum
-    planes, the coded core as one Pallas launch.
-
-    ``xr``: (q, s) REAL request plane (no imag plane exists); ``dr, di``:
-    (q, m, N) scatter decode matrices; ``far/wr/fbr``: four-step planes for
-    the HALF length L/2 = A*B; ``swr``: (1, L/2+1) split twiddle; ``twr``:
-    (m, L) recombine twiddle; ``fhr``: (m//2+1, m) DFT rows.  Returns
-    (q, s//2+1) planes of ``rfft(x, axis=-1)``.  The pair packing and the
-    symmetry butterfly (index reversal, odd-length edges) run as XLA
-    around the launch.
-    """
-    return _rbucket_call(xr, [dr, di], gr, gi, far, fai, wr, wi, fbr, fbi,
-                         swr, swi, twr, twi, fhr, fhi, s, block_q, interpret,
-                         "coded_rfft_bucket")
-
-
 def coded_rfft_bucket_masked(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                              swr, swi, twr, twi, fhr, fhi, s, *,
                              block_q: int = 1, interpret: bool = False):
-    """:func:`coded_rfft_bucket` taking raw ``(q, N)`` responder masks in
-    place of decode planes -- subset selection AND the Lagrange weights
-    run in VMEM per grid step (DESIGN.md §8)."""
-    return _rbucket_call(xr, [masks], gr, gi, far, fai, wr, wi, fbr, fbi,
+    """Fused r2c bucket pipeline: real request planes -> half-spectrum
+    planes, the coded core as one Pallas launch.
+
+    ``xr``: (q, s) REAL request plane (no imag plane exists); ``masks``:
+    (q, N) raw responder masks, decoded in VMEM per grid step (DESIGN.md
+    §8); ``far/wr/fbr``: four-step planes for the HALF length L/2 = A*B;
+    ``swr``: (1, L/2+1) split twiddle; ``twr``: (m, L) recombine twiddle;
+    ``fhr``: (m//2+1, m) DFT rows.  Returns (q, s//2+1) planes of
+    ``rfft(x, axis=-1)``.  The pair packing and the symmetry butterfly
+    (index reversal, odd-length edges) run as XLA around the launch.
+    """
+    return _rbucket_call(xr, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                          swr, swi, twr, twi, fhr, fhi, s, block_q, interpret,
                          "coded_rfft_bucket_masked")
 
@@ -743,45 +706,32 @@ def irbucket_body_fftworker(yr, yi, dvr, dvi, subsets, gr, gi,
     return ir_unpack_body(hr, hi)
 
 
-def _irbucket_call(yr, yi, decode, gr, gi, far, fai, wr, wi, fbr, fbi,
+def _irbucket_call(yr, yi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
                    fpr, fpi, ctwr, ctwi, pwr, pwi, s, block_q, interpret, name):
     m = gr.shape[1]
     a, b = far.shape[0], fbr.shape[0]
     zr, zi = _ir_message_views(yr, yi, fpr, fpi, ctwr, ctwi, pwr, pwi,
                                s, m, a, b)
-    hr, hi = _core_call(zr, zi, decode, gr, -gi, far, fai, wr, wi, fbr, fbi,
+    hr, hi = _core_call(zr, zi, masks, gr, -gi, far, fai, wr, wi, fbr, fbi,
                         inverse=True, block_q=block_q, interpret=interpret,
                         name=name)
     return ir_unpack_body(unscramble_planes(hr), unscramble_planes(hi))
 
 
-def coded_irfft_bucket(yr, yi, dr, di, gr, gi, far, fai, wr, wi, fbr, fbi,
-                       fpr, fpi, ctwr, ctwi, pwr, pwi, s, *, block_q: int = 1,
-                       interpret: bool = False):
-    """Fused c2r bucket pipeline: half-spectrum request planes -> ONE real
-    output plane, the coded core as one Pallas launch (DESIGN.md §9).
-
-    ``yr, yi``: (q, s//2+1) request planes; ``dr, di``: (q, m, N) scatter
-    decode matrices; ``far/wr/fbr``: four-step planes for the HALF length
-    L/2 = A*B; ``fpr``: (m, m) +sign DFT planes and ``ctwr``: (m, L)
-    conjugate twiddle of the adjoint message butterfly; ``pwr``:
-    (1, L/2+1) pack twiddle.  Returns the (q, s) real plane of
-    ``irfft(y, n=s, axis=-1)`` decoded from the masked worker subset each
-    ``D_q`` encodes.
-    """
-    return _irbucket_call(yr, yi, [dr, di], gr, gi, far, fai, wr, wi,
-                          fbr, fbi, fpr, fpi, ctwr, ctwi, pwr, pwi, s,
-                          block_q, interpret, "coded_irfft_bucket")
-
-
 def coded_irfft_bucket_masked(yr, yi, masks, gr, gi, far, fai, wr, wi,
                               fbr, fbi, fpr, fpi, ctwr, ctwi, pwr, pwi, s, *,
                               block_q: int = 1, interpret: bool = False):
-    """:func:`coded_irfft_bucket` taking raw ``(q, N)`` responder masks in
-    place of decode planes -- subset selection and the Lagrange weights are
-    built in VMEM per grid step (DESIGN.md §8), completing the
-    device-resident path for all four kinds."""
-    return _irbucket_call(yr, yi, [masks], gr, gi, far, fai, wr, wi,
+    """Fused c2r bucket pipeline: half-spectrum request planes -> ONE real
+    output plane, the coded core as one Pallas launch (DESIGN.md §9).
+
+    ``yr, yi``: (q, s//2+1) request planes; ``masks``: (q, N) raw
+    responder masks, decoded in VMEM per grid step (DESIGN.md §8);
+    ``far/wr/fbr``: four-step planes for the HALF length L/2 = A*B;
+    ``fpr``: (m, m) +sign DFT planes and ``ctwr``: (m, L) conjugate
+    twiddle of the adjoint message butterfly; ``pwr``: (1, L/2+1) pack
+    twiddle.  Returns the (q, s) real plane of ``irfft(y, n=s, axis=-1)``.
+    """
+    return _irbucket_call(yr, yi, masks, gr, gi, far, fai, wr, wi,
                           fbr, fbi, fpr, fpi, ctwr, ctwi, pwr, pwi, s,
                           block_q, interpret, "coded_irfft_bucket_masked")
 
@@ -803,16 +753,9 @@ def coded_irfft_bucket_masked(yr, yi, masks, gr, gi, far, fai, wr, wi,
 # stage fallback for over-budget shapes.
 
 
-def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
-                             *refs):
-    xr_hbm, xi_hbm = refs[:2]
-    rest = refs[2:]
-    if masked:
-        mk_ref = rest[0]
-        rest = rest[1:]
-    else:
-        dr_ref, di_ref = rest[:2]
-        rest = rest[2:]
+def _streaming_bucket_kernel(nbt, nat, block_q, block_a, block_b, *refs):
+    xr_hbm, xi_hbm, mk_ref = refs[:3]
+    rest = refs[3:]
     (gr_ref, gi_ref, far_ref, fai_ref, wr_ref, wi_ref, fbr_ref, fbi_ref,
      twr_ref, twi_ref, fmr_ref, fmi_ref) = rest[:12]
     (or_hbm, oi_hbm, t1r_hbm, t1i_hbm,
@@ -826,11 +769,8 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
     q0 = pl.program_id(0) * block_q
 
     # per-request decode planes, once per batch block (tiny: (bq, m, n))
-    if masked:
-        mk = mk_ref[...]
-        dr, di = _masked_decode_planes(mk.reshape(bq, n), m, n)
-    else:
-        dr, di = dr_ref[...], di_ref[...]
+    mk = mk_ref[...]
+    dr, di = _masked_decode_planes(mk.reshape(bq, n), m, n)
 
     # ---- phase A: stage 1 + twiddle over B-column tiles -> t1 HBM scratch
     def a_copies(j, slot):
@@ -943,7 +883,7 @@ def _streaming_bucket_kernel(masked, nbt, nat, block_q, block_a, block_b,
     jax.lax.fori_loop(0, nat, phase_b, 0)
 
 
-def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
+def _streaming_bucket_call(xr, xi, masks, gr, gi, far, fai,
                            wr, wi, fbr, fbi, twr, twi, fmr, fmi,
                            block_q, block_a, block_b, interpret, name):
     q, s = xr.shape
@@ -957,13 +897,8 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
     if pad:  # DMA tile sizes are static: round the batch up
         cr = jnp.concatenate([cr, jnp.zeros((pad, m, a, b), f32)])
         ci = jnp.concatenate([ci, jnp.zeros((pad, m, a, b), f32)])
-        if masked:  # all-available filler keeps the Lagrange nodes distinct
-            decode_args = [jnp.concatenate(
-                [decode_args[0], jnp.ones((pad, n), f32)])]
-        else:
-            decode_args = [
-                jnp.concatenate([d, jnp.zeros((pad, m, n), f32)])
-                for d in decode_args]
+        # all-available filler keeps the Lagrange nodes distinct
+        masks = jnp.concatenate([masks, jnp.ones((pad, n), f32)])
     qp = q + pad
     block_a = _even_divisor(a, block_a)
     block_b = _even_divisor(b, block_b)
@@ -975,13 +910,9 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
     def vspec(*shape):
         return pl.BlockSpec(shape, lambda i, r=len(shape): (0,) * r)
 
-    if masked:
-        decode_args = [decode_args[0].reshape(qp, 1, n)]
-        decode_specs = [pl.BlockSpec((block_q, 1, n), lambda i: (i, 0, 0))]
-    else:
-        decode_specs = [
-            pl.BlockSpec((block_q, m, n), lambda i: (i, 0, 0))] * 2
-    in_specs = [any_spec, any_spec, *decode_specs,
+    masks = masks.reshape(qp, 1, n)
+    in_specs = [any_spec, any_spec,
+                pl.BlockSpec((block_q, 1, n), lambda i: (i, 0, 0)),
                 vspec(n, m), vspec(n, m), vspec(a, a), vspec(a, a),
                 vspec(a, b), vspec(a, b), vspec(b, b), vspec(b, b),
                 vspec(m, a, b), vspec(m, a, b), vspec(m, m), vspec(m, m)]
@@ -1006,7 +937,7 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
         pltpu.SemaphoreType.DMA((2,)),
     ]
     outs = pl.pallas_call(
-        functools.partial(_streaming_bucket_kernel, masked, nbt, nat,
+        functools.partial(_streaming_bucket_kernel, nbt, nat,
                           block_q, block_a, block_b),
         grid=(qp // block_q,),
         in_specs=in_specs,
@@ -1016,26 +947,10 @@ def _streaming_bucket_call(masked, xr, xi, decode_args, gr, gi, far, fai,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
         name=name,
-    )(cr, ci, *decode_args, gr, gi, far, fai, wr, wi, fbr, fbi,
+    )(cr, ci, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
       twr.reshape(m, a, b), twi.reshape(m, a, b), fmr, fmi)
     return (unscramble_planes(outs[0][:q]).reshape(q, s),
             unscramble_planes(outs[1][:q]).reshape(q, s))
-
-
-def coded_fft_bucket_streaming(xr, xi, dr, di, gr, gi, far, fai, wr, wi,
-                               fbr, fbi, twr, twi, fmr, fmi, *,
-                               block_q: int = 1, block_a: int = 256,
-                               block_b: int = 256, interpret: bool = False):
-    """One-launch streaming c2c bucket for shapes beyond the VMEM budget.
-
-    Same contract as :func:`coded_fft_bucket` (including the pre-scrambled
-    ``twr/twi``) but only (block_q, A, block_b, m) / (block_q, block_a, B,
-    m) tiles are VMEM-resident, double-buffered against HBM.
-    """
-    return _streaming_bucket_call(
-        False, xr, xi, [dr, di], gr, gi, far, fai, wr, wi, fbr, fbi,
-        twr, twi, fmr, fmi, block_q, block_a, block_b, interpret,
-        "coded_fft_bucket_streaming")
 
 
 def coded_fft_bucket_streaming_masked(xr, xi, masks, gr, gi, far, fai, wr, wi,
@@ -1043,12 +958,15 @@ def coded_fft_bucket_streaming_masked(xr, xi, masks, gr, gi, far, fai, wr, wi,
                                       block_q: int = 1, block_a: int = 256,
                                       block_b: int = 256,
                                       interpret: bool = False):
-    """:func:`coded_fft_bucket_streaming` taking raw ``(q, N)`` responder
-    masks: in-kernel subset selection + Lagrange decode (DESIGN.md §8), so
-    the biggest buckets keep both the one-launch AND the zero-metadata
-    contracts."""
+    """One-launch streaming c2c bucket for shapes beyond the VMEM budget.
+
+    Same contract as :func:`coded_fft_bucket_masked` (including the
+    pre-scrambled ``twr/twi``) but only (block_q, A, block_b, m) /
+    (block_q, block_a, B, m) tiles are VMEM-resident, double-buffered
+    against HBM; subset selection and the Lagrange decode run in-kernel
+    (DESIGN.md §8)."""
     masks = masks.astype(xr.dtype)
     return _streaming_bucket_call(
-        True, xr, xi, [masks], gr, gi, far, fai, wr, wi, fbr, fbi,
+        xr, xi, masks, gr, gi, far, fai, wr, wi, fbr, fbi,
         twr, twi, fmr, fmi, block_q, block_a, block_b, interpret,
         "coded_fft_bucket_streaming_masked")

@@ -41,30 +41,22 @@ from repro.kernels.cmatmul import (
     cmatmul_body,
 )
 from repro.kernels.coded_pipeline import (
-    bucket_body,
     bucket_body_fftworker,
     bucket_body_masked,
-    coded_fft_bucket,
     coded_fft_bucket_masked,
-    coded_fft_bucket_streaming,
     coded_fft_bucket_streaming_masked,
-    coded_irfft_bucket,
     coded_irfft_bucket_masked,
-    coded_rfft_bucket,
     coded_rfft_bucket_masked,
     half_postdecode_body,
     interleave_planes,
     ir_message_body,
     ir_unpack_body,
-    irbucket_body,
     irbucket_body_fftworker,
     irbucket_body_masked,
     lagrange_planes_body,
     pack_real_planes,
-    rbucket_body,
     rbucket_body_fftworker,
     rbucket_body_masked,
-    subsets_from_masks_body,
     unscramble_planes,
 )
 from repro.kernels.fourstep_fft import (
@@ -100,23 +92,20 @@ __all__ = [
     "mask_subsets",
     "lagrange_compact_planes",
     "lagrange_scatter_planes",
-    "coded_bucket",
     "coded_bucket_direct",
     "coded_bucket_fusable",
     "coded_bucket_streamable",
     "coded_bucket_masked",
-    "coded_rbucket",
+    "coded_bucket_staged",
     "coded_rbucket_direct",
     "coded_rbucket_fusable",
     "coded_rbucket_masked",
-    "coded_irbucket",
+    "coded_rbucket_staged",
     "coded_irbucket_direct",
     "coded_irbucket_fusable",
     "coded_irbucket_masked",
+    "coded_irbucket_staged",
     "pack_real_planes",
-    "rfft_postdecode_planar",
-    "irfft_message_planar",
-    "irfft_unpack_planar",
     "mds_apply",
     "recombine_fused",
     "interleave_words",
@@ -615,74 +604,24 @@ def _streaming_blocks(kind: str, mode: str, **params):
             int(ent.get("block_b", _STREAM_TILE) or _STREAM_TILE))
 
 
-def _bucket_direct(body, xr, xi, decode, gr, gi, planes):
-    """The c2c bucket body on the full batch as straight XLA: the same
-    interleave -> body -> unscramble the kernel wrappers run around their
-    launch."""
-    q, s = xr.shape
-    m = gr.shape[1]
-    a, b = planes[0].shape[0], planes[4].shape[0]
-    twr, twi = (p.reshape(m, a, b) for p in planes[6:8])
-    cr, ci = interleave_planes(xr, xi, m, a, b)
-    yr, yi = body(cr, ci, *decode, gr, gi, *planes[:6], twr, twi,
-                  *planes[8:])
-    return (unscramble_planes(yr).reshape(q, s),
-            unscramble_planes(yi).reshape(q, s))
-
-
-def coded_bucket(xr: jax.Array, xi: jax.Array,
-                 dr: jax.Array, di: jax.Array,
-                 gr: jax.Array, gi: jax.Array, s: int, *,
-                 interpret: bool | None = None,
-                 block_q: int | None = None,
-                 precision: str = "f32"):
-    """The service's whole-bucket hot path as ONE Pallas launch.
-
-    ``xr, xi``: (q, s) request planes; ``dr, di``: (q, m, N) per-request
-    scatter decode matrices; ``gr, gi``: (N, m) generator planes.  Returns
-    (q, s) output planes -- interleave, fused encode+worker, decode matmul
-    and recombine with no HBM round-trips between stages (DESIGN.md §6).
-    Shapes beyond :func:`coded_bucket_fusable` route to the streaming
-    double-buffered grid when :func:`coded_bucket_streamable` allows;
-    ``block_q=None`` consults the autotune table, ``precision="bf16"``
-    casts the shared planes (f32 accumulation throughout).
-    """
-    mode = _mode(interpret)
-    q, s_ = xr.shape
-    n, m = gr.shape
-    ell = s // m
-    a, b = split_factor(ell)
-    dt = _plane_dtype(precision)
-    planes = (*_dft_planes(a, dt), *_twiddle_planes(a, b, dt),
-              *_dft_planes(b, dt), *_recombine_planes_scrambled(s, m, a, b, dt))
-    if mode == "direct":
-        return _bucket_direct(bucket_body, xr, xi, [dr, di], gr, gi, planes)
-    itp = mode == "interpret"
-    if not coded_bucket_fusable(s, m, n) and coded_bucket_streamable(s, m, n):
-        bq, ba, bb = _streaming_blocks("bucket", mode, s=s, m=m, n=n)
-        return coded_fft_bucket_streaming(
-            xr, xi, dr, di, gr, gi, *planes,
-            block_q=(block_q or bq), block_a=ba, block_b=bb, interpret=itp)
-    if block_q is None:
-        block_q = _tuned_block_q("bucket", q, 2 * s + (m + n) * ell, mode,
-                                 s=s, m=m, n=n)
-    return coded_fft_bucket(
-        xr, xi, dr, di, gr, gi, *planes, block_q=block_q, interpret=itp)
-
-
 def coded_bucket_masked(xr: jax.Array, xi: jax.Array, masks: jax.Array,
                         gr: jax.Array, gi: jax.Array, s: int, *,
                         interpret: bool | None = None,
                         block_q: int | None = None,
                         precision: str = "f32"):
-    """:func:`coded_bucket` with IN-KERNEL decode matrices (DESIGN.md §8).
+    """The service's whole-bucket c2c hot path as ONE Pallas launch.
 
-    ``masks``: (q, N) responder masks, shipped RAW -- subset selection
-    (first-m responders) now happens inside the kernel
-    (``subsets_from_masks_body``), then the Lagrange weights are built in
-    VMEM per grid step and contracted immediately; nothing decode-related
-    crosses the host boundary.  Same fused/streaming routing as
-    :func:`coded_bucket`.
+    ``xr, xi``: (q, s) request planes; ``masks``: (q, N) responder masks,
+    shipped RAW -- subset selection (first-m responders) happens inside
+    the kernel (``subsets_from_masks_body``), then the Lagrange weights are
+    built in VMEM per grid step and contracted immediately (DESIGN.md §8);
+    ``gr, gi``: (N, m) generator planes.  Returns (q, s) output planes --
+    interleave, fused encode+worker, decode and recombine with no HBM
+    round-trips between stages (DESIGN.md §6).  Shapes beyond
+    :func:`coded_bucket_fusable` route to the streaming double-buffered
+    grid when :func:`coded_bucket_streamable` allows; ``block_q=None``
+    consults the autotune table, ``precision="bf16"`` casts the shared
+    planes (f32 accumulation throughout).
     """
     mode = _mode(interpret)
     q, _ = xr.shape
@@ -693,8 +632,14 @@ def coded_bucket_masked(xr: jax.Array, xi: jax.Array, masks: jax.Array,
     planes = (*_dft_planes(a, dt), *_twiddle_planes(a, b, dt),
               *_dft_planes(b, dt), *_recombine_planes_scrambled(s, m, a, b, dt))
     if mode == "direct":
-        return _bucket_direct(bucket_body_masked, xr, xi, [masks], gr, gi,
-                              planes)
+        # the body on the full batch as straight XLA, between the same
+        # interleave and unscramble the kernels run around their launch
+        twr, twi = (p.reshape(m, a, b) for p in planes[6:8])
+        cr, ci = interleave_planes(xr, xi, m, a, b)
+        yr, yi = bucket_body_masked(cr, ci, masks, gr, gi, *planes[:6],
+                                    twr, twi, *planes[8:])
+        return (unscramble_planes(yr).reshape(q, s),
+                unscramble_planes(yi).reshape(q, s))
     itp = mode == "interpret"
     if not coded_bucket_fusable(s, m, n) and coded_bucket_streamable(s, m, n):
         bq, ba, bb = _streaming_blocks("bucket", mode, s=s, m=m, n=n)
@@ -714,7 +659,7 @@ def coded_bucket_direct(xr: jax.Array, xi: jax.Array,
                         gr: jax.Array, gi: jax.Array, s: int):
     """The off-TPU bucket executor: same fused pipeline, host lowerings.
 
-    Same stage structure as :func:`coded_bucket`, with the worker DFT on
+    Same stage structure as :func:`coded_bucket_masked`, with the worker DFT on
     the platform FFT and the decode as gathered compact ``(m, m)``
     matmuls (``dvr/dvi`` inverses + ``subsets`` responder indices from
     ``DecodeMatrixCache.compact``) -- the lowerings a Mosaic kernel cannot
@@ -724,6 +669,25 @@ def coded_bucket_direct(xr: jax.Array, xi: jax.Array,
     m = gr.shape[1]
     return bucket_body_fftworker(
         xr, xi, dvr, dvi, subsets, gr, gi, *_recombine_planes(s, m))
+
+
+def coded_bucket_staged(xr: jax.Array, xi: jax.Array,
+                        dr: jax.Array, di: jax.Array,
+                        gr: jax.Array, gi: jax.Array, s: int, *,
+                        interpret: bool | None = None):
+    """The c2c bucket as stage kernels, for shapes past both one-launch
+    gates or decode planes from the host: interleave on planes
+    (``c_i[j] = x[i + j*m]``), fused encode+worker, one batched decode
+    matmul against the ``(q, m, N)`` scatter planes ``dr, di``, fused
+    twiddle+DFT recombine.  Returns (q, s) output planes."""
+    q = xr.shape[0]
+    m = gr.shape[1]
+    ell = s // m
+    cr = jnp.swapaxes(xr.reshape(q, ell, m), -1, -2)
+    ci = jnp.swapaxes(xi.reshape(q, ell, m), -1, -2)
+    br, bi = encode_worker(cr, ci, gr, gi, interpret=interpret)
+    hr, hi = decode_apply(dr, di, br, bi, interpret=interpret)
+    return recombine_planar(hr, hi, s, interpret=interpret)
 
 
 # ------------------------------------------------- real-input (r2c) buckets
@@ -746,45 +710,20 @@ def _r2c_postdecode_planes(s: int, m: int, dtype=np.float32):
             *_half_dft_planes(m, dtype))
 
 
-def coded_rbucket(xr: jax.Array, dr: jax.Array, di: jax.Array,
-                  gr: jax.Array, gi: jax.Array, s: int, *,
-                  interpret: bool | None = None,
-                  block_q: int | None = None,
-                  precision: str = "f32"):
-    """The r2c whole-bucket hot path (DESIGN.md §7) as ONE Pallas launch.
-
-    ``xr``: (q, s) REAL request plane; ``dr, di``: (q, m, N) scatter decode
-    matrices; ``gr, gi``: (N, m) generator planes.  Returns (q, s//2+1)
-    half-spectrum planes.  Caller checks :func:`coded_rbucket_fusable`
-    (the packed-butterfly pairing couples column p with n2-p, so the r2c
-    pipeline has no column-local streaming variant -- see DESIGN.md §10).
-    """
-    mode = _mode(interpret)
-    q, _ = xr.shape
-    n, m = gr.shape
-    n2 = s // m // 2
-    a, b = split_factor(n2)
-    dt = _plane_dtype(precision)
-    planes = (*_dft_planes(a, dt), *_twiddle_planes(a, b, dt),
-              *_dft_planes(b, dt), *_r2c_postdecode_planes(s, m, dt))
-    if mode == "direct":
-        return rbucket_body(xr, dr, di, gr, gi, *planes, s)
-    itp = mode == "interpret"
-    if block_q is None:
-        block_q = _tuned_block_q("rbucket", q, 2 * s + (m + n) * n2, mode,
-                                 s=s, m=m, n=n)
-    return coded_rfft_bucket(xr, dr, di, gr, gi, *planes, s,
-                             block_q=block_q, interpret=itp)
-
-
 def coded_rbucket_masked(xr: jax.Array, masks: jax.Array,
                          gr: jax.Array, gi: jax.Array, s: int, *,
                          interpret: bool | None = None,
                          block_q: int | None = None,
                          precision: str = "f32"):
-    """:func:`coded_rbucket` with in-kernel subset selection + Lagrange
-    decode from raw ``(q, N)`` responder masks
-    (cf. :func:`coded_bucket_masked`)."""
+    """The r2c whole-bucket hot path (DESIGN.md §7) as ONE Pallas launch.
+
+    ``xr``: (q, s) REAL request plane; ``masks``: (q, N) raw responder
+    masks, decoded in the kernel (cf. :func:`coded_bucket_masked`);
+    ``gr, gi``: (N, m) generator planes.  Returns (q, s//2+1)
+    half-spectrum planes.  Caller checks :func:`coded_rbucket_fusable`
+    (the packed-butterfly pairing couples column p with n2-p, so the r2c
+    pipeline has no column-local streaming variant -- see DESIGN.md §10).
+    """
     mode = _mode(interpret)
     q, _ = xr.shape
     n, m = gr.shape
@@ -814,13 +753,18 @@ def coded_rbucket_direct(xr: jax.Array, dvr: jax.Array, dvi: jax.Array,
         xr, dvr, dvi, subsets, gr, gi, *_r2c_postdecode_planes(s, m), s)
 
 
-def rfft_postdecode_planar(hr: jax.Array, hi: jax.Array, s: int):
-    """Stage-path r2c postdecode: decoded packed-spectrum planes
-    ``(q, m, L/2)`` (natural order) -> half-spectrum planes
-    ``(q, s//2+1)``.  Elementwise butterfly + one (m//2+1, m) contraction;
-    runs as straight XLA in every mode (it is a fraction of the decode
-    matmul's cost at any bucket shape)."""
-    m = hr.shape[1]
+def coded_rbucket_staged(xr: jax.Array, dr: jax.Array, di: jax.Array,
+                         gr: jax.Array, gi: jax.Array, s: int, *,
+                         interpret: bool | None = None):
+    """The r2c bucket as stage kernels (cf. :func:`coded_bucket_staged`):
+    pair packing, fused encode+worker on the half-length shards, decode
+    matmul, then the symmetry postdecode -- an elementwise butterfly and
+    one (m//2+1, m) contraction, straight XLA in every mode.  Returns
+    (q, s//2+1) planes."""
+    m = gr.shape[1]
+    zr, zi = pack_real_planes(xr, m)
+    br, bi = encode_worker(zr, zi, gr, gi, interpret=interpret)
+    hr, hi = decode_apply(dr, di, br, bi, interpret=interpret)
     return half_postdecode_body(hr, hi, *_r2c_postdecode_planes(s, m), s)
 
 
@@ -842,48 +786,21 @@ def coded_irbucket_fusable(s: int, m: int, n: int) -> bool:
     return coded_rbucket_fusable(s, m, n)
 
 
-def coded_irbucket(yr: jax.Array, yi: jax.Array,
-                   dr: jax.Array, di: jax.Array,
-                   gr: jax.Array, gi: jax.Array, s: int, *,
-                   interpret: bool | None = None,
-                   block_q: int | None = None,
-                   precision: str = "f32"):
-    """The c2r whole-bucket hot path (DESIGN.md §9) as ONE Pallas launch.
-
-    ``yr, yi``: (q, s//2+1) half-spectrum request planes; ``dr, di``:
-    (q, m, N) scatter decode matrices; ``gr, gi``: (N, m) generator
-    planes.  Returns the (q, s) REAL output plane -- adjoint message
-    butterfly, fused encode + half-length ifft worker (conj trick on
-    planes), decode matmul and pair unpack with no HBM round-trips
-    between stages.  Caller checks :func:`coded_irbucket_fusable`.
-    """
-    mode = _mode(interpret)
-    q, _ = yr.shape
-    n, m = gr.shape
-    n2 = s // m // 2
-    a, b = split_factor(n2)
-    dt = _plane_dtype(precision)
-    planes = (*_dft_planes(a, dt), *_twiddle_planes(a, b, dt),
-              *_dft_planes(b, dt), *_c2r_message_planes(s, m, dt))
-    if mode == "direct":
-        return irbucket_body(yr, yi, dr, di, gr, gi, *planes, s)
-    itp = mode == "interpret"
-    if block_q is None:
-        block_q = _tuned_block_q("irbucket", q, 2 * s + (m + n) * n2, mode,
-                                 s=s, m=m, n=n)
-    return coded_irfft_bucket(yr, yi, dr, di, gr, gi, *planes, s,
-                              block_q=block_q, interpret=itp)
-
-
 def coded_irbucket_masked(yr: jax.Array, yi: jax.Array, masks: jax.Array,
                           gr: jax.Array, gi: jax.Array, s: int, *,
                           interpret: bool | None = None,
                           block_q: int | None = None,
                           precision: str = "f32"):
-    """:func:`coded_irbucket` with in-kernel subset selection + Lagrange
-    decode from raw ``(q, N)`` responder masks
-    (cf. :func:`coded_bucket_masked`) -- all four kinds share the §8
-    zero-metadata device-resident decode path."""
+    """The c2r whole-bucket hot path (DESIGN.md §9) as ONE Pallas launch.
+
+    ``yr, yi``: (q, s//2+1) half-spectrum request planes; ``masks``:
+    (q, N) raw responder masks, decoded in the kernel (cf.
+    :func:`coded_bucket_masked`); ``gr, gi``: (N, m) generator planes.
+    Returns the (q, s) REAL output plane -- adjoint message butterfly,
+    fused encode + half-length ifft worker (conj trick on planes), decode
+    and pair unpack with no HBM round-trips between stages.  Caller checks
+    :func:`coded_irbucket_fusable`.
+    """
     mode = _mode(interpret)
     q, _ = yr.shape
     n, m = gr.shape
@@ -902,19 +819,6 @@ def coded_irbucket_masked(yr: jax.Array, yi: jax.Array, masks: jax.Array,
                                      block_q=block_q, interpret=itp)
 
 
-def irfft_message_planar(yr: jax.Array, yi: jax.Array, s: int, m: int):
-    """Stage-path c2r message stage: half-spectrum request planes
-    ``(q, s//2+1)`` -> packed message planes ``(q, m, L/2)`` (the adjoint
-    recombine butterfly + Hermitian pack, DESIGN.md §7)."""
-    return ir_message_body(yr, yi, *_c2r_message_planes(s, m), s, m)
-
-
-def irfft_unpack_planar(hr: jax.Array, hi: jax.Array):
-    """Stage-path c2r postdecode: decoded packed interleave planes
-    ``(q, m, L/2)`` -> the real output plane ``(q, s)``."""
-    return ir_unpack_body(hr, hi)
-
-
 def coded_irbucket_direct(yr: jax.Array, yi: jax.Array,
                           dvr: jax.Array, dvi: jax.Array,
                           subsets: jax.Array,
@@ -925,6 +829,24 @@ def coded_irbucket_direct(yr: jax.Array, yi: jax.Array,
     m = gr.shape[1]
     return irbucket_body_fftworker(
         yr, yi, dvr, dvi, subsets, gr, gi, *_c2r_message_planes(s, m), s)
+
+
+def coded_irbucket_staged(yr: jax.Array, yi: jax.Array,
+                          dr: jax.Array, di: jax.Array,
+                          gr: jax.Array, gi: jax.Array, s: int, *,
+                          interpret: bool | None = None):
+    """The c2r bucket as stage kernels (cf. :func:`coded_bucket_staged`):
+    adjoint message stage, the ifft worker as
+    ``ifft(G @ z) = conj(fft(conj(G) @ conj(z))) / n2`` through the same
+    fused encode+worker kernel, decode matmul, relabel unpack.  Returns
+    ONE real plane (q, s)."""
+    m = gr.shape[1]
+    n2 = s // m // 2
+    zr, zi = ir_message_body(yr, yi, *_c2r_message_planes(s, m), s, m)
+    br, bi = encode_worker(zr, -zi, gr, -gi, interpret=interpret)
+    br, bi = br / n2, -bi / n2
+    hr, hi = decode_apply(dr, di, br, bi, interpret=interpret)
+    return ir_unpack_body(hr, hi)
 
 
 # ----------------------------------------------------- complex entry points

@@ -5,7 +5,7 @@ service path builds per-request matrices inside the jitted bucket executor
 via the closed-form Lagrange inversion (``mds.lagrange_inverse``), and the
 LRU serves only ``m > mds.LAGRANGE_MAX_M`` (where adversarial-subset
 conditioning exceeds what f32 planes carry and the complex128 host inverse
-is the right tool) and explicitly pinned ``device_decode=False`` configs.
+is the right tool).
 
 The batched service decodes every request in a bucket with ONE Pallas
 batched matmul: each request contributes its own ``(m, N)`` *scatter decode

@@ -4,8 +4,8 @@ Clients submit transform requests; the service executes them under a coded
 computation plan and answers as soon as the fastest ``m`` of ``N`` workers
 respond.  The straggler simulator assigns each worker a shifted-exponential
 latency per request; the service's reported latency is the m-th order
-statistic -- benchmarks compare it against waiting for all N (uncoded) and
-against the repetition/short-dot thresholds (paper Remark 4).
+statistic, and ``ServiceStats`` keeps beside it the latency of waiting for
+all N (uncoded).
 
 The scheduler is batched (DESIGN.md §5): submitted requests are bucketed by
 ``(s, m, kind)`` with ``kind in {c2c, r2c, c2r, rfftn, irfftn}`` (forward
@@ -23,11 +23,10 @@ bucket by the full time-domain shape tuple and run the generic jitted
 ``plan.run`` executor.
 
 The default bucket executor is the Pallas kernel pipeline (DESIGN.md §6):
-requests are split to f32 real/imag planes ONCE at ingress, interleaved on
-planes, pushed through the fused encode+worker kernel (coded shards never
-round-trip HBM between encode and the worker DFT), decoded by one batched
-MXU matmul against per-request decode matrices, recombined by the fused
-twiddle+DFT kernel, and recombined to complex ONCE at egress.
+requests are split to f32 real/imag planes ONCE at ingress, run through
+one body -- the one-launch masked bucket kernel on the TPU where the shape
+fits it, the stage kernels where it does not, the direct executor off the
+TPU -- and joined to complex ONCE at egress.
 ``use_reference=True`` is the escape hatch back to the jnp-oracle
 ``plan.run`` executor (as is any config the kernel path does not cover:
 a mesh, an explicit ``worker_fn`` plug-in, a pinned ``decode_method``, or
@@ -39,13 +38,12 @@ executor from each request's straggler mask via the closed-form Lagrange
 inversion (``mds.lagrange_inverse``) -- no host ``linalg.inv``, no LRU
 side channel, a novel mask costs exactly what a repeated one does.  The
 host-side :class:`~repro.serving.decode_cache.DecodeMatrixCache` remains
-only as the fallback for ``m > mds.LAGRANGE_MAX_M`` (or
-``device_decode=False``).  ``submit_batch`` DISPATCHES every (s, m, kind)
-bucket before any host sync -- ingress buffers are donated to XLA
-(``donate_argnums``), legal precisely because decode became jittable and
-nothing host-side aliases the bucket I/O -- then performs ONE device->host
-transfer for the whole call.  ``ServiceStats`` splits dispatch vs sync
-wall time and counts host transfers so the async win is observable.
+only as the fallback for ``m > mds.LAGRANGE_MAX_M``.  ``submit_batch``
+DISPATCHES every (s, m, kind) bucket before any host sync -- ingress
+buffers are donated to XLA (``donate_argnums``) -- then performs ONE
+device->host transfer for the whole call.  ``ServiceStats`` splits
+dispatch vs sync wall time and counts host transfers so the async win is
+observable.
 
 With a mesh, worker compute runs under ``DistributedCodedPlan`` (shard_map,
 batch axis threaded through the collectives); without one, it runs on the
@@ -171,6 +169,19 @@ class _StagedArgs(tuple):
         return self
 
 
+# the three bodies of a kernel-path bucket, by kind (FFTService._bucket_body)
+_BUCKET_BODIES = {
+    "c2c": {"direct": ops.coded_bucket_direct,
+            "whole": ops.coded_bucket_masked,
+            "staged": ops.coded_bucket_staged},
+    "r2c": {"direct": ops.coded_rbucket_direct,
+            "whole": ops.coded_rbucket_masked,
+            "staged": ops.coded_rbucket_staged},
+    "c2r": {"direct": ops.coded_irbucket_direct,
+            "whole": ops.coded_irbucket_masked,
+            "staged": ops.coded_irbucket_staged},
+}
+
 # staging buffers kept per (shape, dtype) for reuse: the streaming pipeline
 # holds at most three buckets between pack and fetch (DESIGN.md §11)
 STAGING_KEEP = 3
@@ -213,24 +224,6 @@ def _host_complex(w: np.ndarray) -> np.ndarray:
     return w.view(np.result_type(w.dtype, np.complex64))
 
 
-def _donate_ingress(fn):
-    """Jit ``fn`` with its ingress buffer donated.
-
-    The real-kind bucket I/O changes shape across the call (``f32[b, s]``
-    -> ``c64[b, s//2+1]`` and its adjoint), so XLA can never ALIAS the
-    donated ingress to the output the way the same-shape c2c path does --
-    but donation still releases the buffer after its last use, so the
-    encode/worker temporaries reuse its memory instead of growing the
-    peak bucket footprint (ROADMAP item 5).  jax warns per-executable
-    that no aliasing happened; that is the expected outcome here, not a
-    bug signal, so the message is filtered (idempotently, message-scoped)
-    when such a runner is built.
-    """
-    warnings.filterwarnings(
-        "ignore", message="Some donated buffers were not usable")
-    return jax.jit(fn, donate_argnums=0)
-
-
 @dataclasses.dataclass(frozen=True)
 class FFTServiceConfig:
     s: int = 4096                 # default transform length
@@ -245,13 +238,9 @@ class FFTServiceConfig:
     max_batch: int = 64           # scheduler bucket cap per (s, m)
     decode_method: str = "auto"   # MDS decode dispatch (DESIGN.md §4);
     #                               non-"auto" pins the reference executor
-    device_decode: bool = True    # build decode matrices IN the jitted
-    #                               bucket executor (Lagrange closed form,
-    #                               DESIGN.md §8); automatic fallback to the
-    #                               host LRU for m > mds.LAGRANGE_MAX_M
     decode_cache_size: int = 512  # LRU size of per-mask decode matrices
-    #                               (the m > LAGRANGE_MAX_M / pinned-config
-    #                               fallback; past the C(N, k) mask-pattern
+    #                               (the m > mds.LAGRANGE_MAX_M fallback;
+    #                               past the C(N, k) mask-pattern
     #                               count for small fleets, so steady state
     #                               is all-hit)
     precision: str = "f32"        # kernel plane precision: "bf16" casts the
@@ -447,10 +436,10 @@ class FFTService:
             if cfg.strategy == "repetition":
                 # its replication decode is host-side block assembly, not
                 # the jittable masked-subset protocol the bucket executors
-                # speak; it stays a Remark-4 benchmark baseline
+                # speak
                 raise ValueError(
-                    "the repetition baseline is bench-only; the service "
-                    "serves subset-decodable strategies")
+                    "the repetition baseline is not subset-decodable; the "
+                    "service serves subset-decodable strategies")
         if mesh is not None and not REGISTRY[cfg.strategy].mesh_ok:
             raise ValueError(
                 f"strategy {cfg.strategy!r} does not compose with a mesh")
@@ -610,15 +599,30 @@ class FFTService:
                 and cfg.decode_method == "auto"
                 and self._plan_for(s, kind).resolved_backend == "kernel")
 
-    def _device_decode(self) -> bool:
-        """Are decode matrices built inside the jitted executor?
+    def _decode_in_jit(self) -> bool:
+        """The kernel path's decode source: are the decode matrices built
+        inside the jitted executor from the raw masks?
 
-        True on the default kernel path for ``m <= mds.LAGRANGE_MAX_M``
-        (the closed-form Lagrange inversion, DESIGN.md §8); past that the
-        f32 planes cannot carry adversarial-subset conditioning and the
-        host complex128 LRU takes over.
+        Yes for ``m <= mds.LAGRANGE_MAX_M`` (the closed-form Lagrange
+        inversion, DESIGN.md §8); past that the f32 planes cannot carry
+        adversarial-subset conditioning and the host complex128 LRU
+        supplies them (:meth:`_bucket_args`).
         """
-        return self.cfg.device_decode and self.cfg.m <= mds.LAGRANGE_MAX_M
+        return self.cfg.m <= mds.LAGRANGE_MAX_M
+
+    def _bucket_body(self, s: int, kind: str) -> str:
+        """The kernel path's body for one bucket (DESIGN.md §6): ``direct``
+        off the TPU; ``whole``, the one-launch masked kernel, where the
+        shape passes its gate and the decode is done in the jit;
+        ``staged``, the stage kernels, otherwise."""
+        if ops.default_interpret():
+            return "direct"
+        m, n = self.cfg.m, self._n_workers()
+        fits = {"c2c": (ops.coded_bucket_fusable(s, m, n)
+                        or ops.coded_bucket_streamable(s, m, n)),
+                "r2c": ops.coded_rbucket_fusable(s, m, n),
+                "c2r": ops.coded_irbucket_fusable(s, m, n)}[kind]
+        return "whole" if fits and self._decode_in_jit() else "staged"
 
     def _precision_for(self, s, kind: str) -> str:
         """Resolved kernel plane precision for one bucket family.
@@ -687,253 +691,62 @@ class FFTService:
         :meth:`warmup` keys them once so steady state never compiles.
         n-D kinds always take the generic ``plan.run`` branch."""
         kernel = self._kernel_path(s, kind)
-        dev = kernel and self._device_decode()
         prec = self._precision_for(s, kind) if kernel else "f32"
-        key = (s, self.cfg.m, kind, bucket, kernel, dev, prec,
-               self._n_workers())
+        key = (s, self.cfg.m, kind, bucket, kernel, prec, self._n_workers())
         if key not in self._runners:
-            if dev:
-                self._runners[key] = self._make_masked_runner(s, bucket, kind)
-            elif kernel:
-                self._runners[key] = self._make_kernel_runner(s, bucket, kind)
+            if kernel:
+                self._runners[key] = self._make_kernel_runner(s, kind)
             else:
+                plan = self._plan_for(s, kind)
+                run = (self._runtime_for(s, kind).run if self.mesh is not None
+                       else plan.run)
+                # a partial-work strategy stages per-fragment (bucket, N, r)
+                # masks
+                arg = ("fragment_mask" if getattr(plan, "fragments", 1) > 1
+                       else "mask")
                 method = self.cfg.decode_method
-                nf = int(getattr(self._plan_for(s, kind), "fragments", 1))
-                if self.mesh is not None:
-                    runtime = self._runtime_for(s, kind)
-                    if nf > 1:
-                        fn = lambda xb, masks: runtime.run(
-                            xb, fragment_mask=masks, method=method)
-                    else:
-                        fn = lambda xb, masks: runtime.run(
-                            xb, masks, method=method)
-                else:
-                    plan = self._plan_for(s, kind)
-                    if nf > 1:
-                        # partial-work strategy: the staged masks are
-                        # per-fragment (bucket, N, r)
-                        fn = lambda xb, masks: plan.run(
-                            xb, fragment_mask=masks, method=method)
-                    else:
-                        fn = lambda xb, masks: plan.run(
-                            xb, mask=masks, method=method)
-                self._runners[key] = jax.jit(fn)
+                self._runners[key] = jax.jit(lambda xb, masks: run(
+                    xb, method=method, **{arg: masks}))
         return self._runners[key]
 
-    def _make_masked_runner(self, s: int, bucket: int, kind: str = "c2c"):
-        """The device-decode bucket executor (DESIGN.md §8).
+    def _make_kernel_runner(self, s: int, kind: str):
+        """The kernel path's bucket executor: ``(requests, *decode) ->
+        result``, split into f32 planes once at ingress and joined once at
+        egress.
 
-        Takes ``(requests, masks)`` and nothing else: the whole-bucket
-        kernels consume the RAW masks -- subset selection, Lagrange decode
-        matrices, worker transform and recombine all happen inside ONE
-        jitted call, and on TPU inside one Pallas launch with the decode
-        matrices built in VMEM (``ops.coded_bucket_masked``; shapes past
-        the VMEM budget stream through the double-buffered grid, §10).
-        The c2c ingress buffer is donated: with no host-side decode cache
-        aliasing bucket I/O, XLA may reuse the request buffer for the
-        same-shape spectrum output.
+        ``decode`` is what the body (:meth:`_bucket_body`) takes: the raw
+        ``(bucket, N)`` masks for the one-launch masked kernel, else decode
+        planes -- shipped from the host LRU (:meth:`_bucket_args`), or,
+        when the decode is in the jit (:meth:`_decode_in_jit`), built here
+        from the masks the runner is given instead.  The ingress buffer is
+        donated: c2c output aliases it, and for the real kinds it frees
+        early for the encode/worker temporaries.
         """
         plan = self._plan_for(s, kind)
         m, n = plan.m, plan.n_workers
         gr, gi = ref.planar(plan.generator)
-        n2 = s // m // 2  # packed shard length of the real kinds
-        direct = ops.default_interpret()
-        prec = self._precision_for(s, kind)
+        body = self._bucket_body(s, kind)
+        run = _BUCKET_BODIES[kind][body]
+        build = self._decode_in_jit() and body != "whole"
+        kw = {"precision": self._precision_for(s, kind)} \
+            if body == "whole" else {}
 
-        if kind == "r2c":
-            whole = not direct and ops.coded_rbucket_fusable(s, m, n)
+        def fn(x, *decode):
+            # an r2c request is its own real plane
+            planes = (x,) if kind == "r2c" else ref.planar(x)
+            if build:       # the body's decode operands, from the masks
+                subsets = ops.mask_subsets(decode[0], m)
+                decode = ((*ops.lagrange_compact_planes(subsets, n), subsets)
+                          if body == "direct"
+                          else ops.lagrange_scatter_planes(subsets, n))
+            y = run(*planes, *decode, gr, gi, s, **kw)
+            return y if kind == "c2r" else ref.unplanar(*y)
 
-            def fn(xb, masks):
-                if direct:
-                    subsets = ops.mask_subsets(masks, m)
-                    ivr, ivi = ops.lagrange_compact_planes(subsets, n)
-                    yr, yi = ops.coded_rbucket_direct(
-                        xb, ivr, ivi, subsets, gr, gi, s)
-                elif whole:
-                    yr, yi = ops.coded_rbucket_masked(xb, masks, gr, gi, s,
-                                                      precision=prec)
-                else:
-                    subsets = ops.mask_subsets(masks, m)
-                    dr, di = ops.lagrange_scatter_planes(subsets, n)
-                    zr, zi = ops.pack_real_planes(xb, m)
-                    br, bi = ops.encode_worker(zr, zi, gr, gi)
-                    hr, hi = ops.decode_apply(dr, di, br, bi)
-                    yr, yi = ops.rfft_postdecode_planar(hr, hi, s)
-                return ref.unplanar(yr, yi)
-
-            # real ingress donated too (ROADMAP item 5): no aliasing (the
-            # shape changes), but the f32 request buffer frees early for
-            # the encode/worker temporaries
-            return _donate_ingress(fn)
-
-        if kind == "c2r":
-            whole = not direct and ops.coded_irbucket_fusable(s, m, n)
-
-            def fn(yb, masks):
-                yr, yi = ref.planar(yb)
-                if direct:
-                    subsets = ops.mask_subsets(masks, m)
-                    ivr, ivi = ops.lagrange_compact_planes(subsets, n)
-                    return ops.coded_irbucket_direct(
-                        yr, yi, ivr, ivi, subsets, gr, gi, s)
-                if whole:
-                    # ONE Pallas launch with in-VMEM decode matrices --
-                    # the last kind to get a whole-bucket kernel
-                    # (DESIGN.md §9)
-                    return ops.coded_irbucket_masked(yr, yi, masks,
-                                                     gr, gi, s,
-                                                     precision=prec)
-                subsets = ops.mask_subsets(masks, m)
-                dr, di = ops.lagrange_scatter_planes(subsets, n)
-                zr, zi = ops.irfft_message_planar(yr, yi, s, m)
-                br, bi = ops.encode_worker(zr, -zi, gr, -gi)
-                br, bi = br / n2, -bi / n2
-                hr, hi = ops.decode_apply(dr, di, br, bi)
-                return ops.irfft_unpack_planar(hr, hi)
-
-            # half-spectrum ingress donated (same early-free rationale)
-            return _donate_ingress(fn)
-
-        whole = not direct and (ops.coded_bucket_fusable(s, m, n)
-                                or ops.coded_bucket_streamable(s, m, n))
-        ell = plan.shard_len
-
-        def fn(xb, masks):
-            xr, xi = ref.planar(xb)
-            if direct:
-                subsets = ops.mask_subsets(masks, m)
-                ivr, ivi = ops.lagrange_compact_planes(subsets, n)
-                yr, yi = ops.coded_bucket_direct(
-                    xr, xi, ivr, ivi, subsets, gr, gi, s)
-            elif whole:
-                yr, yi = ops.coded_bucket_masked(xr, xi, masks, gr, gi, s,
-                                                 precision=prec)
-            else:
-                subsets = ops.mask_subsets(masks, m)
-                dr, di = ops.lagrange_scatter_planes(subsets, n)
-                cr = jnp.swapaxes(xr.reshape(bucket, ell, m), -1, -2)
-                ci = jnp.swapaxes(xi.reshape(bucket, ell, m), -1, -2)
-                br, bi = ops.encode_worker(cr, ci, gr, gi)
-                hr, hi = ops.decode_apply(dr, di, br, bi)
-                yr, yi = ops.recombine_planar(hr, hi, s)
-            return ref.unplanar(yr, yi)
-
-        # c2c donation is a true in-place ALIAS: the (bucket, s) c64
-        # output matches the ingress buffer exactly (the real kinds above
-        # donate for the early-free only)
+        # the real kinds change shape, so their donated buffer cannot be
+        # aliased and jax says so per executable: expected, filtered
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
         return jax.jit(fn, donate_argnums=0)
-
-    def _make_kernel_runner(self, s: int, bucket: int, kind: str = "c2c"):
-        """The fused planar bucket executor (DESIGN.md §6/§7).
-
-        One planar split at ingress, planes threaded end-to-end, one
-        complex recombine at egress.  Straggler handling lives entirely in
-        the per-request decode matrices (zero columns for non-responders),
-        so the jitted function takes no mask.  Bucket shapes that fit the
-        VMEM working set run the whole pipeline as ONE Pallas launch
-        (``ops.coded_bucket`` / ``ops.coded_rbucket``); larger shapes fall
-        back to the stage kernels (fused encode+worker -> decode matmul ->
-        recombine).
-
-        ``r2c`` buckets never split at ingress at all -- the real request
-        IS its plane -- and ship half-length packed shards; ``c2r`` buckets
-        run the adjoint message stage and return a single real plane.
-        """
-        plan = self._plan_for(s, kind)
-        m = plan.m
-        gr, gi = ref.planar(plan.generator)
-        n2 = s // m // 2  # packed shard length of the real kinds
-        prec = self._precision_for(s, kind)
-
-        if kind == "r2c":
-            if ops.default_interpret():
-                def fn(xb, dplanes, subsets):
-                    yr, yi = ops.coded_rbucket_direct(
-                        xb, dplanes[0], dplanes[1], subsets, gr, gi, s)
-                    return ref.unplanar(yr, yi)
-
-                return jax.jit(fn)
-
-            whole = ops.coded_rbucket_fusable(s, m, plan.n_workers)
-
-            def fn(xb, dplanes):
-                dr, di = dplanes[0], dplanes[1]
-                if whole:
-                    yr, yi = ops.coded_rbucket(xb, dr, di, gr, gi, s,
-                                               precision=prec)
-                    return ref.unplanar(yr, yi)
-                zr, zi = ops.pack_real_planes(xb, m)     # relabel ingress
-                br, bi = ops.encode_worker(zr, zi, gr, gi)
-                hr, hi = ops.decode_apply(dr, di, br, bi)
-                yr, yi = ops.rfft_postdecode_planar(hr, hi, s)
-                return ref.unplanar(yr, yi)
-
-            return jax.jit(fn)
-
-        if kind == "c2r":
-            if ops.default_interpret():
-                def fn(yb, dplanes, subsets):
-                    yr, yi = ref.planar(yb)              # ingress split
-                    return ops.coded_irbucket_direct(
-                        yr, yi, dplanes[0], dplanes[1], subsets, gr, gi, s)
-
-                return jax.jit(fn)
-
-            whole = ops.coded_irbucket_fusable(s, m, plan.n_workers)
-
-            def fn(yb, dplanes):
-                dr, di = dplanes[0], dplanes[1]
-                yr, yi = ref.planar(yb)
-                if whole:
-                    return ops.coded_irbucket(yr, yi, dr, di, gr, gi, s,
-                                              precision=prec)
-                zr, zi = ops.irfft_message_planar(yr, yi, s, m)
-                # ifft(G @ z) via the conj trick on planes:
-                # conj(fft(conj(G) @ conj(z))) / n2 through the same fused
-                # encode+worker kernel
-                br, bi = ops.encode_worker(zr, -zi, gr, -gi)
-                br, bi = br / n2, -bi / n2
-                hr, hi = ops.decode_apply(dr, di, br, bi)
-                return ops.irfft_unpack_planar(hr, hi)   # real egress
-
-            return jax.jit(fn)
-
-        ell = plan.shard_len
-        if ops.default_interpret():
-            # off-TPU: the direct executor (platform-FFT worker stage,
-            # gathered compact decode -- DESIGN.md §6)
-            def fn(xb: jax.Array, dplanes: jax.Array,
-                   subsets: jax.Array) -> jax.Array:
-                # dplanes: (2, bucket, m, m) stacked real/imag inverse
-                # planes -- ONE transfer per bucket, split for free in-jit
-                xr, xi = ref.planar(xb)                  # ingress split
-                yr, yi = ops.coded_bucket_direct(
-                    xr, xi, dplanes[0], dplanes[1], subsets, gr, gi, s)
-                return ref.unplanar(yr, yi)              # egress recombine
-
-            return jax.jit(fn)
-
-        whole = (ops.coded_bucket_fusable(s, m, plan.n_workers)
-                 or ops.coded_bucket_streamable(s, m, plan.n_workers))
-
-        def fn(xb: jax.Array, dplanes: jax.Array) -> jax.Array:
-            # dplanes: (2, bucket, m, N) stacked real/imag scatter decode
-            # planes -- ONE host->device transfer, split for free in-jit
-            dr, di = dplanes[0], dplanes[1]
-            xr, xi = ref.planar(xb)                      # ingress split
-            if whole:
-                yr, yi = ops.coded_bucket(xr, xi, dr, di, gr, gi, s,
-                                          precision=prec)
-                return ref.unplanar(yr, yi)              # egress recombine
-            # interleave on planes: c_i[j] = x[i + j*m]
-            cr = jnp.swapaxes(xr.reshape(bucket, ell, m), -1, -2)
-            ci = jnp.swapaxes(xi.reshape(bucket, ell, m), -1, -2)
-            br, bi = ops.encode_worker(cr, ci, gr, gi)   # fused stage 1+2+3
-            hr, hi = ops.decode_apply(dr, di, br, bi)    # batched MXU decode
-            yr, yi = ops.recombine_planar(hr, hi, s)     # fused twiddle+DFT
-            return ref.unplanar(yr, yi)                  # egress recombine
-
-        return jax.jit(fn)
 
     # ------------------------------------------------------------------
     def _wire_scale(self, kind: str) -> float:
@@ -1580,22 +1393,24 @@ class FFTService:
         Device-decode path: the requests and the raw boolean masks -- two
         int words of decode metadata per request cross the host boundary,
         everything else happens in-jit (DESIGN.md §8).  Fallback kernel
-        path (``m > LAGRANGE_MAX_M`` or ``device_decode=False``): per-mask
-        matrices from the host LRU, shared across every (s, kind) bucket.
+        path (``m > mds.LAGRANGE_MAX_M``): per-mask matrices from the
+        host LRU, shared across every (s, kind) bucket -- compact
+        inverses and subsets for the direct body, scatter planes for the
+        stage kernels.
         A complex request buffer crosses as real words; the executor gets
         it back as complex in :meth:`_execute`.
         """
         host: tuple = (masks,)
-        if self._kernel_path(s, kind) and not self._device_decode():
+        if self._kernel_path(s, kind) and not self._decode_in_jit():
             cache = self._decode_cache_for()
             h0, m0 = cache.hits, cache.misses
-            if ops.default_interpret():
+            f32 = np.float32
+            if self._bucket_body(s, kind) == "direct":
                 invs, subsets = cache.compact(masks)
-                host = (np.stack([invs.real, invs.imag]).astype(np.float32),
-                        subsets)
+                host = (invs.real.astype(f32), invs.imag.astype(f32), subsets)
             else:
                 dmats = cache.matrices(masks)
-                host = (np.stack([dmats.real, dmats.imag]).astype(np.float32),)
+                host = (dmats.real.astype(f32), dmats.imag.astype(f32))
             # deltas, not lifetime cache totals: every other ServiceStats
             # field accumulates, so a stats reset must window these too
             self.stats.decode_cache_hits += cache.hits - h0
@@ -1731,7 +1546,7 @@ class FFTService:
             return "robust"
         if not self._kernel_path(s, kind):
             return "plan"
-        return "kernel_masked" if self._device_decode() else "kernel"
+        return "kernel_masked" if self._decode_in_jit() else "kernel"
 
     def _execute(self, s, bucket: int, kind: str, args: tuple) -> _Launched:
         """The bucket executor between the host link's two conversions

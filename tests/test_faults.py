@@ -17,6 +17,7 @@ from repro.distributed import (
     StragglerModel,
     WorkerHealthTracker,
 )
+from repro.distributed.worker_runtime import Clock
 from repro.serving import (
     FAILURE_REASONS,
     DegradedResult,
@@ -348,12 +349,24 @@ def test_service_elastic_membership_live_changes():
 
 
 # ------------------------------------------------------- measured runtime
+# The measured tests keep the runtime's own seconds on a clock slowed
+# 1000x: its 2 ms window floor lasts 2 s on the host, so which rows arrive
+# inside a window does not hang on how busy the test host is.
+_SLOW = Clock(slowdown=1000.0)
+
+
+def _slow_measured(svc, s=64):
+    """The service's measured runtime for length ``s``, on the slow clock."""
+    svc._measured_for(s).clock = _SLOW
+    return svc
+
+
 def test_measured_runtime_round_completes_and_decodes():
     plan = CodedFFT(s=64, m=4, n_workers=8, dtype=np.complex128,
                     backend="reference")
     h = WorkerHealthTracker(8)
     x = np.stack([_x(64, s, np.complex128) for s in range(3)])
-    with MeasuredWorkerRuntime(plan, h) as rt:
+    with MeasuredWorkerRuntime(plan, h, clock=_SLOW) as rt:
         res = rt.round(x, 0)
     assert res.ok and res.mask.sum() >= 4
     assert np.isfinite(res.t_met) and res.t_met <= res.t_last
@@ -370,7 +383,7 @@ def test_measured_runtime_kill_faults_and_insufficient():
     h = WorkerHealthTracker(8)
     inj = FaultInjector(FaultPlan().kill(0, rounds=999).kill(7, rounds=999))
     x = _x(64, 1, np.complex128)[None]
-    with MeasuredWorkerRuntime(plan, h, injector=inj) as rt:
+    with MeasuredWorkerRuntime(plan, h, injector=inj, clock=_SLOW) as rt:
         warm = rt.round(x, 0)                    # learn live-worker times
         assert warm.ok and not warm.mask[0]
         res = rt.round(x, 1)
@@ -390,9 +403,9 @@ def test_measured_service_corrects_byzantine_workers():
     verify="correct" still recovers the exact transform (quorum k = m + 4
     corrects 2 liars)."""
     plan = FaultPlan(seed=8).corrupt(2, rounds=999).corrupt(5, rounds=999)
-    svc = FFTService(_cfg(s=64, measured=True, faults=plan,
-                          verify="correct", verify_quorum=4,
-                          dtype=np.complex128, use_reference=True))
+    svc = _slow_measured(FFTService(_cfg(
+        s=64, measured=True, faults=plan, verify="correct", verify_quorum=4,
+        dtype=np.complex128, use_reference=True)))
     x = _x(64, 2, np.complex128)
     y = svc.submit(x)
     np.testing.assert_allclose(y, np.fft.fft(x), atol=1e-8)
@@ -404,14 +417,15 @@ def test_measured_uncoded_baseline_requires_every_worker():
     """require_all=True is the uncoded baseline: one killed worker forces
     the full retry ladder (an uncoded partition has no slack)."""
     plan = FaultPlan().kill(3, rounds=999)
-    svc = FFTService(_cfg(s=64, measured=True, require_all=True,
-                          faults=plan, max_retries=0, on_failure="degrade",
-                          dtype=np.complex128, use_reference=True))
+    svc = _slow_measured(FFTService(_cfg(
+        s=64, measured=True, require_all=True, faults=plan, max_retries=0,
+        on_failure="degrade", dtype=np.complex128, use_reference=True)))
     r = svc.submit(_x(64, 0, np.complex128))
     assert isinstance(r, DegradedResult) and r.reason == "retries_exhausted"
     # the coded service under the SAME fault plan just ... works
-    svc2 = FFTService(_cfg(s=64, measured=True, faults=plan,
-                           dtype=np.complex128, use_reference=True))
+    svc2 = _slow_measured(FFTService(_cfg(
+        s=64, measured=True, faults=plan, dtype=np.complex128,
+        use_reference=True)))
     x = _x(64, 0, np.complex128)
     np.testing.assert_allclose(svc2.submit(x), np.fft.fft(x), atol=1e-8)
     assert svc2.stats.degraded == 0

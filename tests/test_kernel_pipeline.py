@@ -8,6 +8,8 @@ kernels-interpret job runs this module); dispatch tests cover the
 the plan/service backend-selection rules.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,7 @@ from repro.core.coded_fft import _default_fft
 from repro.kernels import ops, ref
 from repro.serving import FFTService, FFTServiceConfig
 from repro.serving.decode_cache import DecodeMatrixCache
+from repro.serving.fft_service import from_words
 
 pytestmark = pytest.mark.kernels
 
@@ -34,6 +37,28 @@ def _randc(shape, seed=0):
 def _relerr(got, want):
     got, want = np.asarray(got), np.asarray(want)
     return np.abs(got - want).max() / max(np.abs(want).max(), 1e-12)
+
+
+def _random_masks(rng, q, n, m):
+    """(q, n) responder masks, each with exactly m responders."""
+    masks = np.zeros((q, n), bool)
+    for row in masks:
+        row[rng.choice(n, size=m, replace=False)] = True
+    return masks
+
+
+def _compact(cache, masks):
+    """Host-LRU compact inverse planes and subsets, as device arrays."""
+    invs, subsets = cache.compact(masks)
+    return (jnp.asarray(invs.real.astype(np.float32)),
+            jnp.asarray(invs.imag.astype(np.float32)), jnp.asarray(subsets))
+
+
+def _scatter(cache, masks):
+    """Host-LRU (q, m, N) scatter decode planes, as device arrays."""
+    dmats = cache.matrices(masks)
+    return (jnp.asarray(dmats.real.astype(np.float32)),
+            jnp.asarray(dmats.imag.astype(np.float32)))
 
 
 # --------------------------------------------- fused encode+worker parity
@@ -92,57 +117,43 @@ def test_degenerate_factorization_falls_back_to_platform_fft():
 @pytest.mark.parametrize("s,m,n", [(2048, 4, 8), (756, 4, 6), (254, 2, 5)])
 def test_coded_bucket_kernel_parity(s, m, n):
     """One-launch bucket pipeline (interleave -> encode -> worker ->
-    decode -> recombine) == jnp.fft, via Pallas interpret, including odd
-    and prime shard lengths."""
+    in-kernel decode -> recombine) == jnp.fft, via Pallas interpret,
+    including odd and prime shard lengths."""
     assert ops.coded_bucket_fusable(s, m, n)
     q = 3
     xb = _randc((q, s), seed=s)
     g = mds.rs_generator(n, m, jnp.complex64)
-    rng = np.random.default_rng(s)
-    masks = np.zeros((q, n), bool)
-    for row in masks:
-        row[rng.choice(n, size=m, replace=False)] = True
-    cache = DecodeMatrixCache(np.asarray(g))
-    dmats = cache.matrices(masks)
+    masks = jnp.asarray(_random_masks(np.random.default_rng(s), q, n, m))
     xr, xi = ref.planar(xb)
     gr, gi = ref.planar(g)
-    dr = jnp.asarray(dmats.real.astype(np.float32))
-    di = jnp.asarray(dmats.imag.astype(np.float32))
-    yr, yi = ops.coded_bucket(xr, xi, dr, di, gr, gi, s, interpret=True)
+    yr, yi = ops.coded_bucket_masked(xr, xi, masks, gr, gi, s,
+                                     interpret=True)
     want = np.fft.fft(np.asarray(xb, np.complex128), axis=-1)
     assert _relerr(ref.unplanar(yr, yi), want) < 1e-3
     # direct path (off-TPU default) computes the identical body
     # (not bit-identical: XLA may reassociate the f32 accumulations)
-    yr2, yi2 = ops.coded_bucket(xr, xi, dr, di, gr, gi, s)
+    yr2, yi2 = ops.coded_bucket_masked(xr, xi, masks, gr, gi, s)
     assert _relerr(ref.unplanar(yr2, yi2), ref.unplanar(yr, yi)) < 1e-5
 
 
 @pytest.mark.parametrize("s,m,n", [(2048, 4, 8), (756, 4, 6)])
 def test_coded_bucket_direct_matches_pallas_bucket(s, m, n):
     """The off-TPU direct executor (platform-FFT worker stage, gathered
-    compact decode) == the Pallas bucket kernel == jnp.fft."""
+    compact decode from the host LRU) == the Pallas bucket kernel (decode
+    in the kernel from the same masks) == jnp.fft."""
     q = 3
     xb = _randc((q, s), seed=s + 1)
     g = mds.rs_generator(n, m, jnp.complex64)
-    rng = np.random.default_rng(s)
-    masks = np.zeros((q, n), bool)
-    for row in masks:
-        row[rng.choice(n, size=m, replace=False)] = True
+    masks = _random_masks(np.random.default_rng(s), q, n, m)
     cache = DecodeMatrixCache(np.asarray(g))
-    invs, subsets = cache.compact(masks)
-    dmats = cache.matrices(masks)
     xr, xi = ref.planar(xb)
     gr, gi = ref.planar(g)
-    yr, yi = ops.coded_bucket_direct(
-        xr, xi, jnp.asarray(invs.real.astype(np.float32)),
-        jnp.asarray(invs.imag.astype(np.float32)),
-        jnp.asarray(subsets), gr, gi, s)
+    yr, yi = ops.coded_bucket_direct(xr, xi, *_compact(cache, masks),
+                                     gr, gi, s)
     want = np.fft.fft(np.asarray(xb, np.complex128), axis=-1)
     assert _relerr(ref.unplanar(yr, yi), want) < 1e-3
-    kr, ki = ops.coded_bucket(
-        xr, xi, jnp.asarray(dmats.real.astype(np.float32)),
-        jnp.asarray(dmats.imag.astype(np.float32)), gr, gi, s,
-        interpret=True)
+    kr, ki = ops.coded_bucket_masked(xr, xi, jnp.asarray(masks), gr, gi, s,
+                                     interpret=True)
     assert _relerr(ref.unplanar(yr, yi), ref.unplanar(kr, ki)) < 1e-4
 
 
@@ -251,14 +262,15 @@ def test_decode_cache_rejects_undecodable_mask():
         cache.matrix(np.array([1, 1, 1, 0, 0, 0, 0, 0], bool))
 
 
-def test_service_lru_churn_stays_correct():
+def test_service_lru_churn_stays_correct(monkeypatch):
     """With a tiny decode cache, straggler-mask churn forces constant
     evictions; every request must still decode exactly.  Pins the host-LRU
-    FALLBACK path (``device_decode=False``) -- the default path builds
-    decode matrices in-jit and is covered by test_lagrange_decode.py."""
+    FALLBACK path (m above ``mds.LAGRANGE_MAX_M``, lowered here) -- the
+    default path builds decode matrices in-jit and is covered by
+    test_lagrange_decode.py."""
+    monkeypatch.setattr(mds, "LAGRANGE_MAX_M", 2)
     svc = FFTService(FFTServiceConfig(
-        s=256, m=4, n_workers=8, seed=11, decode_cache_size=2,
-        device_decode=False))
+        s=256, m=4, n_workers=8, seed=11, decode_cache_size=2))
     rng = np.random.default_rng(0)
     worst = 0.0
     for _ in range(6):
@@ -278,32 +290,26 @@ def test_service_lru_churn_stays_correct():
                                    (96, 3, 7)])
 def test_coded_rfft_bucket_kernel_parity(s, m, n):
     """One-launch r2c bucket (pack -> encode -> half-length worker ->
-    decode -> symmetry butterfly) == numpy.rfft via Pallas interpret,
-    including odd shard lengths and odd m; direct path same math."""
+    in-kernel decode -> symmetry butterfly) == numpy.rfft via Pallas
+    interpret, including odd shard lengths and odd m; direct path same
+    math."""
     assert ops.coded_rbucket_fusable(s, m, n)
     q = 3
     rng = np.random.default_rng(s + m)
     xb = jnp.asarray(rng.normal(size=(q, s)).astype(np.float32))
     g = mds.rs_generator(n, m, jnp.complex64)
-    masks = np.zeros((q, n), bool)
-    for row in masks:
-        row[rng.choice(n, size=m, replace=False)] = True
-    cache = DecodeMatrixCache(np.asarray(g))
-    dmats = cache.matrices(masks)
+    masks = _random_masks(rng, q, n, m)
     gr, gi = ref.planar(g)
-    dr = jnp.asarray(dmats.real.astype(np.float32))
-    di = jnp.asarray(dmats.imag.astype(np.float32))
     want = np.fft.rfft(np.asarray(xb, np.float64), axis=-1)
-    yr, yi = ops.coded_rbucket(xb, dr, di, gr, gi, s, interpret=True)
+    yr, yi = ops.coded_rbucket_masked(xb, jnp.asarray(masks), gr, gi, s,
+                                      interpret=True)
     assert _relerr(ref.unplanar(yr, yi), want) < 1e-3
-    yr2, yi2 = ops.coded_rbucket(xb, dr, di, gr, gi, s)
+    yr2, yi2 = ops.coded_rbucket_masked(xb, jnp.asarray(masks), gr, gi, s)
     assert _relerr(ref.unplanar(yr2, yi2), ref.unplanar(yr, yi)) < 1e-5
     # gathered-compact direct executor (the off-TPU service path)
-    invs, subsets = cache.compact(masks)
-    yr3, yi3 = ops.coded_rbucket_direct(
-        xb, jnp.asarray(invs.real.astype(np.float32)),
-        jnp.asarray(invs.imag.astype(np.float32)),
-        jnp.asarray(subsets), gr, gi, s)
+    cache = DecodeMatrixCache(np.asarray(g))
+    yr3, yi3 = ops.coded_rbucket_direct(xb, *_compact(cache, masks),
+                                        gr, gi, s)
     assert _relerr(ref.unplanar(yr3, yi3), want) < 1e-3
 
 
@@ -316,17 +322,12 @@ def test_coded_irbucket_direct_matches_numpy(s, m, n):
     xs = rng.normal(size=(q, s))
     yb = jnp.asarray(np.fft.rfft(xs, axis=-1).astype(np.complex64))
     g = mds.rs_generator(n, m, jnp.complex64)
-    masks = np.zeros((q, n), bool)
-    for row in masks:
-        row[rng.choice(n, size=m, replace=False)] = True
+    masks = _random_masks(rng, q, n, m)
     cache = DecodeMatrixCache(np.asarray(g))
-    invs, subsets = cache.compact(masks)
     gr, gi = ref.planar(g)
     yr, yi = ref.planar(yb)
-    out = ops.coded_irbucket_direct(
-        yr, yi, jnp.asarray(invs.real.astype(np.float32)),
-        jnp.asarray(invs.imag.astype(np.float32)),
-        jnp.asarray(subsets), gr, gi, s)
+    out = ops.coded_irbucket_direct(yr, yi, *_compact(cache, masks),
+                                    gr, gi, s)
     assert _relerr(out, xs) < 1e-3
 
 
@@ -334,11 +335,11 @@ def test_coded_irbucket_direct_matches_numpy(s, m, n):
                                    (96, 3, 7)])
 def test_coded_irfft_bucket_kernel_parity(s, m, n):
     """One-launch fused c2r bucket (adjoint message butterfly -> fused
-    encode + half-length ifft worker -> decode -> pair unpack) ==
-    numpy.irfft via Pallas interpret, over ADVERSARIAL byte-pattern masks
-    -- pairs that select the same first-m responder subset but differ as
-    byte patterns, plus exact-threshold scatters -- including odd shard
-    lengths and odd m; direct path same math (DESIGN.md §9)."""
+    encode + half-length ifft worker -> in-kernel decode -> pair unpack)
+    == numpy.irfft via Pallas interpret, over ADVERSARIAL byte-pattern
+    masks -- pairs that select the same first-m responder subset but
+    differ as byte patterns, plus exact-threshold scatters -- including
+    odd shard lengths and odd m; direct path same math (DESIGN.md §9)."""
     assert ops.coded_irbucket_fusable(s, m, n)
     rng = np.random.default_rng(s + m)
     # adversarial mask family: same-subset-different-bytes pairs + the
@@ -357,24 +358,16 @@ def test_coded_irfft_bucket_kernel_parity(s, m, n):
     xs = rng.normal(size=(q, s))
     yb = jnp.asarray(np.fft.rfft(xs, axis=-1).astype(np.complex64))
     g = mds.rs_generator(n, m, jnp.complex64)
-    cache = DecodeMatrixCache(np.asarray(g))
-    dmats = cache.matrices(masks)
     gr, gi = ref.planar(g)
-    dr = jnp.asarray(dmats.real.astype(np.float32))
-    di = jnp.asarray(dmats.imag.astype(np.float32))
     yr, yi = ref.planar(yb)
-    out = ops.coded_irbucket(yr, yi, dr, di, gr, gi, s, interpret=True)
+    # raw masks in, subset selection + decode matrices built in-kernel
+    out = ops.coded_irbucket_masked(yr, yi, jnp.asarray(masks), gr, gi, s,
+                                    interpret=True)
     assert _relerr(out, xs) < 1e-3
     # direct path (off-TPU default) computes the identical body
-    out2 = ops.coded_irbucket(yr, yi, dr, di, gr, gi, s)
+    out2 = ops.coded_irbucket_masked(yr, yi, jnp.asarray(masks), gr, gi, s)
     assert _relerr(out2, np.asarray(out)) < 1e-5
-    # masked variant: raw masks in, subset selection + decode matrices
-    # built in-kernel
-    out3 = ops.coded_irbucket_masked(yr, yi, jnp.asarray(masks), gr, gi, s,
-                                     interpret=True)
-    assert _relerr(out3, xs) < 1e-3
-    out4 = ops.coded_irbucket_masked(yr, yi, jnp.asarray(masks), gr, gi, s)
-    assert _relerr(out4, xs) < 1e-3
+    assert _relerr(out2, xs) < 1e-3
     # and the reference plan agrees (the acceptance cross-check)
     from repro.core import CodedIRFFT
 
@@ -388,25 +381,27 @@ def test_submit_irfft_routes_through_fused_c2r_kernel(monkeypatch):
     """Dispatch pin (the acceptance criterion): on the kernel backend with
     a non-interpret (TPU-like) dispatch, the c2r bucket runner lowers to
     the ONE-LAUNCH fused kernel -- the jaxpr carries the
-    coded_irfft_bucket pallas_call, not the stage-path composition.  CI
-    runs on CPU, so the TPU dispatch is pinned by patching
+    coded_irfft_bucket_masked pallas_call, not the stage-path composition.
+    CI runs on CPU, so the TPU dispatch is pinned by patching
     ops.default_interpret; tracing never executes the kernel."""
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
     s, m, n = 256, 4, 8
     svc = FFTService(FFTServiceConfig(s=s, m=m, n_workers=n))
-    assert svc._kernel_path(s, "c2r") and svc._device_decode()
+    assert svc._kernel_path(s, "c2r") and svc._decode_in_jit()
     runner = svc._runner_for(s, 4, "c2r")
     yb = jax.ShapeDtypeStruct((4, s // 2 + 1), jnp.complex64)
     masks = jax.ShapeDtypeStruct((4, n), jnp.bool_)
     jaxpr = str(jax.make_jaxpr(runner)(yb, masks))
     assert "coded_irfft_bucket_masked" in jaxpr
-    # the host-LRU fallback runner pins the unmasked fused kernel too
-    svc2 = FFTService(FFTServiceConfig(s=s, m=m, n_workers=n,
-                                       device_decode=False))
+    # decode planes from the host LRU: the runner takes the stage
+    # composition (encode_worker -> decode_apply -> unpack) instead
+    monkeypatch.setattr(mds, "LAGRANGE_MAX_M", 2)
+    svc2 = FFTService(FFTServiceConfig(s=s, m=m, n_workers=n))
     runner2 = svc2._runner_for(s, 4, "c2r")
-    dplanes = jax.ShapeDtypeStruct((2, 4, m, n), jnp.float32)
-    jaxpr2 = str(jax.make_jaxpr(runner2)(yb, dplanes))
-    assert "coded_irfft_bucket" in jaxpr2
+    dplane = jax.ShapeDtypeStruct((4, m, n), jnp.float32)
+    jaxpr2 = str(jax.make_jaxpr(runner2)(yb, dplane, dplane))
+    assert "coded_irfft_bucket" not in jaxpr2
+    assert "encode_fourstep_fused" in jaxpr2 and "bcmatmul" in jaxpr2
 
 
 def test_pack_real_planes_odd_shard_raises_documented_error():
@@ -419,42 +414,34 @@ def test_pack_real_planes_odd_shard_raises_documented_error():
 @pytest.mark.parametrize("s,m,n", [(2048, 4, 8), (768, 4, 6), (240, 2, 5),
                                    (96, 3, 7)])
 def test_tpu_stage_path_compositions_match_numpy(s, m, n):
-    """Pin the TPU-only stage compositions of _make_kernel_runner, which
-    CI's interpret-mode default never executes: the r2c non-fusable
-    fallback (pack -> encode_worker -> decode_apply -> rfft_postdecode)
-    and the c2r executor's conj-trick ifft (encode_worker on negated
-    planes, /n2 rescale).  Run them through the Pallas kernels in
-    interpret mode against numpy."""
+    """Pin the TPU-only stage compositions the service's staged body
+    runs, which CI's direct default never executes: c2c (interleave ->
+    encode_worker -> decode_apply -> recombine), the r2c fallback (pack
+    -> encode_worker -> decode_apply -> rfft_postdecode) and the c2r
+    conj-trick ifft (encode_worker on negated planes, /n2 rescale).  Run
+    them through the Pallas kernels in interpret mode against numpy."""
     q = 2
-    n2 = s // m // 2
     rng = np.random.default_rng(s)
     xb = rng.normal(size=(q, s)).astype(np.float32)
     g = mds.rs_generator(n, m, jnp.complex64)
     gr, gi = ref.planar(g)
-    masks = np.zeros((q, n), bool)
-    for row in masks:
-        row[rng.choice(n, size=m, replace=False)] = True
-    cache = DecodeMatrixCache(np.asarray(g))
-    dmats = cache.matrices(masks)
-    dr = jnp.asarray(dmats.real.astype(np.float32))
-    di = jnp.asarray(dmats.imag.astype(np.float32))
+    masks = _random_masks(rng, q, n, m)
+    dr, di = _scatter(DecodeMatrixCache(np.asarray(g)), masks)
 
-    # r2c stage path (the whole=False branch)
-    zr, zi = ops.pack_real_planes(jnp.asarray(xb), m)
-    br, bi = ops.encode_worker(zr, zi, gr, gi, interpret=True)
-    hr, hi = ops.decode_apply(dr, di, br, bi, interpret=True)
-    yr, yi = ops.rfft_postdecode_planar(hr, hi, s)
+    xc = _randc((q, s), seed=s + 2)
+    yr, yi = ops.coded_bucket_staged(*ref.planar(xc), dr, di, gr, gi, s,
+                                     interpret=True)
+    want = np.fft.fft(np.asarray(xc, np.complex128), axis=-1)
+    assert _relerr(ref.unplanar(yr, yi), want) < 1e-3
+
+    yr, yi = ops.coded_rbucket_staged(jnp.asarray(xb), dr, di, gr, gi, s,
+                                      interpret=True)
     want = np.fft.rfft(xb.astype(np.float64), axis=-1)
     assert _relerr(ref.unplanar(yr, yi), want) < 1e-3
 
-    # c2r executor: ifft(G @ z) = conj(fft(conj(G) @ conj(z))) / n2
     yb = np.fft.rfft(xb, axis=-1).astype(np.complex64)
-    yr_, yi_ = ref.planar(jnp.asarray(yb))
-    zr2, zi2 = ops.irfft_message_planar(yr_, yi_, s, m)
-    br2, bi2 = ops.encode_worker(zr2, -zi2, gr, -gi, interpret=True)
-    br2, bi2 = br2 / n2, -bi2 / n2
-    hr2, hi2 = ops.decode_apply(dr, di, br2, bi2, interpret=True)
-    out = ops.irfft_unpack_planar(hr2, hi2)
+    out = ops.coded_irbucket_staged(*ref.planar(jnp.asarray(yb)), dr, di,
+                                    gr, gi, s, interpret=True)
     assert _relerr(out, xb) < 1e-3
 
 
@@ -471,12 +458,12 @@ def test_rfft_payload_is_half_of_c2c():
     assert a.shape == (n, s // m // 2)
 
 
-def test_service_rfft_and_irfft_kinds():
+def test_service_rfft_and_irfft_kinds(monkeypatch):
     """Service r2c/c2r buckets decode exactly under straggler churn and
     share ONE decode-matrix LRU across kinds (same (N, m) generator).
     Pinned to the host-LRU fallback path, which is what shares the LRU."""
-    svc = FFTService(FFTServiceConfig(s=256, m=4, n_workers=8, seed=3,
-                                      device_decode=False))
+    monkeypatch.setattr(mds, "LAGRANGE_MAX_M", 2)
+    svc = FFTService(FFTServiceConfig(s=256, m=4, n_workers=8, seed=3))
     rng = np.random.default_rng(1)
     xs = [jnp.asarray(rng.normal(size=256).astype(np.float32))
           for _ in range(6)]
@@ -516,13 +503,13 @@ def test_masks_equal_as_subsets_do_not_collide():
     assert cache.hits == 1
 
 
-def test_service_shares_decode_cache_across_buckets():
+def test_service_shares_decode_cache_across_buckets(monkeypatch):
     """Identical straggler masks arriving in different (s, kind) buckets
     must hit the one shared LRU, not rebuild per bucket (host-fallback
-    path; the default device-decode path has no cache to share)."""
+    path; the default in-jit decode has no cache to share)."""
+    monkeypatch.setattr(mds, "LAGRANGE_MAX_M", 2)
     svc = FFTService(FFTServiceConfig(s=256, m=4, n_workers=8, seed=9,
-                                      decode_cache_size=512,
-                                      device_decode=False))
+                                      decode_cache_size=512))
     rng = np.random.default_rng(2)
     xs256 = [jnp.asarray((rng.normal(size=256) + 1j * rng.normal(size=256))
                          .astype(np.complex64)) for _ in range(4)]
@@ -541,13 +528,13 @@ def test_service_shares_decode_cache_across_buckets():
     assert svc.stats.decode_cache_misses >= misses_after_first
 
 
-def test_service_lru_churn_with_real_kinds_stays_correct():
+def test_service_lru_churn_with_real_kinds_stays_correct(monkeypatch):
     """LRU eviction under churn across c2c + r2c + c2r buckets keeps
     parity: a tiny cache forces constant evictions; every request of every
     kind must still decode exactly (extends the c2c churn test above)."""
+    monkeypatch.setattr(mds, "LAGRANGE_MAX_M", 2)
     svc = FFTService(FFTServiceConfig(
-        s=128, m=4, n_workers=8, seed=13, decode_cache_size=2,
-        device_decode=False))
+        s=128, m=4, n_workers=8, seed=13, decode_cache_size=2))
     rng = np.random.default_rng(5)
     worst = 0.0
     for _ in range(4):
@@ -599,3 +586,44 @@ def test_service_kernel_vs_reference_same_results():
     outs = [FFTService(c).submit_batch(xs) for c in cfgs]
     for yk, yr in zip(*outs):
         assert float(np.max(np.abs(yk - yr))) < 1e-3
+
+
+# ------------------------------------------------- the kernel path's routes
+_ROUTE_KERNELS = {"c2c": "coded_fft_bucket_masked",
+                  "r2c": "coded_rfft_bucket_masked",
+                  "c2r": "coded_irfft_bucket_masked"}
+
+
+@pytest.mark.parametrize("dispatch", ["direct", "tpu"])
+@pytest.mark.parametrize("decode", ["in_jit", "host"])
+@pytest.mark.parametrize("kind", ["c2c", "r2c", "c2r"])
+def test_kernel_runner_route_table(monkeypatch, kind, decode, dispatch):
+    """The route table of the kernel path's one runner builder: the decode
+    source (in the jit from the raw masks, or planes from the host LRU)
+    and the body (direct off the TPU; on a TPU-like dispatch the
+    one-launch masked kernel where the decode is in the jit, the stage
+    kernels where it is not).  Traced over the arguments the stager ships;
+    tracing never executes a kernel."""
+    if dispatch == "tpu":
+        monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    if decode == "host":
+        monkeypatch.setattr(mds, "LAGRANGE_MAX_M", 2)
+    s, q = 256, 4
+    svc = FFTService(FFTServiceConfig(s=s, m=4, n_workers=8))
+    body = {"direct": "direct", "tpu": "whole" if decode == "in_jit"
+            else "staged"}[dispatch]
+    assert svc._bucket_body(s, kind) == body
+    assert svc._runner_name(s, kind) == ("kernel_masked" if decode == "in_jit"
+                                         else "kernel")
+    xb = svc._bucket_buffer(s, q, kind)
+    x, *dec = svc._bucket_args(s, kind, xb, svc._full_masks(s, kind, q))
+    if kind != "r2c":
+        x = from_words(x)
+    jaxpr = str(jax.make_jaxpr(svc._runner_for(s, q, kind))(x, *dec))
+    # the decode matrices: built in the trace from the masks, or inputs
+    assert bool(re.search(r"\bsort\[", jaxpr)) == (
+        decode == "in_jit" and body != "whole")
+    assert (_ROUTE_KERNELS[kind] in jaxpr) == (body == "whole")
+    assert ("bcmatmul" in jaxpr) == (body == "staged")
+    assert ("pallas_call" in jaxpr) == (body != "direct")
+    assert svc.stats.decode_cache_misses == (decode == "host")

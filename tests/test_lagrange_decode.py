@@ -196,7 +196,7 @@ def test_device_decode_below_boundary_builds_weights_in_trace():
     touches the host LRU."""
     m = mds.LAGRANGE_MAX_M
     svc = FFTService(FFTServiceConfig(s=64 * m, m=m, n_workers=2 * m))
-    assert svc._device_decode()
+    assert svc._decode_in_jit()
     jaxpr = _runner_jaxpr(svc)
     assert "cos" in jaxpr and "sort" in jaxpr
     x = jnp.asarray(np.random.default_rng(0)
@@ -213,7 +213,7 @@ def test_above_boundary_falls_back_to_host_lru():
     m = 64
     assert m > mds.LAGRANGE_MAX_M
     svc = FFTService(FFTServiceConfig(s=32 * m, m=m, n_workers=2 * m))
-    assert not svc._device_decode()
+    assert not svc._decode_in_jit()
     jaxpr = _runner_jaxpr(svc)
     assert "cos" not in jaxpr
     x = jnp.asarray(np.random.default_rng(0)
@@ -222,18 +222,21 @@ def test_above_boundary_falls_back_to_host_lru():
     assert svc.stats.decode_cache_misses > 0
 
 
-def test_device_and_host_paths_serve_identical_results():
+def test_device_and_host_paths_serve_identical_results(monkeypatch):
     """Same seed (hence same simulated straggler masks): the device-decode
-    service and the host-LRU fallback service must agree request for
-    request -- and both must match numpy."""
+    service and the host-LRU fallback service (m above a lowered
+    ``LAGRANGE_MAX_M``) must agree request for request -- and both must
+    match numpy."""
     rng = np.random.default_rng(7)
     xs = [jnp.asarray((rng.normal(size=512) + 1j * rng.normal(size=512))
                       .astype(np.complex64)) for _ in range(9)]
-    common = dict(s=512, m=4, n_workers=8, seed=21)
-    dev = FFTService(FFTServiceConfig(**common))
-    host = FFTService(FFTServiceConfig(**common, device_decode=False))
+    cfg = FFTServiceConfig(s=512, m=4, n_workers=8, seed=21)
+    dev = FFTService(cfg)
     out_d = dev.submit_batch(xs)
-    out_h = host.submit_batch(xs)
+    with monkeypatch.context() as patch:
+        patch.setattr(mds, "LAGRANGE_MAX_M", 2)
+        host = FFTService(cfg)
+        out_h = host.submit_batch(xs)
     for x, yd, yh in zip(xs, out_d, out_h):
         want = np.fft.fft(np.asarray(x, np.complex128))
         assert np.abs(yd - want).max() < 1e-2

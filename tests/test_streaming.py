@@ -17,7 +17,6 @@ import pytest
 from repro.core import mds
 from repro.kernels import ops, ref
 from repro.kernels.coded_pipeline import (
-    coded_fft_bucket_streaming,
     coded_fft_bucket_streaming_masked,
     subsets_from_masks_body,
 )
@@ -162,7 +161,8 @@ def _bucket_case(s, m, n, q, seed=0):
 ])
 def test_streaming_bucket_forced_multi_tile_parity(s, m, n, q, bq, ba, bb):
     """Direct kernel-level parity with tiny tiles: many grid steps per
-    phase, ragged batch padding, masked and unmasked variants."""
+    phase, ragged batch padding, subset selection and decode in the
+    kernel."""
     g, gr, gi, x, xr, xi, masks = _bucket_case(s, m, n, q)
     ell = s // m
     a, b = ops.split_factor(ell)
@@ -173,13 +173,6 @@ def test_streaming_bucket_forced_multi_tile_parity(s, m, n, q, bq, ba, bb):
 
     yr, yi = coded_fft_bucket_streaming_masked(
         xr, xi, jnp.asarray(masks), gr, gi, *planes,
-        block_q=bq, block_a=ba, block_b=bb, interpret=True)
-    assert _relerr(np.asarray(yr) + 1j * np.asarray(yi), want) < 1e-4
-
-    subsets = ops.mask_subsets(jnp.asarray(masks), m)
-    dr, di = ops.lagrange_scatter_planes(subsets, n)
-    yr, yi = coded_fft_bucket_streaming(
-        xr, xi, dr, di, gr, gi, *planes,
         block_q=bq, block_a=ba, block_b=bb, interpret=True)
     assert _relerr(np.asarray(yr) + 1j * np.asarray(yi), want) < 1e-4
 

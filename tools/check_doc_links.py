@@ -6,7 +6,7 @@ Three classes of reference are verified against the working tree:
    is a local path (http(s) links are skipped -- CI must not flake on
    third-party outages);
 2. backticked repo paths like `src/repro/core/rfftn.py`,
-   `tests/test_rfftn.py`, or `benchmarks/bench_service.py` -- the docs
+   `tests/test_rfftn.py`, or `bench/harness.py` -- the docs
    lean on these heavily as the architecture map;
 3. DESIGN.md section anchors: every `§k` the README cites must exist as
    a `## §k` heading in DESIGN.md.
